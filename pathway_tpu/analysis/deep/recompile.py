@@ -8,8 +8,8 @@ enumerates that space symbolically — per target, via the owning ops
 module's ``deep_compile_profile`` hook — and compares the summed
 distinct-compile prediction against a budget
 (``PATHWAY_COMPILE_BUDGET``, default 256). Exceeding the budget means
-the run spends its first epochs in a compile storm (on a remote/
-tunneled TPU each compile is seconds of dead chip time); a dynamic
+the run spends its first epochs in a compile storm (each compile is
+seconds of dead chip time); a dynamic
 dimension with *no* bucket ladder at all is flagged unconditionally,
 because its compile count is workload-dependent and unbounded.
 

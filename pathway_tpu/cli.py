@@ -6,9 +6,10 @@ program as N OS processes with the PATHWAY_* worker-topology env vars
 88-120), ``spawn-from-env`` re-reads the spawn arguments from
 PATHWAY_SPAWN_ARGS, and ``--record``/``--replay`` wire stream
 record/replay through env (reference cli.py:166-193). In the TPU build
-each spawned process is one host of the slice: processes join a global
-``jax.sharding.Mesh`` via ``jax.distributed`` (see
-pathway_tpu/parallel/sharding.py host_mesh_from_env).
+the spawned processes scale the host dataflow only: process 0 owns
+every chip of the host (one process per chip, driven through
+``pw.run(mesh=...)``) and the others are started with
+``JAX_PLATFORMS=cpu`` (internals/config.py worker_process_env).
 """
 
 from __future__ import annotations
@@ -62,10 +63,12 @@ def _spawn_program(
 
     procs: list[subprocess.Popen] = []
     try:
+        from .internals.config import worker_process_env
+
         for pid in range(processes):
-            env = dict(env_base)
-            env["PATHWAY_PROCESS_ID"] = str(pid)
-            procs.append(subprocess.Popen(argv, env=env))
+            procs.append(
+                subprocess.Popen(argv, env=worker_process_env(env_base, pid))
+            )
     except OSError:
         for p in procs:
             p.terminate()

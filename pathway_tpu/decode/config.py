@@ -53,8 +53,9 @@ class DecodeConfig:
     clamp applied when admission degrades a query (degrade also skips
     the rerank stage); ``max_seq`` bounds prompt+generation context;
     ``impl`` picks the attention path (``auto`` = paged kernel on TPU,
-    XLA gather elsewhere; ``interpret`` = Pallas interpret mode, the
-    CPU parity path); ``hbm_bytes`` overrides the pool budget check.
+    which needs ``page_size`` to be a multiple of 8, XLA gather
+    elsewhere; ``interpret`` = Pallas interpret mode, the CPU parity
+    path); ``hbm_bytes`` overrides the pool budget check.
 
     Serving extensions (all default off — the defaults reproduce the
     original single-token greedy engine byte-for-byte):

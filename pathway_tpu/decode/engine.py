@@ -52,6 +52,7 @@ from ..ops.paged_attention import (
     paged_attention_reference,
     paged_decode_attention,
     pages_for,
+    require_kernel_page_size,
 )
 from .config import DecodeConfig, active_decode
 from .metrics import DECODE_METRICS
@@ -643,6 +644,8 @@ class DecodeEngine:
         impl = self.config.impl
         if impl == "auto":
             impl = "paged" if jax.default_backend() == "tpu" else "xla"
+        if impl == "paged":
+            require_kernel_page_size(self.config.page_size)
         self.impl = impl
         self.params = (
             params

@@ -22,8 +22,8 @@ Donation rules (documented in README "Performance"):
   are committed, and runs before state pickling in
   ``EngineGraph._snapshot_operators`` / ``ShardCluster``.
 
-Everything degrades to plain numpy when jax is unavailable, so the ring
-is safe to use from pure-host test paths.
+A put the device refuses raises: a ring that handed back the host array
+would let every consumer run on whatever device jit picked next.
 """
 
 from __future__ import annotations
@@ -69,17 +69,6 @@ def quiesce_all() -> None:
     while a donated buffer is mid-transfer must not capture the alias."""
     for ring in active_rings():
         ring.sync()
-
-
-def _device_put(arr, sharding=None):
-    try:
-        import jax
-
-        if sharding is not None:
-            return jax.device_put(arr, sharding)
-        return jax.device_put(arr)
-    except Exception:
-        return arr  # host fallback: the ring still bounds generations
 
 
 def _block(arr) -> None:
@@ -189,19 +178,21 @@ class DeviceRing:
                 flight_recorder.record(
                     "ring.donate", ring=self.name, buffers=len(prev), total=self.donated
                 )
+            import jax
+
             from ..internals.chip_ledger import CHIP_LEDGER
 
             if CHIP_LEDGER.on():
                 import time as _time
 
                 c0 = _time.perf_counter()
-                handles = [_device_put(a, s) for a, s in zip(items, per_item)]
+                handles = [jax.device_put(a, s) for a, s in zip(items, per_item)]
                 # put-issue wall only: staging stays non-blocking even
                 # under accounting (the stall above is already a
                 # stranded-time cause, not chip work)
                 CHIP_LEDGER.book("ingest.stage", _time.perf_counter() - c0)
             else:
-                handles = [_device_put(a, s) for a, s in zip(items, per_item)]
+                handles = [jax.device_put(a, s) for a, s in zip(items, per_item)]
             nbytes = sum(int(getattr(a, "nbytes", 0) or 0) for a in items)
             with self._lock:
                 self._slots[idx] = handles

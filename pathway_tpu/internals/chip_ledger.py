@@ -97,16 +97,32 @@ def chip_ledger_enabled() -> bool:
     return os.environ.get("PATHWAY_CHIP_LEDGER", "").strip().lower() in _TRUE
 
 
-def chip_peak_tflops() -> float:
-    """Roofline peak used for the encode MFU column. Feed the probed
-    value from ``bench.py``'s ``chip_peak_probe_tflops`` via
-    ``PATHWAY_CHIP_PEAK_TFLOPS``; defaults to the nominal full-chip
-    peak the ROADMAP targets assume (~200 TFLOPs bf16)."""
+def chip_peak_tflops() -> float | None:
+    """Roofline peak for the encode MFU column, from
+    ``PATHWAY_CHIP_PEAK_TFLOPS``. There is no default: a peak assumed
+    for whatever device happens to be attached makes every MFU a guess,
+    so unset (or not a positive number) means the column reads "n/a"."""
     try:
-        v = float(os.environ.get("PATHWAY_CHIP_PEAK_TFLOPS", "200"))
-    except ValueError:
-        return 200.0
-    return v if v > 0 else 200.0
+        v = float(os.environ["PATHWAY_CHIP_PEAK_TFLOPS"])
+    except (KeyError, ValueError):
+        return None
+    return v if v > 0 else None
+
+
+def format_mfu(mfu: dict, pad: bool = False) -> str:
+    """One line for an ``encode_mfu`` snapshot block (``pathway top``,
+    ``pathway doctor``): "n/a" where no peak is configured."""
+    achieved = float(mfu.get("achieved_tflops") or 0.0)
+    if mfu.get("mfu") is None:
+        text = f"encode MFU n/a ({achieved:.1f} TFLOPs; PATHWAY_CHIP_PEAK_TFLOPS unset"
+    else:
+        text = (
+            f"encode MFU {100 * float(mfu['mfu']):.2f}% "
+            f"({achieved:.1f} / {float(mfu['peak_tflops']):.1f} TFLOPs"
+        )
+    if pad:
+        text += f", pad {100 * float(mfu.get('pad_fraction') or 0.0):.1f}%"
+    return text + ")"
 
 
 class ChipTimeLedger:
@@ -269,8 +285,9 @@ class ChipTimeLedger:
         return stalls
 
     def _mfu(self) -> dict[str, Any] | None:
-        """Encode-plane MFU vs the probed roofline peak, from the
-        encoder kernel stats window (dispatch-clock achieved TFLOPs)."""
+        """Encode-plane MFU vs the configured roofline peak (``None``
+        fields when no peak is configured), from the encoder kernel
+        stats window (dispatch-clock achieved TFLOPs)."""
         try:
             from .profiler import ENCODER_KERNEL_STATS
 
@@ -281,8 +298,8 @@ class ChipTimeLedger:
             achieved = float(enc.get("achieved_tflops", 0.0))
             return {
                 "achieved_tflops": round(achieved, 3),
-                "peak_tflops": round(peak, 3),
-                "mfu": round(achieved / peak, 6) if peak > 0 else 0.0,
+                "peak_tflops": round(peak, 3) if peak else None,
+                "mfu": round(achieved / peak, 6) if peak else None,
                 "pad_fraction": enc.get("pad_fraction", 0.0),
             }
         except Exception:

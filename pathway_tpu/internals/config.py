@@ -10,6 +10,21 @@ import os
 from dataclasses import dataclass, field
 
 
+def worker_process_env(env: dict[str, str], process_id: int) -> dict[str, str]:
+    """The environment a cluster launcher gives process ``process_id``.
+
+    One process per chip: process 0 owns every chip of its host and
+    drives them through the mesh (``pw.run(mesh=...)``). ``PATHWAY_PROCESSES
+    > 1`` scales the host dataflow only, so every other process is held
+    to the CPU here — a second JAX that found the TPU would fail or hang
+    on a chip that is already taken."""
+    env = dict(env)
+    env["PATHWAY_PROCESS_ID"] = str(process_id)
+    if process_id != 0:
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def _env_int(name: str, default: int) -> int:
     v = os.environ.get(name)
     try:

@@ -895,7 +895,7 @@ class MonitoringHttpServer:
                     )
                 )
         mfu = snap.get("encode_mfu")
-        if mfu:
+        if mfu and mfu.get("mfu") is not None:  # no configured peak, no gauge
             lines.append("# TYPE pathway_chip_encode_mfu gauge")
             lines.append(series("pathway_chip_encode_mfu", f"{mfu['mfu']:.6f}"))
         tenants = snap.get("tenants") or {}

@@ -952,11 +952,9 @@ def render_verdict(verdict: dict) -> str:
             lines.append(f"    stranded causes: {cause_txt}")
         mfu = chip.get("encode_mfu")
         if mfu:
-            lines.append(
-                f"    encode MFU {mfu.get('mfu', 0.0) * 100:.2f}% "
-                f"({mfu.get('achieved_tflops', 0.0):.1f} / "
-                f"{mfu.get('peak_tflops', 0.0):.1f} TFLOPs)"
-            )
+            from .chip_ledger import format_mfu
+
+            lines.append(f"    {format_mfu(mfu)}")
     fresh = verdict.get("freshness")
     if fresh:
         lag = fresh.get("lag") or {}

@@ -564,6 +564,14 @@ def run(
     # that don't override it.
     from ..parallel.mesh import resolve_mesh, set_active_mesh
 
+    # JAX's persistent compile cache, placed before this run compiles
+    # anything. Only in a process that has loaded JAX (an embedder or
+    # an index built at graph time has) or is about to for the mesh:
+    # host-only ETL runs never import it and should not for a cache.
+    if mesh is not None or "jax" in sys.modules:
+        from .compile_cache import configure_compile_cache
+
+        configure_compile_cache()
     _run_mesh = resolve_mesh(mesh) if mesh is not None else None
     if _run_mesh is not None:
         set_active_mesh(_run_mesh)
@@ -634,11 +642,14 @@ def run(
             """Same interpreter + argv (every process runs the same
             program), with the dead worker's slot and the bumped
             generation in the environment — the generation is what lets
-            the coordinator tell the replacement from a zombie."""
+            the coordinator tell the replacement from a zombie. Like
+            every worker it is held to the CPU: this process keeps the
+            chips."""
             import subprocess
 
-            env = dict(os.environ)
-            env["PATHWAY_PROCESS_ID"] = str(wpid)
+            from .config import worker_process_env
+
+            env = worker_process_env(os.environ, wpid)
             env["PATHWAY_CLUSTER_GENERATION"] = str(generation)
             children.append(subprocess.Popen([sys.executable] + sys.argv, env=env))
 

@@ -144,11 +144,9 @@ class CLIPEncoder:
         # built on the first _image_batches call)
         self._ring = None
         # ingest path: images ship as FLAT uint8 rows — 4x fewer bytes
-        # than f32 over the host->device link (on tunneled/remote
-        # devices the uplink, not the MXU, is the CLIP bottleneck) and
-        # flat layout avoids the padded device tiling of a [B,H,W,3]
-        # uint8 transfer (measured 5 MB/s vs link-rate flat). Reshape +
-        # dequantize happen on device inside the jit.
+        # than f32 over the host->device link, and the flat layout
+        # avoids the padded device tiling of a [B,H,W,3] uint8 transfer.
+        # Reshape + dequantize happen on device inside the jit.
         H = self.cfg.image_size
 
         def _vfwd_flat(p, flat):

@@ -163,9 +163,7 @@ def ring_encode(params, cfg, ids, mask, mesh: Mesh, axis: str = "data"):
     fn = _RING_JIT.get(key)
     if fn is None:
         fwd = functools.partial(_sp_encoder_forward, axis_name=axis)
-        from ..parallel.sharding import shard_map as _shard_map
-
-        shard = _shard_map(
+        shard = jax.shard_map(
             lambda p, i, m: fwd(p, cfg, i, m),
             mesh=mesh,
             in_specs=(P(), P(None, axis), P(None, axis)),

@@ -3,14 +3,21 @@
 The native library is the host-side state/persistence engine — the
 TPU-native counterpart of the reference's Rust engine state layer
 (/root/reference/src/engine/dataflow.rs arrangements,
-/root/reference/src/persistence/). Built on demand with g++ into
-pathway_tpu/_native/ and cached; everything degrades to pure-Python
-fallbacks if the toolchain is missing (`NATIVE` is None then).
+/root/reference/src/persistence/). Built on demand with g++ into the
+git-ignored pathway_tpu/_native/, under a name that carries a hash of
+the source and the compiler flags — so the library that loads is always
+the one built from the pathway_native.cc that is on disk, whatever
+other artefacts (and mtimes) a copied tree brings along. Library users
+degrade to pure-Python fallbacks if the toolchain is missing (`NATIVE`
+is None then); ``chip_smoke.py`` and ``bench.py`` refuse to run so.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import pickle
 import subprocess
@@ -21,9 +28,17 @@ from typing import Any, Iterator
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(os.path.dirname(_HERE), "native", "pathway_native.cc")
 _OUT_DIR = os.path.join(_HERE, "_native")
-_LIB_PATH = os.path.join(_OUT_DIR, "libpathway_native.so")
+_CXX = ("g++", "-O2", "-std=c++17", "-shared", "-fPIC")
 
 _build_lock = threading.Lock()
+
+
+def _lib_path() -> str:
+    """Where the library built from the current source and flags lives."""
+    digest = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        digest.update(f.read())
+    return os.path.join(_OUT_DIR, f"libpathway_native-{digest.hexdigest()[:16]}.so")
 
 
 def _build() -> str | None:
@@ -31,17 +46,24 @@ def _build() -> str | None:
         return None
     with _build_lock:
         try:
-            if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC):
-                return _LIB_PATH
+            lib_path = _lib_path()
+            if os.path.exists(lib_path):
+                return lib_path
             os.makedirs(_OUT_DIR, exist_ok=True)
             # pid-unique temp + atomic replace: concurrent processes (e.g.
             # pytest-xdist on a fresh checkout) each build their own copy
             # and the last replace wins with a complete .so
-            tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
-            cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC]
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            os.replace(tmp, _LIB_PATH)
-            return _LIB_PATH
+            tmp = f"{lib_path}.{os.getpid()}.tmp"
+            subprocess.run(
+                [*_CXX, "-o", tmp, _SRC], check=True, capture_output=True, timeout=120
+            )
+            os.replace(tmp, lib_path)
+            # artefacts of other sources or flags are never loaded again
+            for stale in glob.glob(os.path.join(_OUT_DIR, "libpathway_native*.so")):
+                if stale != lib_path:
+                    with contextlib.suppress(OSError):  # a racing process won
+                        os.unlink(stale)
+            return lib_path
         except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
             # includes read-only installs (makedirs/replace PermissionError):
             # import must survive and fall back to the python paths
@@ -52,7 +74,7 @@ def _build() -> str | None:
 def _load() -> ctypes.CDLL | None:
     if os.environ.get("PATHWAY_DISABLE_NATIVE"):
         return None
-    path = _build()  # no-op when the .so is newer than the source
+    path = _build()  # no-op when this source's artefact is already there
     if path is None:
         return None
     try:

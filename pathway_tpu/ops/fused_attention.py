@@ -33,9 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BLOCK_OFF = -1.0e30  # additive bias outside the block diagonal
 KEY_OFF = -1.0e9  # additive bias on padded keys
 
@@ -152,7 +149,7 @@ def _fused_call(qkv, key_mask, n_heads: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp * rows, d), qkv.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(tokens, kbias)
     return out.reshape(bp * p, s, d)[:b]
@@ -274,7 +271,7 @@ def _packed_call(qkv, segment_ids, n_heads: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp * rows, d), qkv.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(tokens, seg, segc)
     return out.reshape(bp * p, s, d)[:b]

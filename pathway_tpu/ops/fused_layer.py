@@ -15,7 +15,9 @@ whole layer:
 MFU round (ROADMAP item 1) tiling:
 
 * **Ragged lengths instead of a key-bias stream.**  Per-sequence real
-  lengths ride a tiny SMEM block ([bp, p] int32) instead of the old
+  lengths ride SMEM as one scalar-prefetched [bp * p] int32 vector
+  (a blocked (1, p) SMEM operand does not lower: Mosaic wants the last
+  two block dims (8, 128)-aligned or whole) instead of the old
   [bp, 8, rows] f32 key-bias tensor; the key-padding bias is rebuilt
   on the VPU from a (1, seq) iota.  That deletes the largest non-token
   HBM stream the kernel had and is what lets the grid *skip* padded
@@ -62,9 +64,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 from .fused_attention import BLOCK_OFF, KEY_OFF
 
@@ -139,10 +138,11 @@ def _layer_kernel(
     p = rows // seq
     hd = d // n_heads
 
-    # max real length across the packed sequences: scalar SMEM reads
-    live = lens_ref[0, 0]
-    for j in range(1, p):
-        live = jnp.maximum(live, lens_ref[0, j])
+    # this block's p real lengths out of the prefetched [bp * p] vector
+    # (scalar SMEM reads), and their max
+    base = pl.program_id(0) * p
+    blk_lens = [lens_ref[base + j] for j in range(p)]
+    live = functools.reduce(jnp.maximum, blk_lens)
 
     @pl.when(live == 0)
     def _dead_block():
@@ -163,7 +163,7 @@ def _layer_kernel(
             # packed sequence; cross-sequence tiles never computed
             blocks = []
             for j in range(p):
-                kb = jnp.where(kiota < lens_ref[0, j], 0.0, KEY_OFF)
+                kb = jnp.where(kiota < blk_lens[j], 0.0, KEY_OFF)
                 sub = qkv[j * seq : (j + 1) * seq, :]
                 blocks.append(_head_attention(sub, kb, d, hd, n_heads, scale))
             ctx = jnp.concatenate(blocks, axis=0).astype(x.dtype)
@@ -174,10 +174,7 @@ def _layer_kernel(
             qi = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0) // seq
             ki = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1) // seq
             kb = jnp.concatenate(
-                [
-                    jnp.where(kiota < lens_ref[0, j], 0.0, KEY_OFF)
-                    for j in range(p)
-                ],
+                [jnp.where(kiota < ln, 0.0, KEY_OFF) for ln in blk_lens],
                 axis=1,
             )  # (1, rows)
             bias = jnp.where(qi == ki, 0.0, BLOCK_OFF) + kb
@@ -254,7 +251,7 @@ def fused_layer_tokens(
     bp = tokens.shape[0] // rows
     att, ln1 = layer_params["attention"], layer_params["ln_att"]
     w = lambda t: t.astype(tokens.dtype)
-    const = lambda shape: pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape))
+    const = lambda shape: pl.BlockSpec(shape, lambda i, lens: (0,) * len(shape))
     args = [
         w(att["qkv"]["kernel"]),
         _row2(att["qkv"]["bias"].astype(jnp.float32)),
@@ -277,17 +274,19 @@ def fused_layer_tokens(
             scale=1.0 / math.sqrt(d // n_heads),
             eps=eps,
         ),
-        grid=(bp,),
-        in_specs=[
-            pl.BlockSpec((1, p), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((rows, d), lambda i: (i, 0)),
-            *[const(a.shape) for a in args],
-        ],
-        out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bp,),
+            in_specs=[
+                pl.BlockSpec((rows, d), lambda i, lens: (i, 0)),
+                *[const(a.shape) for a in args],
+            ],
+            out_specs=pl.BlockSpec((rows, d), lambda i, lens: (i, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct(tokens.shape, tokens.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(lens, tokens, *args)
+    )(lens.reshape(-1), tokens, *args)
 
 
 def pack_tokens(x, key_mask, lens=None):

@@ -4,9 +4,8 @@ The reference's RAG query path runs three host-driven stages — query
 embedding (embedders.py:270), KNN retrieval
 (external_integration/usearch_integration.rs:53), cross-encoder rerank
 (rerankers.py:186) — each a separate model/native call. On TPU each
-stage boundary costs a host->device dispatch; on a tunneled or remote
-device the link latency (~150ms RTT) times three blows the <50ms p50
-SLO (BASELINE.md config 3) regardless of compute speed.
+stage boundary costs a host->device dispatch and a sync, three per
+query against the <50ms p50 SLO (BASELINE.md config 3).
 
 Here the WHOLE query is one jit dispatch: tokenize on host, then
   encode query -> score vs HBM-resident doc matrix -> top-k ->

@@ -43,14 +43,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .fused_attention import KEY_OFF
 
-# older/newer pltpu spellings of the compiler-params container
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = [
     "PagedKvPool",
     "dense_decode_attention",
     "paged_decode_attention",
     "paged_attention_reference",
+    "require_kernel_page_size",
     "pages_for",
     "kv_pool_bytes",
 ]
@@ -139,6 +137,18 @@ def kv_pool_bytes(
     return _kv_pool_bytes(n_pages, page_size, layers, dim, dtype_bytes)
 
 
+def require_kernel_page_size(page_size: int) -> None:
+    """The compiled kernel stores each page into the gather buffer at
+    the dynamic row offset ``p * page_size``, and Mosaic takes a dynamic
+    sublane offset only where it can prove it a multiple of the f32
+    tile's 8 rows. Interpret mode and the XLA reference take any size."""
+    if page_size % 8:
+        raise ValueError(
+            f"paged attention: the TPU kernel needs page_size to be a "
+            f"multiple of 8, got {page_size} (or run impl='xla')"
+        )
+
+
 def _attend(q, k, v, length, n_heads: int, scale: float):
     """One query row against one gathered context — the *shared* op
     sequence. The kernel calls it on VMEM refs' values; the dense
@@ -202,10 +212,10 @@ def dense_decode_attention(q, k_ctx, v_ctx, lens, *, n_heads: int, scale=None):
 def _paged_kernel(
     pt_ref,  # SMEM [B, P] page tables (scalar prefetch)
     lens_ref,  # SMEM [B] context lengths (scalar prefetch)
-    q_ref,  # VMEM (1, d) query token for sequence b
+    q_ref,  # VMEM (1, 1, d) query token for sequence b
     k_ref,  # VMEM (1, page_size, d) pool page table[b, p]
     v_ref,  # VMEM (1, page_size, d)
-    o_ref,  # VMEM (1, d)
+    o_ref,  # VMEM (1, 1, d)
     k_buf,  # VMEM scratch (P * page_size, d) — persists across grid steps
     v_buf,
     *,
@@ -247,8 +257,8 @@ def _paged_kernel(
 
         @pl.when(length > 0)
         def _live():
-            o_ref[...] = _attend(
-                q_ref[...], k_buf[...], v_buf[...], length, n_heads, scale
+            o_ref[0] = _attend(
+                q_ref[0], k_buf[...], v_buf[...], length, n_heads, scale
             ).astype(o_ref.dtype)
 
 
@@ -274,6 +284,8 @@ def paged_decode_attention(
     run the reference jitted)."""
     b, d = q.shape
     n_pages, page_size, _ = k_pages.shape
+    if not interpret:
+        require_kernel_page_size(page_size)
     pages_per_seq = page_tables.shape[1]
     ctx = pages_per_seq * page_size
     if scale is None:
@@ -291,32 +303,36 @@ def paged_decode_attention(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, pages_per_seq),
+        # q and the output ride as [B, 1, d]: a (1, d) block over [B, d]
+        # does not lower (Mosaic wants the last two block dims
+        # (8, 128)-aligned or whole), a (1, 1, d) block's are whole
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, p, pt, ln: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, p, pt, ln: (i, 0, 0)),
             pl.BlockSpec((1, page_size, d), lambda i, p, pt, ln: (pt[i, p], 0, 0)),
             pl.BlockSpec((1, page_size, d), lambda i, p, pt, ln: (pt[i, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, p, pt, ln: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, p, pt, ln: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((ctx, d), jnp.float32),
             pltpu.VMEM((ctx, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, d), jnp.float32),
         # the gather buffer carries state across page steps of one
         # sequence, so the grid must run sequentially
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(
         page_tables,
         lens.astype(jnp.int32),
-        q.astype(jnp.float32),
+        q.astype(jnp.float32).reshape(b, 1, d),
         k_pages.astype(jnp.float32),
         v_pages.astype(jnp.float32),
     )
+    return out.reshape(b, d)
 
 
 def paged_attention_reference(

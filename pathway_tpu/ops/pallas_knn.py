@@ -13,7 +13,8 @@ Grid: (query_tiles, doc_blocks); the doc axis is `arbitrary` (sequential
 on TPU), accumulating into the output block that lives in VMEM across
 the inner iterations. Top-k per block via k iterative max-extractions
 on the VPU (k is small: 8-64), then merged with the running top-k the
-same way. Falls back to interpret mode off-TPU so tests run on CPU.
+same way. ``interpret=True`` (CPU tests) is only ever an explicit
+argument; the entry points never infer it from the backend.
 """
 
 from __future__ import annotations
@@ -25,9 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG = -3.0e38  # sentinel below any real score
 
@@ -119,15 +117,13 @@ def knn_topk(
     factor: float = 1.0,
     block_q: int = 128,
     block_n: int = 2048,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Fused top-k of ``factor * (queries @ docs.T) + bias``:
     queries [Q, D] x docs [N, D] (+ bias [N]) -> (scores [Q, k],
     indices [Q, k]). bias carries validity masking (NEG for dead index
     slots) and the -|doc|^2 term for L2 distance. Pads Q/N to block
     multiples; padded docs never surface."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # the extraction merge keeps [block_q, block_n + k] candidate copies
     # live in VMEM — shrink the query tile as k grows to stay inside
     # the ~16MB scoped budget
@@ -166,7 +162,7 @@ def knn_topk(
             jax.ShapeDtypeStruct((q.shape[0], k), jnp.float32),
             jax.ShapeDtypeStruct((q.shape[0], k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -188,7 +184,7 @@ def knn_topk_sharded(
     factor: float = 1.0,
     block_q: int = 128,
     block_n: int = 2048,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Sharded fused top-k: ``docs``/``bias`` are row-sharded over the
     mesh's "data" axis; each device runs the VMEM kernel on its shard,
@@ -197,7 +193,6 @@ def knn_topk_sharded(
     cross-device merge of the reference's sharded index story
     (usearch_integration.rs:53 redesigned for the mesh). Queries are
     replicated. Returns global ([Q, k], [Q, k])."""
-    from ..parallel.sharding import shard_map  # version-compat wrapper
     from jax.sharding import PartitionSpec as P
 
     n_shards = mesh.shape["data"]
@@ -221,7 +216,7 @@ def knn_topk_sharded(
         return vals, jnp.where(idx >= 0, idx + base, -1)
 
     # check_vma off: pallas_call's out_shape carries no vma annotation
-    vals, idx = shard_map(
+    vals, idx = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, None), P("data", None), P("data")),

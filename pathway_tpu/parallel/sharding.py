@@ -4,13 +4,14 @@ Replaces the reference's worker topology (threads × processes over TCP,
 /root/reference/src/engine/dataflow/config.rs:36-120) with a
 ``jax.sharding.Mesh``: the "data" axis plays the role of key-sharded
 workers (R7 shard.rs — hash(key) → shard), the "model" axis shards
-embedder/reranker weights tensor-parallel. Collectives ride ICI; multi-
-host extends the same mesh over DCN via ``jax.distributed.initialize``.
+embedder/reranker weights tensor-parallel. Collectives ride ICI. One
+process drives every chip of its host through this mesh; the
+multi-process cluster (``pathway spawn --processes``) is host dataflow
+only.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Sequence
 
 import jax
@@ -69,35 +70,3 @@ def data_sharding(mesh: Mesh, ndim: int = 2) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """jax.shard_map across jax versions: the top-level export (jax >=
-    0.6, kwarg check_vma) or jax.experimental.shard_map (0.4.x, kwarg
-    check_rep — same meaning)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as sm
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def host_mesh_from_env() -> Mesh | None:
-    """Multi-host init: when PATHWAY_PROCESSES/PROCESS_ID are set (same
-    env contract as the reference's config.rs:88-120), join the cluster
-    via jax.distributed and return the global mesh."""
-    n_proc = int(os.environ.get("PATHWAY_PROCESSES", "1"))
-    if n_proc <= 1:
-        return None
-    pid = int(os.environ.get("PATHWAY_PROCESS_ID", "0"))
-    coord = os.environ.get(
-        "PATHWAY_COORDINATOR",
-        f"127.0.0.1:{int(os.environ.get('PATHWAY_FIRST_PORT', '10000'))}",
-    )
-    jax.distributed.initialize(
-        coordinator_address=coord, num_processes=n_proc, process_id=pid
-    )
-    return make_mesh()

@@ -97,12 +97,9 @@ def render_top(data: dict[str, Any]) -> tuple[str, str]:
 
         mfu = chip.get("encode_mfu")
         if mfu:
-            lines.append(
-                f"  encode MFU {100 * float(mfu.get('mfu', 0.0)):.2f}%  "
-                f"({float(mfu.get('achieved_tflops', 0.0)):.1f} / "
-                f"{float(mfu.get('peak_tflops', 0.0)):.1f} TFLOPs, "
-                f"pad {100 * float(mfu.get('pad_fraction', 0.0)):.1f}%)"
-            )
+            from ..internals.chip_ledger import format_mfu
+
+            lines.append(f"  {format_mfu(mfu, pad=True)}")
 
         stranded = float(chip.get("stranded_fraction", 0.0))
         causes = chip.get("stranded_causes") or {}

@@ -409,7 +409,9 @@ class VectorStoreServer:
         :478-585). ``serving=`` puts the query endpoint behind the
         overload-safe serving plane (admission control, per-request
         deadlines, adaptive batching; under ``shed="degrade"`` a loaded
-        server clamps retrieval top-``k`` instead of rejecting)."""
+        server clamps retrieval top-``k`` instead of rejecting). Other
+        keyword arguments go to ``pw.run`` (``mesh=4``, ...), as in the
+        reference."""
         from ...io.http import PathwayWebserver, rest_connector
 
         webserver = PathwayWebserver(host=host, port=port)
@@ -447,7 +449,7 @@ class VectorStoreServer:
         def run():
             from ...internals.run import run as pw_run
 
-            pw_run(monitoring_level=None)
+            pw_run(monitoring_level=None, **kwargs)
 
         if threaded:
             t = threading.Thread(target=run, daemon=True, name="vector_store_server")

@@ -198,12 +198,20 @@ def test_stranded_causes_capped_at_residual(_chip):
 
 
 def test_chip_peak_tflops_env(monkeypatch):
+    """No peak is assumed for an unknown device: unset (or unusable)
+    means no MFU, rendered as "n/a"."""
+    from pathway_tpu.internals.chip_ledger import format_mfu
+
     monkeypatch.delenv("PATHWAY_CHIP_PEAK_TFLOPS", raising=False)
-    assert chip_peak_tflops() == 200.0
+    assert chip_peak_tflops() is None
     monkeypatch.setenv("PATHWAY_CHIP_PEAK_TFLOPS", "130.7")
     assert chip_peak_tflops() == 130.7
     monkeypatch.setenv("PATHWAY_CHIP_PEAK_TFLOPS", "bogus")
-    assert chip_peak_tflops() == 200.0
+    assert chip_peak_tflops() is None
+    unknown = {"achieved_tflops": 73.4, "peak_tflops": None, "mfu": None}
+    assert format_mfu(unknown).startswith("encode MFU n/a (73.4 TFLOPs")
+    known = {"achieved_tflops": 73.4, "peak_tflops": 146.8, "mfu": 0.5, "pad_fraction": 0.25}
+    assert format_mfu(known, pad=True) == "encode MFU 50.00% (73.4 / 146.8 TFLOPs, pad 25.0%)"
 
 
 # ---------------------------------------------------------------------------

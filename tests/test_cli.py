@@ -39,19 +39,24 @@ def test_spawn_runs_n_processes_with_topology_env(tmp_path):
         "pid = os.environ['PATHWAY_PROCESS_ID']\n"
         "info = {k: os.environ.get(k) for k in\n"
         "        ('PATHWAY_THREADS', 'PATHWAY_PROCESSES', 'PATHWAY_FIRST_PORT')}\n"
+        "info['JAX_PLATFORMS'] = os.environ.get('JAX_PLATFORMS')\n"
         "open(f'out_{pid}.json', 'w').write(json.dumps(info))\n"
     )
     res = _run_cli(
         ["spawn", "--threads", "2", "--processes", "2", "--first-port", "11500", str(prog)],
         cwd=tmp_path,
+        extra_env={"JAX_PLATFORMS": "tpu,cpu"},
     )
     assert res.returncode == 0, res.stderr
-    for pid in (0, 1):
+    # one process per chip: process 0 keeps the launcher's platforms,
+    # every other worker is held to the CPU
+    for pid, platforms in ((0, "tpu,cpu"), (1, "cpu")):
         info = json.loads((tmp_path / f"out_{pid}.json").read_text())
         assert info == {
             "PATHWAY_THREADS": "2",
             "PATHWAY_PROCESSES": "2",
             "PATHWAY_FIRST_PORT": "11500",
+            "JAX_PLATFORMS": platforms,
         }
 
 

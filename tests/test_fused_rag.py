@@ -66,3 +66,40 @@ def test_empty_and_missing(enc):
     p.add_docs([1], ["only doc"])
     r = p.query("only doc", k=5, k_retrieve=8)
     assert [k for k, _ in r] == [1]  # padding slots filtered out
+
+
+def test_index_text_queries_match_embed_then_search(enc):
+    """DeviceKnnIndex.search_texts_batch (one fused dispatch; two over a
+    mesh) against enc.encode + search_batch. The fused program ships
+    scores and slots in one int32 array: slots bitcast to f32 are
+    denormals, which a TPU flushes to zero (every hit became slot 0)."""
+    import jax
+    from jax.sharding import Mesh
+
+    from pathway_tpu.ops.knn import DeviceKnnIndex
+
+    docs = [f"text query doc {i} of kind {i % 5}" for i in range(24)]
+    queries = [docs[7], docs[19], "kind 3"]
+    vecs = enc.encode(docs)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4, 1), ("data", "model"))
+    for m in (None, mesh):
+        idx = DeviceKnnIndex(dim=enc.dim, metric="cos", mesh=m)
+        idx.add_batch_arrays(list(range(len(docs))), vecs)
+        idx.attach_encoder(enc)
+        got = idx.search_texts_batch(queries, 3)
+        want = idx.search_batch(enc.encode(queries), 3)
+        assert [[k for k, _ in row] for row in got] == [[k for k, _ in row] for row in want]
+        assert got[0][0][0] == 7 and got[1][0][0] == 19
+        np.testing.assert_allclose(
+            [s for row in got for _, s in row], [s for row in want for _, s in row], atol=1e-5
+        )
+    packed = enc._pw_fused_query_jit(
+        enc.params,
+        np.zeros((8, 16), np.int32),
+        np.full((8,), 4, np.int32),
+        np.zeros((64, enc.dim), np.float32),
+        np.ones((64,), bool),
+        k=8,
+        l2=False,
+    )
+    assert packed.dtype == np.int32 and packed.shape == (8, 16)

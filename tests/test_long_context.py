@@ -11,7 +11,7 @@ import pytest
 
 from pathway_tpu.models.encoder import EncoderConfig, TextEncoder, init_params
 from pathway_tpu.models.long_context import ring_attention, ring_encode
-from pathway_tpu.parallel.sharding import make_mesh, shard_map
+from pathway_tpu.parallel.sharding import make_mesh
 
 
 def _cfg():
@@ -41,7 +41,7 @@ def test_ring_attention_matches_full_attention():
     mask = jnp.asarray(mask)
 
     ringed = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda q, k, v, m: ring_attention(q, k, v, m, "data"),
             mesh=mesh,
             in_specs=(P(None, None, "data"), P(None, None, "data"), P(None, None, "data"), P(None, "data")),

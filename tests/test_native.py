@@ -197,3 +197,34 @@ def test_shard_batch_matches_python():
     )
     expect = np.array([shard_of(int(k), 8) for k in keys], dtype=np.uint32)
     np.testing.assert_array_equal(out, expect)
+
+
+def test_loaded_library_is_built_from_the_current_source(tmp_path, monkeypatch):
+    """A stale artefact, however new its mtime, is never what loads: the
+    library is looked up under a hash of the source on disk. Here the
+    "current" source is a copy whose version string was edited, and the
+    stale artefact is the library this process is running (built from
+    the unedited source), planted under the old fixed name and under
+    another hash's name, both stamped an hour into the future."""
+    import shutil
+    import time
+
+    src = tmp_path / "pathway_native.cc"
+    with open(native._SRC) as f:
+        text = f.read()
+    assert '"pathway-native 1.0"' in text
+    src.write_text(text.replace('"pathway-native 1.0"', '"pathway-native edited"'))
+    out_dir = tmp_path / "_native"
+    out_dir.mkdir()
+    future = time.time() + 3600
+    for name in ("libpathway_native.so", "libpathway_native-0123456789abcdef.so"):
+        shutil.copy(native._lib_path(), out_dir / name)
+        os.utime(out_dir / name, (future, future))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_OUT_DIR", str(out_dir))
+
+    lib = native._load()
+    assert lib is not None
+    assert lib.pn_version() == b"pathway-native edited"
+    # and the stale artefacts are gone, so nothing can pick them up later
+    assert os.listdir(out_dir) == [os.path.basename(native._lib_path())]

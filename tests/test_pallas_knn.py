@@ -1,8 +1,7 @@
 """Fused pallas KNN top-k kernel vs the unfused XLA reference.
 
-Runs in interpret mode on CPU (tests/conftest.py); on a real TPU the
-same kernel lowers through Mosaic (verified there: exact index
-agreement, ~6x faster than unfused at 1M docs)."""
+Every call here asks for interpret mode by argument (the CPU has no
+Mosaic); the kernel's run on a real TPU is ``chip_smoke.py``'s."""
 
 from __future__ import annotations
 
@@ -81,7 +80,9 @@ def test_device_index_parity_with_pallas_formula():
 
     idx._sync()
     qn = q / np.linalg.norm(q, axis=1, keepdims=True)
-    vals, ids = knn_mod._pallas_topk("cos", idx._dev_matrix, idx._dev_valid, qn, 8)
+    vals, ids = knn_mod._pallas_topk(
+        "cos", idx._dev_matrix, idx._dev_valid, qn, 8, interpret=True
+    )
     got = []
     for row_v, row_i in zip(np.asarray(vals), np.asarray(ids)):
         out = []
@@ -154,8 +155,14 @@ def test_device_index_sharded_pallas_parity(monkeypatch):
     expected = ref_idx.search_batch(q, 6)
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
-    monkeypatch.setenv("PATHWAY_TPU_FORCE_PALLAS", "1")
-    # interpret mode on CPU: knn_topk auto-interprets off-TPU
+    import functools
+
+    monkeypatch.setattr(knn_mod, "_pallas_eligible", lambda metric, k, mesh: True)
+    monkeypatch.setattr(
+        knn_mod,
+        "_pallas_topk",
+        functools.partial(knn_mod._pallas_topk, interpret=True),
+    )
     sh_idx = knn_mod.DeviceKnnIndex(dim=24, metric="l2", mesh=mesh)
     for i, v in enumerate(vecs):
         sh_idx.add(f"k{i}", v)
