@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..tracing import span as _span
 from .batching import chunks, pad_token_batch
 from .encoder import (
     CrossEncoderHead,
@@ -184,7 +185,10 @@ class SentenceEncoder:
         from ..ingest import stage as ingest_stage
 
         st = ingest_stage.get_stage()
-        m = self.tokenizer.batch_encode_matrix(texts, self.max_seq_len, stage=st)
+        with _span("embed_tokenize", rows=len(texts)) as sp:
+            m = self.tokenizer.batch_encode_matrix(texts, self.max_seq_len, stage=st)
+            if sp is not None and m is not None:
+                sp.attrs["tokens"] = int(m[1].sum())  # real tokens, no padding
         if m is not None and st is not None:
             from ..ingest.stage import route_by_length
             from .batching import DEFAULT_SEQ_BUCKETS, bucket
@@ -212,17 +216,18 @@ class SentenceEncoder:
         pending = []
         for start in range(0, n, batch):
             group = order[start : start + batch]
-            L = min(
-                bucket(int(lens[group].max()), DEFAULT_SEQ_BUCKETS),
-                ids_mat.shape[1],
-            )
             ng = len(group)
-            ids = np.take(ids_mat[:, :L], group, axis=0)
-            if ng < batch:
-                bb = tuple(b for b in DEFAULT_BATCH_BUCKETS if b < batch) + (batch,)
-                B = max(bucket(ng, bb), ng)
-                if B > ng:
-                    ids = np.pad(ids, ((0, B - ng), (0, 0)))
+            with _span("embed_pack", rows=ng):
+                L = min(
+                    bucket(int(lens[group].max()), DEFAULT_SEQ_BUCKETS),
+                    ids_mat.shape[1],
+                )
+                ids = np.take(ids_mat[:, :L], group, axis=0)
+                if ng < batch:
+                    bb = tuple(b for b in DEFAULT_BATCH_BUCKETS if b < batch) + (batch,)
+                    B = max(bucket(ng, bb), ng)
+                    if B > ng:
+                        ids = np.pad(ids, ((0, B - ng), (0, 0)))
             if self.mesh is None:
                 # same compiled program as the uniform fast path (one
                 # (B, L)-shaped jit serves every batch size) — distinct
@@ -295,22 +300,23 @@ class SentenceEncoder:
 
             depth = max(2, int(os.environ.get("PATHWAY_WIRE_RING_DEPTH", "2")))
             self._wire_ring = DeviceRing(depth=depth, name="sentence_encoder.wire")
-        ids_dev, lens_dev = self._wire_ring.stage(
-            [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]
-        )
-        from ..internals.chip_ledger import CHIP_LEDGER
+        with _span("embed_dispatch", rows=ids.shape[0]):
+            ids_dev, lens_dev = self._wire_ring.stage(
+                [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]
+            )
+            from ..internals.chip_ledger import CHIP_LEDGER
 
-        if CHIP_LEDGER.on():
-            # chip-time accounting syncs the dispatch to read the clock
-            # (the opt-in trade: exact encode device-seconds for lost
-            # dispatch pipelining); jit compiles nested in this window
-            # book under `compile`, not here
-            with CHIP_LEDGER.timed("encode"):
+            if CHIP_LEDGER.on():
+                # chip-time accounting syncs the dispatch to read the clock
+                # (the opt-in trade: exact encode device-seconds for lost
+                # dispatch pipelining); jit compiles nested in this window
+                # book under `compile`, not here
+                with CHIP_LEDGER.timed("encode"):
+                    out = self._fwd_group(self.params, ids_dev, lens_dev)
+                    jax.block_until_ready(out)
+            else:
                 out = self._fwd_group(self.params, ids_dev, lens_dev)
-                jax.block_until_ready(out)
-        else:
-            out = self._fwd_group(self.params, ids_dev, lens_dev)
-        self._wire_ring.retire([ids_dev, lens_dev])
+            self._wire_ring.retire([ids_dev, lens_dev])
         self._record_dispatch(ids.shape[0], ids.shape[1], lens)
         return out
 
@@ -411,7 +417,10 @@ class SentenceEncoder:
             return embs
         ids_mat, lens = m
         n_out = pad_to or len(lens)
-        packed = self._pack_segments(ids_mat, lens)
+        with _span("embed_pack"):
+            # the packed path's one dispatch is part of this span; the
+            # rows are counted by the path that packs them
+            packed = self._pack_segments(ids_mat, lens)
         if packed is None:
             packed = self._pack_uniform(ids_mat, lens)
         if packed is None:
@@ -608,15 +617,17 @@ class SentenceEncoder:
         import jax
         import jax.numpy as jnp
 
-        order = np.argsort(lens, kind="stable")
-        G = n // B
-        ln = lens[order].reshape(G, B).astype(np.int32)
+        with _span("embed_pack", rows=n):
+            order = np.argsort(lens, kind="stable")
+            G = n // B
+            ln = lens[order].reshape(G, B).astype(np.int32)
         parts = []
         for g in range(G):
-            grp = order[g * B : (g + 1) * B]
-            # sorted ascending, so the group's last row holds its max
-            Lg = min(bucket(int(ln[g, -1]), DEFAULT_SEQ_BUCKETS), ids_mat.shape[1])
-            ids_g = np.take(ids_mat[:, :Lg], grp, axis=0).astype(np.int16)
+            with _span("embed_pack"):
+                grp = order[g * B : (g + 1) * B]
+                # sorted ascending, so the group's last row holds its max
+                Lg = min(bucket(int(ln[g, -1]), DEFAULT_SEQ_BUCKETS), ids_mat.shape[1])
+                ids_g = np.take(ids_mat[:, :Lg], grp, axis=0).astype(np.int16)
             parts.append(self._run_group(ids_g, ln[g]))
         embs = jnp.concatenate(parts, axis=0)  # (n, dim), device-resident
         return order, embs

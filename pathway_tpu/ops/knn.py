@@ -36,6 +36,7 @@ from typing import Any, Callable
 import numpy as np
 
 from ..freshness.plane import FRESHNESS
+from ..tracing import span as _span
 
 _NEG = -3.0e38
 
@@ -309,18 +310,19 @@ def _scatter_dev_fn() -> Callable:
 
         @partial(jax.jit, static_argnames=("l2", "normalize"), donate_argnums=(0, 1, 2))
         def scatter_dev(matrix, valid, bias, slots, vecs, l2, normalize):
-            vecs = vecs.astype(matrix.dtype)
-            if normalize:
-                norms = jnp.sqrt(jnp.sum(vecs * vecs, axis=1, keepdims=True))
-                vecs = vecs / jnp.maximum(norms, 1e-12)
-            matrix = matrix.at[slots].set(vecs, mode="drop")
-            valid = valid.at[slots].set(True, mode="drop")
-            b = (
-                -jnp.sum(vecs * vecs, axis=1)
-                if l2
-                else jnp.zeros(slots.shape, bias.dtype)
-            )
-            bias = bias.at[slots].set(b, mode="drop")
+            with jax.named_scope("pw.index.scatter"):
+                vecs = vecs.astype(matrix.dtype)
+                if normalize:
+                    norms = jnp.sqrt(jnp.sum(vecs * vecs, axis=1, keepdims=True))
+                    vecs = vecs / jnp.maximum(norms, 1e-12)
+                matrix = matrix.at[slots].set(vecs, mode="drop")
+                valid = valid.at[slots].set(True, mode="drop")
+                b = (
+                    -jnp.sum(vecs * vecs, axis=1)
+                    if l2
+                    else jnp.zeros(slots.shape, bias.dtype)
+                )
+                bias = bias.at[slots].set(b, mode="drop")
             return matrix, valid, bias
 
         _UPDATE_JIT["scatter_dev"] = scatter_dev
@@ -366,8 +368,9 @@ def _scatter_tomb_fn() -> Callable:
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def scatter_tomb(valid, bias, slots):
-            valid = valid.at[slots].set(False, mode="drop")
-            bias = bias.at[slots].set(_PNEG, mode="drop")
+            with jax.named_scope("pw.index.tomb"):
+                valid = valid.at[slots].set(False, mode="drop")
+                bias = bias.at[slots].set(_PNEG, mode="drop")
             return valid, bias
 
         _UPDATE_JIT["scatter_tomb"] = scatter_tomb
@@ -457,17 +460,18 @@ def _mesh_fns(mesh) -> dict[str, Callable]:
     @partial(jax.jit, static_argnames=("l2", "normalize"), donate_argnums=(0, 1, 2))
     def scatter_dev(matrix, valid, bias, slots, vecs, l2, normalize):
         def body(m, v, b, s, vc):
-            vc = vc.astype(m.dtype)
-            if normalize:
-                norms = jnp.sqrt(jnp.sum(vc * vc, axis=1, keepdims=True))
-                vc = vc / jnp.maximum(norms, 1e-12)
-            loc = _local_slots(s, m.shape[0])
-            m = m.at[loc].set(vc, mode="drop")
-            v = v.at[loc].set(True, mode="drop")
-            bb = (
-                -jnp.sum(vc * vc, axis=1) if l2 else jnp.zeros(s.shape, b.dtype)
-            )
-            b = b.at[loc].set(bb, mode="drop")
+            with jax.named_scope("pw.index.scatter"):
+                vc = vc.astype(m.dtype)
+                if normalize:
+                    norms = jnp.sqrt(jnp.sum(vc * vc, axis=1, keepdims=True))
+                    vc = vc / jnp.maximum(norms, 1e-12)
+                loc = _local_slots(s, m.shape[0])
+                m = m.at[loc].set(vc, mode="drop")
+                v = v.at[loc].set(True, mode="drop")
+                bb = (
+                    -jnp.sum(vc * vc, axis=1) if l2 else jnp.zeros(s.shape, b.dtype)
+                )
+                b = b.at[loc].set(bb, mode="drop")
             return m, v, b
 
         return jax.shard_map(
@@ -481,9 +485,10 @@ def _mesh_fns(mesh) -> dict[str, Callable]:
     @partial(jax.jit, donate_argnums=(0, 1))
     def tomb(valid, bias, slots):
         def body(v, b, s):
-            loc = _local_slots(s, v.shape[0])
-            v = v.at[loc].set(False, mode="drop")
-            b = b.at[loc].set(_PNEG, mode="drop")
+            with jax.named_scope("pw.index.tomb"):
+                loc = _local_slots(s, v.shape[0])
+                v = v.at[loc].set(False, mode="drop")
+                b = b.at[loc].set(_PNEG, mode="drop")
             return v, b
 
         return jax.shard_map(
@@ -578,6 +583,48 @@ def _mesh_fns(mesh) -> dict[str, Callable]:
     }
     _MESH_JIT[mesh] = fns
     return fns
+
+
+def _fused_query_fn(module, cfg) -> Callable:
+    """The text-query program: encode -> score every row -> top-k, one
+    dispatch. ``cfg`` picks the whole-layer kernel where it applies."""
+    import jax
+    import jax.numpy as jnp
+    from functools import partial
+
+    @partial(jax.jit, static_argnames=("k", "l2"))
+    def fused(params, ids, lens, matrix, valid, k, l2):
+        with jax.named_scope("pw.query.encode"):
+            mask = jnp.arange(ids.shape[1])[None, :] < lens[:, None]
+            use_fused_layer = False
+            if cfg is not None:
+                from ..ops.fused_layer import use_fused_encoder
+
+                use_fused_layer = use_fused_encoder(cfg, ids.shape[1])
+            if use_fused_layer:
+                from ..ops.fused_layer import encoder_forward
+
+                emb = encoder_forward(params, cfg, ids, mask)
+            else:
+                emb = module.apply(params, ids, mask)  # [q, dim], L2-normed
+        with jax.named_scope("pw.query.scan"):
+            scores = emb @ matrix.T
+            if l2:
+                sq = jnp.sum(matrix * matrix, axis=1)
+                scores = 2.0 * scores - sq[None, :] - 1.0  # |emb|=1
+            scores = jnp.where(valid[None, :], scores, _NEG)
+        with jax.named_scope("pw.query.topk"):
+            vals, idx = jax.lax.top_k(scores, k)
+            # ONE packed host transfer: bitcast(scores) | idx — two
+            # separate np.asarray pulls pay the device->host
+            # round-trip twice per epoch. Packed as int32, not f32:
+            # a small index bitcast to f32 is a denormal, which the
+            # TPU flushes to zero (every hit then names slot 0)
+            return jnp.concatenate(
+                [jax.lax.bitcast_convert_type(vals, jnp.int32), idx], axis=1
+            )
+
+    return fused
 
 
 class DeviceKnnIndex:
@@ -688,6 +735,13 @@ class DeviceKnnIndex:
         v = self._valid_host.reshape(self.n_shards, self.shard_capacity)
         return [int(n) for n in v.sum(axis=1)]
 
+    def _publish(self, shards) -> None:
+        """What every write tells the other planes: the freshness
+        watermark of the shards touched, the index gauges, the ledger."""
+        with _span("index_publish"):
+            FRESHNESS.note_index_add(self, shards)
+            self._publish_metrics()
+
     def _publish_metrics(self) -> None:
         from .index_metrics import INDEX_METRICS
 
@@ -758,9 +812,7 @@ class DeviceKnnIndex:
         if n != len(vecs):
             raise ValueError("keys/vectors length mismatch")
         self._check_fence()
-        for key in keys:
-            if key in self._slot_of:
-                self.remove(key)
+        self._remove_replaced(keys)
         slots = self._alloc_slots(keys)
         if self.metric == "cos" and not self._import_raw:
             norms = np.linalg.norm(vecs, axis=1, keepdims=True)
@@ -776,8 +828,17 @@ class DeviceKnnIndex:
         if not self._full:
             for i, slot in enumerate(slots):
                 self._pending[slot] = vecs[i]
-        FRESHNESS.note_index_add(self, {s // self.shard_capacity for s in slots})
-        self._publish_metrics()
+        self._publish({s // self.shard_capacity for s in slots})
+
+    def _remove_replaced(self, keys) -> None:
+        """A key added again replaces its row: the old one goes first."""
+        replaced = [key for key in keys if key in self._slot_of]
+        if replaced:
+            # a stage of its own, so that a reader can take the removes
+            # nested in an add out of ``index_remove``'s seconds
+            with _span("index_replace", rows=len(replaced)):
+                for key in replaced:
+                    self.remove(key)
 
     def add_batch_device(self, keys, dev_vectors, metadatas=None) -> None:
         """Bulk insert of embeddings that already live in HBM (a jax
@@ -791,9 +852,13 @@ class DeviceKnnIndex:
         streaming epochs of arbitrary size reuse a bounded set of
         compiled scatter programs; the pad rows scatter out of bounds
         and drop."""
-        n = len(keys)
-        if n == 0:
+        if len(keys) == 0:
             return
+        with _span("index_add", new_trace=True, rows=len(keys)):
+            self._add_batch_device(keys, dev_vectors, metadatas)
+
+    def _add_batch_device(self, keys, dev_vectors, metadatas) -> None:
+        n = len(keys)
         self._check_fence()
         if self._full or self._dev_matrix is None:
             if not self._slot_of and not self._pending:
@@ -817,9 +882,7 @@ class DeviceKnnIndex:
                 # host rows already exist: one full upload, then scatter
                 # the device batch into it
                 self._upload_full()
-        for key in keys:
-            if key in self._slot_of:
-                self.remove(key)
+        self._remove_replaced(keys)
         alloc = self._alloc_slots(keys)
         if self._full:  # growth fell back to a host re-upload
             for s, key in zip(alloc, keys):  # hand slots back; arrays re-alloc
@@ -832,23 +895,16 @@ class DeviceKnnIndex:
         pad_slot = max(int(self._dev_matrix.shape[0]), self.capacity)
         slots = np.full((nv,), pad_slot, np.int32)  # pad rows drop
         slots[:n] = alloc
-        if self.mesh is not None:
-            # replicated slots broadcast over the mesh; each shard keeps
-            # only the rows the hash router assigned to it (everything
-            # else maps out of the local slab and drops)
-            self._dev_matrix, self._dev_valid, self._dev_bias = _mesh_fns(self.mesh)[
-                "scatter_dev"
-            ](
-                self._dev_matrix,
-                self._dev_valid,
-                self._dev_bias,
-                slots,
-                dev_vectors,
-                l2=self.metric == "l2",
-                normalize=self.metric == "cos",
-            )
-        else:
-            self._dev_matrix, self._dev_valid, self._dev_bias = _scatter_dev_fn()(
+        # replicated slots broadcast over the mesh; each shard keeps
+        # only the rows the hash router assigned to it (everything
+        # else maps out of the local slab and drops)
+        scatter_dev = (
+            _mesh_fns(self.mesh)["scatter_dev"]
+            if self.mesh is not None
+            else _scatter_dev_fn()
+        )
+        with _span("index_scatter", rows=n):
+            self._dev_matrix, self._dev_valid, self._dev_bias = scatter_dev(
                 self._dev_matrix,
                 self._dev_valid,
                 self._dev_bias,
@@ -865,12 +921,13 @@ class DeviceKnnIndex:
             self._slot_of[key] = int(slot)
             if metadatas is not None and metadatas[i] is not None:
                 self._meta[key] = metadatas[i]
-        FRESHNESS.note_index_add(
-            self, {int(s) // self.shard_capacity for s in real}
-        )
-        self._publish_metrics()
+        self._publish({int(s) // self.shard_capacity for s in real})
 
     def remove(self, key) -> None:
+        with _span("index_remove", rows=1):
+            self._remove(key)
+
+    def _remove(self, key) -> None:
         self._check_fence()
         slot = self._slot_of.pop(key, None)
         if slot is None:
@@ -883,8 +940,7 @@ class DeviceKnnIndex:
         self._docs_shard[shard] -= 1
         if not self._full:
             self._pending[slot] = None
-        FRESHNESS.note_index_add(self, (shard,))
-        self._publish_metrics()
+        self._publish((shard,))
 
     # --- elastic reshard protocol (elastic/controller.py drives) ---
 
@@ -1092,6 +1148,10 @@ class DeviceKnnIndex:
     def _flush_pending(self) -> None:
         if not self._pending:
             return
+        with _span("index_flush", rows=len(self._pending)):
+            self._scatter_pending()
+
+    def _scatter_pending(self) -> None:
         n_rows = max(int(self._dev_matrix.shape[0]), self.capacity)
         m = len(self._pending)
         mb = _k_bucket(m)
@@ -1321,24 +1381,27 @@ class DeviceKnnIndex:
             scores = np.asarray(scores)
             idx = np.asarray(idx)
             next_todo = []
-            for row, qi in enumerate(todo):
-                flt = filter_fns[qi] if filter_fns is not None else None
-                out: list[tuple[Any, float]] = []
-                for s, slot in zip(scores[row], idx[row]):
-                    if s <= _NEG / 2:
-                        break
-                    key = self._keys[slot]
-                    if key is None:
-                        continue
-                    if flt is not None and not _apply_filter(flt, self._meta.get(key)):
-                        continue
-                    out.append((key, float(s)))
-                    if len(out) == k:
-                        break
-                results[qi] = out
-                if len(out) < min(k, len(self._slot_of)) and fetch < self.capacity:
-                    # filters ate too many candidates — refetch deeper
-                    next_todo.append(qi)
+            with _span("query_resolve", queries=len(todo)):
+                for row, qi in enumerate(todo):
+                    flt = filter_fns[qi] if filter_fns is not None else None
+                    out: list[tuple[Any, float]] = []
+                    for s, slot in zip(scores[row], idx[row]):
+                        if s <= _NEG / 2:
+                            break
+                        key = self._keys[slot]
+                        if key is None:
+                            continue
+                        if flt is not None and not _apply_filter(
+                            flt, self._meta.get(key)
+                        ):
+                            continue
+                        out.append((key, float(s)))
+                        if len(out) == k:
+                            break
+                    results[qi] = out
+                    if len(out) < min(k, len(self._slot_of)) and fetch < self.capacity:
+                        # filters ate too many candidates — refetch deeper
+                        next_todo.append(qi)
             if next_todo:
                 fetch = min(fetch * 4, self.capacity)
                 todo = next_todo
@@ -1416,17 +1479,32 @@ class DeviceKnnIndex:
         enc = getattr(self, "_encoder", None)
         if len(self._slot_of) == 0 or len(texts) == 0:
             return [[] for _ in range(len(texts))]
-        texts = ["" if t is None else str(t) for t in texts]
         if enc is None:
             raise RuntimeError("search_texts_batch requires attach_encoder()")
-        m = enc.tokenizer.batch_encode_matrix(texts, enc.max_seq_len)
+        texts = ["" if t is None else str(t) for t in texts]
+        with _span("query_batch", new_trace=True, queries=len(texts), k=k):
+            return self._search_texts(enc, texts, k, filter_fns)
+
+    def _search_texts(self, enc, texts, k, filter_fns):
+        n = len(texts)
+        with _span("query_tokenize", queries=n):
+            m = enc.tokenizer.batch_encode_matrix(texts, enc.max_seq_len)
+            if m is not None and self.mesh is None:
+                from ..models.batching import DEFAULT_SEQ_BUCKETS, bucket
+
+                ids_mat, lens = m
+                L = min(bucket(int(lens.max()), DEFAULT_SEQ_BUCKETS), ids_mat.shape[1])
+                qb = _k_bucket(n)
+                ids = np.zeros((qb, L), ids_mat.dtype)
+                ids[:n] = ids_mat[:, :L]
+                lens_p = np.zeros((qb,), lens.dtype)
+                lens_p[:n] = lens
         if m is None or self.mesh is not None:
             # two dispatches: without the native tokenizer there is no
             # id matrix to feed the fused program; and over a mesh the
             # encoder (a Mosaic kernel on TPU, which XLA cannot partition
             # into the sharded score program) embeds on its own first
             return self.search_batch(np.asarray(enc.encode(texts)), k, filter_fns)
-        ids_mat, lens = m
         self._sync()
         # cache the fused program on the ENCODER (shared across index
         # instances): a warm-up index using the same embedder warms the
@@ -1435,70 +1513,26 @@ class DeviceKnnIndex:
         if self._fused_jit is None:
             self._fused_jit = getattr(enc, "_pw_fused_query_jit", None)
         if self._fused_jit is None:
-            import jax
-            import jax.numpy as jnp
-            from functools import partial
-
-            module = enc.module
-            cfg = getattr(enc, "cfg", None)
-
-            @partial(jax.jit, static_argnames=("k", "l2"))
-            def fused(params, ids, lens, matrix, valid, k, l2):
-                mask = jnp.arange(ids.shape[1])[None, :] < lens[:, None]
-                use_fused_layer = False
-                if cfg is not None:
-                    from ..ops.fused_layer import use_fused_encoder
-
-                    use_fused_layer = use_fused_encoder(cfg, ids.shape[1])
-                if use_fused_layer:
-                    from ..ops.fused_layer import encoder_forward
-
-                    emb = encoder_forward(params, cfg, ids, mask)
-                else:
-                    emb = module.apply(params, ids, mask)  # [q, dim], L2-normed
-                scores = emb @ matrix.T
-                if l2:
-                    sq = jnp.sum(matrix * matrix, axis=1)
-                    scores = 2.0 * scores - sq[None, :] - 1.0  # |emb|=1
-                scores = jnp.where(valid[None, :], scores, _NEG)
-                vals, idx = jax.lax.top_k(scores, k)
-                # ONE packed host transfer: bitcast(scores) | idx — two
-                # separate np.asarray pulls pay the device->host
-                # round-trip twice per epoch. Packed as int32, not f32:
-                # a small index bitcast to f32 is a denormal, which the
-                # TPU flushes to zero (every hit then names slot 0)
-                return jnp.concatenate(
-                    [jax.lax.bitcast_convert_type(vals, jnp.int32), idx], axis=1
-                )
-
-            self._fused_jit = fused
-            enc._pw_fused_query_jit = fused
-
-        from ..models.batching import DEFAULT_SEQ_BUCKETS, bucket
-
-        n = len(texts)
-        L = min(bucket(int(lens.max()), DEFAULT_SEQ_BUCKETS), ids_mat.shape[1])
-        qb = _k_bucket(n)
-        ids = np.zeros((qb, L), ids_mat.dtype)
-        ids[:n] = ids_mat[:, :L]
-        lens_p = np.zeros((qb,), lens.dtype)
-        lens_p[:n] = lens
+            self._fused_jit = enc._pw_fused_query_jit = _fused_query_fn(
+                enc.module, getattr(enc, "cfg", None)
+            )
 
         def dispatch(todo, fetch):
             # the fused kernel scores every query each pass; refills
             # (rare, filter starvation) just deepen fetch for all
             kk = min(fetch, self.capacity)
-            packed = np.asarray(
-                self._fused_jit(
-                    enc.params,
-                    ids,
-                    lens_p,
-                    self._dev_matrix,
-                    self._dev_valid,
-                    k=kk,
-                    l2=self.metric == "l2",
+            with _span("query_device", queries=n):
+                packed = np.asarray(
+                    self._fused_jit(
+                        enc.params,
+                        ids,
+                        lens_p,
+                        self._dev_matrix,
+                        self._dev_valid,
+                        k=kk,
+                        l2=self.metric == "l2",
+                    )
                 )
-            )
             return packed[:, :kk].view(np.float32)[todo], packed[:, kk:][todo]
 
         return self._assemble(n, k, filter_fns, dispatch)

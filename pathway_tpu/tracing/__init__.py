@@ -5,10 +5,18 @@ wait, adaptive-batch fan-in (batch spans *link* their member request
 traces), mesh per-shard top-k + on-device merge, tiered hot/cold
 probes, reranking, and per-tick decode steps — with p99 exemplar
 retention, a tail-attribution aggregator, OTLP export, and the
-``pathway trace`` CLI. See README "Request tracing".
+``pathway trace`` CLI. The device plane's write and text-query paths
+(``ops/knn.py``, ``models/sentence_encoder.py``, the embedder's
+``encode_device``) go through the same
+:class:`span`: with no request bound, a write batch, an embed batch or
+a query batch is the journey. See README "Request tracing".
 
-Enable with ``pw.run(tracing=True)`` or ``PATHWAY_TRACING=1``; with
-tracing off every instrumentation site is a single flag check.
+On with ``pw.run(tracing=True)`` or ``PATHWAY_TRACING=1``, and for as
+long as a ``jax.profiler`` session runs: every span is then also a
+``TraceAnnotation("pw.<stage>")`` in the profile, on the device
+trace's clock. With tracing off every instrumentation site is a single
+check. :func:`stage_totals` reads each stage's calls, seconds and work
+units (``rows``, ``queries``, ``tokens``).
 """
 
 from __future__ import annotations
@@ -59,8 +67,16 @@ __all__ = [
     "set_worker",
     "slow_report",
     "span",
+    "stage_totals",
     "tracing_enabled",
 ]
+
+
+def stage_totals() -> dict[str, dict]:
+    """``{stage: {"calls", "seconds", "rows", "queries", "tokens"}}``
+    over every span finished since tracing came on (or the last
+    ``TRACING_METRICS.reset()``), summed over workers."""
+    return TRACING_METRICS.totals()
 
 
 def ensure_trace() -> TraceContext | None:

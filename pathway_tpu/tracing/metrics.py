@@ -9,6 +9,11 @@ serving plane's request-latency scale. Each bucket remembers the last
 trace id that landed in it, rendered as an OpenMetrics exemplar
 (``... # {trace_id="..."} value timestamp``) so a dashboard's p99
 bucket links straight to ``pathway trace show <id>``.
+
+Beside calls and seconds a stage's totals hold the sums of the work
+units its sites pass as span attributes (``rows``, ``queries``,
+``tokens``), so a per-layer ratio is taken where the work happens;
+:meth:`TracingMetrics.totals` reads them, summed over workers.
 """
 
 from __future__ import annotations
@@ -18,12 +23,15 @@ import time as _time
 
 from ..serving.metrics import STAGE_BUCKETS
 
+#: span attributes that count work: summed into the stage's totals
+WORK_UNITS = ("rows", "queries", "tokens")
+
 
 class _ExemplarHistogram:
     """Fixed-bucket histogram where every bucket keeps its most recent
     (trace_id, value, unix_ts) exemplar."""
 
-    __slots__ = ("counts", "total", "count", "exemplars")
+    __slots__ = ("counts", "total", "count", "exemplars", "units")
 
     def __init__(self) -> None:
         self.counts = [0] * (len(STAGE_BUCKETS) + 1)
@@ -32,8 +40,9 @@ class _ExemplarHistogram:
         )
         self.total = 0.0
         self.count = 0
+        self.units = dict.fromkeys(WORK_UNITS, 0)
 
-    def observe(self, seconds: float, trace_id: str) -> None:
+    def observe(self, seconds: float, trace_id: str, units: dict | None = None) -> None:
         seconds = max(0.0, float(seconds))
         idx = len(STAGE_BUCKETS)
         for i, le in enumerate(STAGE_BUCKETS):
@@ -45,6 +54,11 @@ class _ExemplarHistogram:
             self.exemplars[idx] = (trace_id, seconds, _time.time())
         self.total += seconds
         self.count += 1
+        if units:
+            for name in WORK_UNITS:
+                n = units.get(name)
+                if n:
+                    self.units[name] += int(n)
 
     def cumulative(self) -> list[tuple[str, int, tuple[str, float, float] | None]]:
         """(le, cumulative count, bucket exemplar) ending at +Inf."""
@@ -66,14 +80,22 @@ class TracingMetrics:
         self._hists: dict[tuple[str, int], _ExemplarHistogram] = {}
 
     def observe(
-        self, stage: str, seconds: float, trace_id: str, *, worker: int = 0
+        self,
+        stage: str,
+        seconds: float,
+        trace_id: str,
+        *,
+        worker: int = 0,
+        units: dict | None = None,
     ) -> None:
+        """One finished span of ``stage``. ``units`` is the span's
+        attributes: those named in :data:`WORK_UNITS` add to the totals."""
         key = (stage, int(worker))
         with self._lock:
             hist = self._hists.get(key)
             if hist is None:
                 hist = self._hists[key] = _ExemplarHistogram()
-            hist.observe(seconds, trace_id)
+            hist.observe(seconds, trace_id, units)
 
     def active(self) -> bool:
         """Anything to render? (keeps /metrics byte-identical for runs
@@ -105,10 +127,26 @@ class TracingMetrics:
                 f"{stage}[w{worker}]": {
                     "count": h.count,
                     "sum": round(h.total, 6),
+                    **{name: n for name, n in h.units.items() if n},
                 }
                 for (stage, worker), h in sorted(self._hists.items())
                 if h.count
             }
+
+    def totals(self) -> dict[str, dict]:
+        """``{stage: {"calls", "seconds", "rows", "queries", "tokens"}}``,
+        summed over workers: what a per-layer metric divides."""
+        out: dict[str, dict] = {}
+        with self._lock:
+            for (stage, _worker), h in self._hists.items():
+                t = out.setdefault(
+                    stage, {"calls": 0, "seconds": 0.0, **dict.fromkeys(WORK_UNITS, 0)}
+                )
+                t["calls"] += h.count
+                t["seconds"] += h.total
+                for name, n in h.units.items():
+                    t[name] += n
+        return out
 
     def reset(self) -> None:
         with self._lock:

@@ -1,10 +1,14 @@
 """Span recording and the bounded trace store.
 
-Recording is gated on one process-wide flag (``pw.run(tracing=...)`` /
-``PATHWAY_TRACING``): with tracing off a :class:`span` block costs one
-attribute read and records nothing, so the serving hot path stays
-within its <5% overhead budget and ``/metrics`` output is byte-identical
-to a build without the plane.
+Recording is gated on one process-wide switch: the flag
+(``pw.run(tracing=...)`` / ``PATHWAY_TRACING``) or a running
+``jax.profiler`` session. With tracing off a :class:`span` block costs
+the check and records nothing, so the serving hot path stays within its
+<5% overhead budget and ``/metrics`` output is byte-identical to a build
+without the plane. While a profiler session runs every :class:`span` is
+also a ``jax.profiler.TraceAnnotation("pw.<stage>")``: the program's
+spans land in the same xplane as the device's ``XLA Ops`` line, on its
+clock.
 
 The :class:`TraceStore` keeps completed spans in a bounded ring (like
 the flight recorder's event ring) plus **p99 exemplar retention**: when
@@ -30,6 +34,7 @@ import heapq
 import itertools
 import json
 import os
+import sys
 import threading
 import time as _time
 from collections import deque
@@ -42,9 +47,25 @@ TRACE_DUMP_FORMAT_VERSION = 1
 
 _ENABLED = _env_flag("PATHWAY_TRACING", False)
 
+#: ``jax.profiler.TraceAnnotation``, once JAX is there to ask
+_ANNOTATION = None
+
+
+def _profiling() -> bool:
+    """Is a ``jax.profiler`` session running? Asked only of a JAX some
+    other module has imported, so ``import pathway_tpu`` stays jax-free:
+    no session can run before JAX is loaded."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return False
+        _ANNOTATION = profiler.TraceAnnotation
+    return _ANNOTATION.is_enabled()
+
 
 def tracing_enabled() -> bool:
-    return _ENABLED
+    return _ENABLED or _profiling()
 
 
 def set_tracing_enabled(on: bool) -> bool:
@@ -238,7 +259,9 @@ class TraceStore:
                 self._retain(sp.trace_id, completed, sp.duration_s)
         from .metrics import TRACING_METRICS
 
-        TRACING_METRICS.observe(sp.stage, sp.duration_s, sp.trace_id, worker=sp.worker)
+        TRACING_METRICS.observe(
+            sp.stage, sp.duration_s, sp.trace_id, worker=sp.worker, units=sp.attrs
+        )
 
     def _retain(self, trace_id: str, spans: list[Span], wall_s: float) -> None:
         """Exemplar retention (caller holds the lock): the slowest-N
@@ -449,16 +472,40 @@ TRACE_STORE = TraceStore()
 # -- recording helpers ----------------------------------------------------
 
 
+class _Off:
+    """What :class:`span` hands out while tracing is off."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        return None
+
+
+_OFF = _Off()
+
+
 class span:
     """``with span("stage", attr=...) as sp:`` — record one stage of
     the current request journey.
 
-    No-op (yields None) when tracing is off or no trace context is
-    bound, unless ``new_trace=True`` (the admission path: a request
-    that arrived without a ``traceparent`` starts its journey here).
-    While the block runs, the child context is bound so nested spans
-    parent correctly — the same scoping ``bind_deadline`` gives the
-    request deadline.
+    With tracing off this is one check: the shared no-op comes back and
+    nothing is built. On, the block's wall (``time.perf_counter``) and
+    its work units (the attributes ``rows``, ``queries``, ``tokens``)
+    add to the stage's totals, and under a ``jax.profiler`` session the
+    block is a ``TraceAnnotation("pw.<stage>")`` in the profile.
+
+    A :class:`Span` with ids is built (and yielded) only where there is
+    a journey to hang it on: a trace context is bound, or
+    ``new_trace=True`` — the admission path, where a request that
+    arrived without a ``traceparent`` starts its journey, and the batch
+    boundaries of the device plane, where with no request the batch is
+    the request. Elsewhere the block yields None and leaves its totals
+    and its annotation, nothing in the ring. While the block runs, the
+    child context is bound so nested spans parent correctly — the same
+    scoping ``bind_deadline`` gives the request deadline.
 
     ``boundary=True`` marks the process-entry span of a journey (the
     HTTP request span): finishing it completes the trace for exemplar
@@ -475,7 +522,14 @@ class span:
         "_attrs",
         "_sp",
         "_token",
+        "_annotation",
+        "_t0",
     )
+
+    def __new__(cls, stage: str, **kwargs):
+        if not tracing_enabled():
+            return _OFF
+        return super().__new__(cls)
 
     def __init__(
         self,
@@ -495,10 +549,14 @@ class span:
         self._attrs = attrs
         self._sp: Span | None = None
         self._token = None
+        self._annotation = None
+        self._t0 = 0.0
 
     def __enter__(self) -> Span | None:
-        if not _ENABLED:
-            return None
+        if _profiling():
+            self._annotation = _ANNOTATION("pw." + self._stage)
+            self._annotation.__enter__()
+        self._t0 = _time.perf_counter()
         parent = self._ctx if self._ctx is not None else current_trace()
         if parent is None:
             if not self._new_trace:
@@ -512,7 +570,7 @@ class span:
             parent_id,
             self._stage,
             worker=TRACE_STORE.worker,
-            attrs=dict(self._attrs) if self._attrs else {},
+            attrs=self._attrs,
             links=self._links,
         )
         sp.boundary = self._boundary
@@ -523,15 +581,24 @@ class span:
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if self._sp is None:
-            return
-        if self._token is not None:
+        seconds = _time.perf_counter() - self._t0
+        sp, self._sp = self._sp, None
+        if sp is None:
+            from .metrics import TRACING_METRICS
+
+            TRACING_METRICS.observe(
+                self._stage, seconds, "", worker=TRACE_STORE.worker, units=self._attrs
+            )
+        else:
             self._token.__exit__()
             self._token = None
-        if exc is not None:
-            self._sp.attrs["error"] = type(exc).__name__
-        TRACE_STORE.finish(self._sp)
-        self._sp = None
+            if exc is not None:
+                sp.attrs["error"] = type(exc).__name__
+            sp.duration_s = seconds
+            TRACE_STORE.finish(sp)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
 
 
 def record_span(
@@ -555,7 +622,7 @@ def record_span(
     it eligible for exemplar retention. Embedded callers (bench
     drivers) use this: they admit and submit with a trace context, then
     close the journey root once the async dispatch finishes."""
-    if not _ENABLED:
+    if not tracing_enabled():
         return None
     if root_of is not None:
         trace_id, parent_id, span_id = root_of.trace_id, "", root_of.span_id

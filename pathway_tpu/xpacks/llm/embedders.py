@@ -20,6 +20,7 @@ import numpy as np
 
 from ...internals import udfs
 from ...internals.expression import ColumnExpression
+from ...tracing import span as _span
 from ._utils import _coerce_sync, coerce_async
 
 
@@ -75,7 +76,12 @@ class SentenceTransformerEmbedder(BaseEmbedder):
     def encode_device(self, texts, pad_to: int | None = None):
         """Batch ingest surface: texts -> DEVICE-resident [n, dim] jax
         array (no host round-trip; feeds the on-device KNN index)."""
-        return self._encoder.encode_device(texts, pad_to=pad_to)
+        # the embed batch's boundary stands here, not in the encoder:
+        # encode_device halves large batches by calling itself, and a
+        # span threaded through that recursion cost 3 s of a 25 s
+        # set-up on the chip (PERF.md, PR 26)
+        with _span("embed_batch", new_trace=True, rows=len(texts)):
+            return self._encoder.encode_device(texts, pad_to=pad_to)
 
     def encode_device_many(self, batches, pad_to: int | None = None) -> list:
         """Staged multi-epoch ingest: >= 2 pending input batches drain

@@ -1,0 +1,359 @@
+"""The device plane's spans: ``tracing.span`` on the index's write path,
+the embedder's ingest path and the fused text-query path.
+
+With tracing off a site is one check and leaves nothing. On, a write
+batch, an embed batch and a query batch are journeys of their own when
+no request is bound; a bare ``remove(key)`` adds to its stage's totals
+and builds no ``Span``. A ``jax.profiler`` session turns tracing on by
+itself and every span is then a ``pw.<stage>`` event in the profile.
+The named scopes inside the device programs change no operation.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import glob
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from pathway_tpu import tracing
+from pathway_tpu.models.encoder import EncoderConfig
+from pathway_tpu.models.sentence_encoder import SentenceEncoder
+from pathway_tpu.ops import knn
+from pathway_tpu.tracing import TRACE_STORE, TRACING_METRICS, set_tracing_enabled, span, stage_totals
+from pathway_tpu.tracing import store as trace_store
+from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
+
+WRITE_STAGES = {
+    "index_remove", "index_publish", "embed_batch", "embed_tokenize", "embed_pack",
+    "embed_dispatch", "index_add", "index_flush", "index_scatter",
+}
+QUERY_STAGES = {"query_batch", "query_tokenize", "query_device", "query_resolve"}
+
+
+@pytest.fixture(autouse=True)
+def _tracing_sandbox():
+    prev = set_tracing_enabled(False)
+    TRACE_STORE.reset()
+    TRACING_METRICS.reset()
+    yield
+    set_tracing_enabled(prev)
+    TRACE_STORE.reset()
+    TRACING_METRICS.reset()
+
+
+@pytest.fixture(scope="module")
+def enc():
+    return SentenceEncoder(config=EncoderConfig(num_layers=1), max_seq_len=32, max_batch=8)
+
+
+@pytest.fixture(scope="module")
+def embedder(enc):
+    """What ``VectorStoreServer`` hands the index, over the one-layer
+    encoder: seconds, not minutes, on the CPU."""
+    emb = SentenceTransformerEmbedder(max_batch_size=8)
+    emb._encoder = enc
+    return emb
+
+
+DOCS = [f"document {i} speaks of subject {i % 7} at length" for i in range(24)]
+
+
+@pytest.fixture()
+def index(enc, embedder):
+    """24 standing rows, put there with tracing off and the programs of
+    the write and query paths warm."""
+    idx = knn.DeviceKnnIndex(dim=enc.dim, metric="cos", reserved_space=64)
+    idx.attach_encoder(enc)
+    idx.add_batch_device(list(range(24)), embedder.encode_device(DOCS), None)
+    idx.search_texts_batch(DOCS[:3], 3)
+    return idx
+
+
+def _write_batch(idx, embedder, keys):
+    for key in keys:
+        idx.remove(key)
+    texts = [DOCS[k] for k in keys]
+    idx.add_batch_device(keys, embedder.encode_device(texts), None)
+    return texts
+
+
+def _spans_by_stage():
+    out: dict[str, list[dict]] = {}
+    for s in TRACE_STORE.recent_spans(limit=4096):
+        out.setdefault(s["stage"], []).append(s)
+    return out
+
+
+# -- off -----------------------------------------------------------------
+
+
+def test_off_a_write_batch_and_a_query_leave_nothing(index, embedder):
+    from pathway_tpu.internals.http_monitoring import MonitoringHttpServer
+
+    before = (TRACE_STORE.spans_total, MonitoringHttpServer._tracing_lines())
+    _write_batch(index, embedder, [3, 4, 5])
+    got = index.search_texts_batch([DOCS[4], DOCS[9]], 3)
+    assert got[0][0][0] == 4 and got[1][0][0] == 9
+    assert stage_totals() == {}
+    assert not TRACING_METRICS.active() and not TRACE_STORE.active()
+    assert (TRACE_STORE.spans_total, MonitoringHttpServer._tracing_lines()) == before == (0, [])
+
+
+def test_off_a_site_is_one_check(monkeypatch):
+    """No ``Span``, no id, no annotation object, no lock: the shared
+    no-op comes back before anything is built."""
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("built with tracing off")
+
+    monkeypatch.setattr(trace_store, "Span", refuse)
+    monkeypatch.setattr(trace_store, "gen_trace_id", refuse)
+    monkeypatch.setattr(trace_store, "gen_span_id", refuse)
+    monkeypatch.setattr(trace_store, "_ANNOTATION", None)
+    monkeypatch.setattr(TRACING_METRICS, "_lock", None)
+    monkeypatch.setattr(TRACE_STORE, "_lock", None)
+    a, b = span("index_remove", rows=1), span("query_batch", new_trace=True, queries=3)
+    assert a is b and not isinstance(a, span)
+    with a as sp:
+        assert sp is None
+
+
+def test_import_and_the_check_stay_jax_free():
+    code = (
+        "import sys, pathway_tpu.tracing as t\n"
+        "assert not t.tracing_enabled()\n"
+        "with t.span('index_remove', rows=1) as sp: assert sp is None\n"
+        "assert 'jax' not in sys.modules and t.stage_totals() == {}\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "PATHWAY_TRACING"}
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, timeout=120)
+
+
+# -- on: stages, work units, ids ------------------------------------------
+
+
+def test_on_a_write_batch_gives_every_stage_with_its_units(index, enc, embedder):
+    set_tracing_enabled(True)
+    keys = [3, 4, 5, 6, 7]
+    texts = _write_batch(index, embedder, keys)
+    totals = stage_totals()
+    assert WRITE_STAGES <= set(totals) and not QUERY_STAGES & set(totals)
+    assert totals["index_remove"]["calls"] == totals["index_remove"]["rows"] == len(keys)
+    assert totals["index_publish"]["calls"] == len(keys) + 1  # each remove, then the add
+    assert totals["index_add"]["calls"] == 1 and totals["index_add"]["rows"] == len(keys)
+    assert totals["index_scatter"]["rows"] == len(keys)
+    assert totals["index_flush"]["rows"] == len(keys)  # the removes' tombstones
+    assert totals["embed_batch"]["calls"] == 1 and totals["embed_batch"]["rows"] == len(keys)
+    # real tokens, counted where they are made
+    real_tokens = sum(len(enc.tokenizer.encode(t, enc.max_seq_len)) for t in texts)
+    assert totals["embed_tokenize"]["tokens"] == real_tokens > 0
+    assert totals["embed_tokenize"]["rows"] == totals["embed_pack"]["rows"] == len(keys)
+    assert totals["embed_dispatch"]["calls"] == 1
+    for stage in WRITE_STAGES:
+        assert totals[stage]["seconds"] > 0
+    assert "index_replace" not in totals  # the keys were removed first
+
+
+def test_on_a_query_batch_counts_real_queries_not_padded(index):
+    set_tracing_enabled(True)
+    got = index.search_texts_batch([DOCS[4], DOCS[9], DOCS[11]], 3)
+    assert [row[0][0] for row in got] == [4, 9, 11]
+    totals = stage_totals()
+    assert set(totals) == QUERY_STAGES
+    for stage in QUERY_STAGES:
+        assert totals[stage]["calls"] == 1 and totals[stage]["queries"] == 3  # the program ran 8
+        assert totals[stage]["rows"] == totals[stage]["tokens"] == 0
+    inner = sum(totals[s]["seconds"] for s in QUERY_STAGES - {"query_batch"})
+    assert 0 < inner <= totals["query_batch"]["seconds"]
+
+
+@pytest.mark.parametrize(
+    "boundary, children",
+    [
+        ("embed_batch", {"embed_tokenize", "embed_pack", "embed_dispatch"}),
+        ("index_add", {"index_flush", "index_scatter", "index_publish"}),
+        ("query_batch", {"query_tokenize", "query_device", "query_resolve"}),
+    ],
+)
+def test_with_no_request_the_batch_is_the_journey(index, embedder, boundary, children):
+    set_tracing_enabled(True)
+    _write_batch(index, embedder, [8, 9])
+    index.search_texts_batch([DOCS[8]], 2)
+    spans = _spans_by_stage()
+    (root,) = spans[boundary]
+    assert root["parent"] == ""
+    for stage in children:
+        for child in spans[stage]:
+            assert (child["trace"], child["parent"]) == (root["trace"], root["span"])
+    # three batches, three journeys
+    assert len({spans[b][0]["trace"] for b in ("embed_batch", "index_add", "query_batch")}) == 3
+
+
+def test_under_a_request_the_batches_join_its_trace(index, embedder):
+    set_tracing_enabled(True)
+    with span("request", new_trace=True) as request:
+        _write_batch(index, embedder, [10])
+        index.search_texts_batch([DOCS[10]], 1)
+    spans = _spans_by_stage()
+    for stage in ("index_remove", "embed_batch", "index_add", "query_batch"):
+        (sp,) = spans[stage]
+        assert (sp["trace"], sp["parent"]) == (request.trace_id, request.span_id)
+    assert spans["index_publish"][0]["parent"] == spans["index_remove"][0]["span"]
+
+
+def test_a_bare_remove_builds_no_span(index):
+    set_tracing_enabled(True)
+    for key in range(12):
+        index.remove(key)
+    index.remove("never added")  # a call all the same
+    assert TRACE_STORE.spans_total == 0 and TRACE_STORE.traces_total == 0
+    assert TRACE_STORE.recent_spans() == [] and TRACE_STORE.exemplar_traces() == []
+    totals = stage_totals()
+    assert set(totals) == {"index_remove", "index_publish"}
+    assert totals["index_remove"]["calls"] == totals["index_remove"]["rows"] == 13
+    assert totals["index_publish"]["calls"] == 12
+    assert 0 < totals["index_publish"]["seconds"] <= totals["index_remove"]["seconds"]
+
+
+def test_replaced_keys_nest_in_the_add_and_can_be_taken_out(index, embedder):
+    """``index_replace`` holds the removes nested in an add, so that
+    remove + add - replace is the wall of the top-level calls."""
+    set_tracing_enabled(True)
+    index.remove(0)
+    index.add_batch_device([0, 1, 2], embedder.encode_device(DOCS[:3]), None)  # 1 and 2 are replaced
+    totals = stage_totals()
+    assert totals["index_remove"]["calls"] == 3
+    assert totals["index_replace"]["calls"] == 1 and totals["index_replace"]["rows"] == 2
+    spans = _spans_by_stage()
+    (add,), (replace,) = spans["index_add"], spans["index_replace"]
+    assert replace["parent"] == add["span"]
+    assert [s["parent"] for s in spans["index_remove"]] == [replace["span"]] * 2  # the bare one built none
+    nested = sum(s["dur_ms"] for s in spans["index_remove"]) / 1e3
+    assert nested <= totals["index_replace"]["seconds"] <= totals["index_add"]["seconds"]
+    assert len(index) == 24
+
+
+def test_embed_batch_is_one_span_over_the_encoder_s_halves(enc, embedder):
+    """40 texts at ``max_batch`` 8: ``SentenceEncoder.encode_device``
+    halves the batch by calling itself. The boundary stands in the
+    embedder's call, so the halves share one ``embed_batch``; the
+    encoder called bare leaves totals and builds no ``Span``."""
+    texts = [DOCS[i % 24] + f" copy {i}" for i in range(40)]
+    off = np.asarray(embedder.encode_device(texts))
+    set_tracing_enabled(True)
+    on = np.asarray(embedder.encode_device(texts))
+    np.testing.assert_array_equal(on, off)
+    totals = stage_totals()
+    assert totals["embed_batch"]["calls"] == 1 and totals["embed_batch"]["rows"] == 40
+    assert totals["embed_tokenize"]["calls"] > 1 and totals["embed_tokenize"]["rows"] == 40
+    assert totals["embed_tokenize"]["tokens"] == sum(len(enc.tokenizer.encode(t, 32)) for t in texts)
+    assert totals["embed_dispatch"]["calls"] == 5
+    spans = TRACE_STORE.spans_total
+    enc.encode_device(texts)
+    assert TRACE_STORE.spans_total == spans and stage_totals()["embed_batch"]["calls"] == 1
+    assert stage_totals()["embed_dispatch"]["calls"] == 10
+
+
+def test_totals_sum_workers_and_take_units_from_attributes():
+    TRACING_METRICS.observe("index_add", 0.25, "", worker=0, units={"rows": 7, "index": "docs"})
+    TRACING_METRICS.observe("index_add", 0.5, "ab" * 16, worker=1, units={"rows": 5, "tokens": 11})
+    TRACING_METRICS.observe("admission", 0.125, "")
+    assert stage_totals() == {
+        "index_add": {"calls": 2, "seconds": 0.75, "rows": 12, "queries": 0, "tokens": 11},
+        "admission": {"calls": 1, "seconds": 0.125, "rows": 0, "queries": 0, "tokens": 0},
+    }
+    assert TRACING_METRICS.snapshot()["index_add[w1]"] == {"count": 1, "sum": 0.5, "rows": 5, "tokens": 11}
+
+
+# -- the profiler's switch ------------------------------------------------
+
+
+def test_a_profiler_session_turns_tracing_on_and_names_the_spans(index, embedder, tmp_path, monkeypatch):
+    monkeypatch.delenv("PATHWAY_TRACING", raising=False)
+    assert not tracing.tracing_enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert tracing.tracing_enabled()
+        _write_batch(index, embedder, [3, 4])
+        index.search_texts_batch([DOCS[3]], 2)
+    finally:
+        jax.profiler.stop_trace()
+    assert not tracing.tracing_enabled()
+    index.remove(5)  # off again: nothing more
+    totals = stage_totals()
+    assert WRITE_STAGES | QUERY_STAGES <= set(totals) and totals["index_remove"]["calls"] == 2
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    seen: dict[str, int] = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name.startswith("pw."):
+                    assert plane.name.startswith("/host:")
+                    seen[event.name[3:]] = seen.get(event.name[3:], 0) + 1
+    assert set(seen) == set(totals)
+    assert seen == {stage: t["calls"] for stage, t in totals.items()}
+
+
+# -- the scopes are names only --------------------------------------------
+
+
+def _lowered_text(build, args, **static):
+    return build().lower(*args, **static).as_text()
+
+
+def _fused(enc):
+    shapes = (
+        jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), enc.params),
+        jax.ShapeDtypeStruct((8, 16), np.int32),
+        jax.ShapeDtypeStruct((8,), np.int32),
+        jax.ShapeDtypeStruct((64, enc.dim), np.float32),
+        jax.ShapeDtypeStruct((64,), np.bool_),
+    )
+    return (lambda: knn._fused_query_fn(enc.module, enc.cfg)), shapes, {"k": 8, "l2": False}
+
+
+def _slab(*extra):
+    slab = (
+        jax.ShapeDtypeStruct((64, 384), np.float32),
+        jax.ShapeDtypeStruct((64,), np.bool_),
+        jax.ShapeDtypeStruct((64,), np.float32),
+    )
+    return slab + extra
+
+
+def _scatter_dev(enc):
+    args = _slab(jax.ShapeDtypeStruct((8,), np.int32), jax.ShapeDtypeStruct((8, 384), np.float32))
+    return knn._scatter_dev_fn, args, {"l2": False, "normalize": True}
+
+
+def _scatter_tomb(enc):
+    return knn._scatter_tomb_fn, _slab(jax.ShapeDtypeStruct((8,), np.int32))[1:], {}
+
+
+@pytest.mark.parametrize(
+    "program, scopes",
+    [
+        (_fused, ("pw.query.encode", "pw.query.scan", "pw.query.topk")),
+        (_scatter_dev, ("pw.index.scatter",)),
+        (_scatter_tomb, ("pw.index.tomb",)),
+    ],
+)
+def test_named_scopes_change_no_operation(enc, monkeypatch, program, scopes):
+    build, args, static = program(enc)
+    monkeypatch.setattr(knn, "_UPDATE_JIT", {})
+    with_scopes = build().lower(*args, **static)
+    for scope in scopes:  # the names are there, in the locations
+        assert scope in with_scopes.as_text(debug_info=True)
+    monkeypatch.setattr(knn, "_UPDATE_JIT", {})
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    without = build().lower(*args, **static)
+    assert "pw." not in without.as_text(debug_info=True)
+    assert with_scopes.as_text() == without.as_text()
