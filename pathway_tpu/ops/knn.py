@@ -633,6 +633,12 @@ class DeviceKnnIndex:
     add/remove mutate a host staging buffer; the device matrix syncs
     lazily before the next search (streams batch many updates between
     queries — one transfer amortizes them all).
+
+    Invariant, after every public call and on every kind (flat, mesh,
+    tenant-packed, tiered hot): ``_docs_shard[s]`` is the number of set
+    flags of ``_valid_host`` in shard ``s``'s slab. Every site that
+    flips a flag moves the count with it, so a write publishes the
+    counts and never reads the mask.
     """
 
     def __init__(
@@ -726,14 +732,10 @@ class DeviceKnnIndex:
         return out
 
     def _live_docs_shard(self) -> list[int]:
-        """Per-shard live row counts from the validity mask — what the
-        imbalance gauge must see. Identical to ``_docs_shard`` for a
-        flat index; for a tenant-packed slab, segment rows that are
-        reserved to a tenant but not yet occupied must not read as
-        skew (``pathway_index_imbalance`` is live rows, not granted
-        capacity)."""
-        v = self._valid_host.reshape(self.n_shards, self.shard_capacity)
-        return [int(n) for n in v.sum(axis=1)]
+        """Per-shard live rows, as the gauges publish them: the counts
+        the writes keep (the class invariant), so rows a tenant holds
+        in reserve but has not filled are not skew."""
+        return list(self._docs_shard)
 
     def _publish(self, shards) -> None:
         """What every write tells the other planes: the freshness
@@ -831,14 +833,20 @@ class DeviceKnnIndex:
         self._publish({s // self.shard_capacity for s in slots})
 
     def _remove_replaced(self, keys) -> None:
-        """A key added again replaces its row: the old one goes first."""
+        """A key added again replaces its row: the old ones go first,
+        with one publish for the lot — before the slots are handed out,
+        so the gauges are exact even if that raises."""
         replaced = [key for key in keys if key in self._slot_of]
-        if replaced:
-            # a stage of its own, so that a reader can take the removes
-            # nested in an add out of ``index_remove``'s seconds
-            with _span("index_replace", rows=len(replaced)):
-                for key in replaced:
-                    self.remove(key)
+        if not replaced:
+            return
+        # a stage of its own, so that a reader can take the removes
+        # nested in an add out of ``index_remove``'s seconds
+        with _span("index_replace", rows=len(replaced)):
+            shards = set()
+            for key in replaced:
+                with _span("index_remove", rows=1):
+                    shards.add(self._drop(key))
+        self._publish(shards)
 
     def add_batch_device(self, keys, dev_vectors, metadatas=None) -> None:
         """Bulk insert of embeddings that already live in HBM (a jax
@@ -925,22 +933,30 @@ class DeviceKnnIndex:
 
     def remove(self, key) -> None:
         with _span("index_remove", rows=1):
-            self._remove(key)
+            self._check_fence()
+            shard = self._drop(key)
+            if shard is not None:
+                self._publish((shard,))
 
-    def _remove(self, key) -> None:
-        self._check_fence()
+    def _drop(self, key) -> int | None:
+        """Take ``key``'s row out of the host bookkeeping; the shard it
+        lay in, or None for a key that has no row. Publishes nothing."""
         slot = self._slot_of.pop(key, None)
         if slot is None:
-            return
+            return None
         self._valid_host[slot] = False
         self._keys[slot] = None
         self._meta.pop(key, None)
         shard = slot // self.shard_capacity
-        self._free_shard[shard].append(slot)
         self._docs_shard[shard] -= 1
+        self._free_slot(key, slot)
         if not self._full:
             self._pending[slot] = None
-        self._publish((shard,))
+        return shard
+
+    def _free_slot(self, key, slot: int) -> None:
+        """Where a dropped row's slot goes to be handed out again."""
+        self._free_shard[slot // self.shard_capacity].append(slot)
 
     # --- elastic reshard protocol (elastic/controller.py drives) ---
 

@@ -304,20 +304,14 @@ class TenantPackedIndex(DeviceKnnIndex):
 
     def remove(self, key) -> None:
         self._check_fence()
-        slot = self._slot_of.pop(key, None)
-        if slot is None:
+        if self._drop(key) is None:
             self._cold_remove(key)
-            return
-        tenant = self._tenant_of_key(key)
-        self._valid_host[slot] = False
-        self._keys[slot] = None
-        self._meta.pop(key, None)
-        self._docs_shard[slot // self.shard_capacity] -= 1
+        else:
+            self._publish_metrics()
+
+    def _free_slot(self, key, slot: int) -> None:
         # the slot stays reserved to its tenant's segment
-        self._tenant_free[tenant].append(slot)
-        if not self._full:
-            self._pending[slot] = None
-        self._publish_metrics()
+        self._tenant_free[self._tenant_of_key(key)].append(slot)
 
     def _cold_remove(self, key) -> None:
         if not (isinstance(key, tuple) and len(key) == 2):
