@@ -65,11 +65,13 @@ def malformed(answers, k: int, rows: int) -> int:
     return bad
 
 
-def judge(config, traffic, weights, seed, queries, first_version: int, control: str | None = None) -> dict:
+def judge(config, traffic, family, weights, seed, queries, first_version: int, control: str | None = None) -> dict:
     """-> {"rank_gap", "score_err", "fresh_checked", "sampled"} for the
     window's answers and, with ``control`` ("fp8" or "int8"), the same
     under "control" for the reference in that precision put in the
-    program's place."""
+    program's place. ``family`` is the configuration's (``spec.py``): its
+    ``encode`` is the reference encoder, and ``weights`` the handle it
+    takes its leaves from."""
     model, k, rows = config["model"], int(config["index"]["k"]), int(config["rows"])
     rng = np.random.default_rng([seed, 2])
     usable = [q for q in queries if q.answer is not None and len(q.answer) == k]
@@ -84,8 +86,8 @@ def judge(config, traffic, weights, seed, queries, first_version: int, control: 
     sides = {"reference": None, **({"control": control} if control else {})}
     index, q_emb = {}, {}
     for side, quant in sides.items():
-        pool_emb = reference.encode(weights, model, traffic.pool_texts, quant=quant)
-        q_emb[side] = reference.encode(weights, model, [q.text for q in sample], quant=quant)
+        pool_emb = family.encode(weights, model, traffic.pool_texts, quant=quant)
+        q_emb[side] = family.encode(weights, model, [q.text for q in sample], quant=quant)
         index[side] = reference.ReferenceIndex(pool_emb, rows, sigma, key, chunk, quant=quant)
 
     out = {side: {"rank_gap": 0.0, "score_err": 0.0} for side in sides}

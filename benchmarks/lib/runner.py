@@ -127,8 +127,10 @@ def run_cell(
 
     # ---- set-up: traffic, weights, the system, the standing corpus, warm-up
     traffic = make_traffic(config, mix, seed)
-    weights = make_weights(config["model"], config["weights"], seed)
-    _log(t0, "traffic and weights made")
+    family, model = cell.family, config["model"]
+    weights = make_weights(family, model, config["weights"], seed)
+    pool_tokens = family.tokens_of(traffic.pool_words, model)
+    _log(t0, "traffic made")
     system = system_mod.System(config, weights)
     _log(t0, "system built")
     pool_emb = system.embed_pool(traffic.pool_texts)
@@ -159,7 +161,7 @@ def run_cell(
 
     # ---- the window
     spans = Spans(annotate=trace)
-    window = Window(system, traffic, mix, seed, spans, first_batch=warm_batches)
+    window = Window(system, traffic, pool_tokens, mix, seed, spans, first_batch=warm_batches)
     tracer = None
     if trace:
         tr = mix["trace"]
@@ -207,26 +209,22 @@ def run_cell(
                 json.dump(events, f)
         reduced = trace_reduce.reduce(events, cell.chips)
         shutil.rmtree(tracer.out_dir, ignore_errors=True)
-        model, rows, dim = config["model"], int(config["rows"]), int(config["index"]["dimensions"])
-        q_tokens = [len(q.text.split()) + 2 for q in answered]
+        rows, dim = int(config["rows"]), int(config["index"]["dimensions"])
+        q_tokens = family.tokens_of([len(q.text.split()) for q in answered], model)
         ctx = {
             "trace": reduced,
             "spans": [s for s in spans.rows if start <= s[1] and s[2] <= close],
             "window_s": window_s,
             "peaks": peaks,
             "work": {
-                "ingest_flops": workarith.encoder_flops(
+                "ingest_flops": family.flops(
                     model, np.concatenate([b.tokens for b in window.writes if b.visible <= close] or [[]])
                 ),
-                "serve_flops": workarith.encoder_flops(model, q_tokens)
-                + workarith.scan_flops(len(answered), rows, dim),
-                "encoder_flops_per_write_batch": workarith.encoder_flops(
-                    model, traffic.pool_words[: traffic.batch] + 2
-                ),
+                "serve_flops": family.flops(model, q_tokens) + workarith.scan_flops(len(answered), rows, dim),
+                "encoder_flops_per_write_batch": family.flops(model, pool_tokens[: traffic.batch]),
                 "scan_bytes_per_dispatch": workarith.scan_bytes(
                     rows, dim, np.dtype(config["index"]["row_dtype"]).itemsize
                 ),
-                "encoder_layers": model["num_hidden_layers"],
             },
         }
         for metric in cell.layer_metrics:
@@ -244,7 +242,7 @@ def run_cell(
     del system, window
     gc.collect()
     t_check = time.perf_counter()
-    judged = check.judge(config, traffic, weights, seed, queries, first_version, control=control)
+    judged = check.judge(config, traffic, family, weights, seed, queries, first_version, control=control)
     numbers = check.numbers(config, judged, exact)
     _log(t0, f"reference took {time.perf_counter() - t_check:.1f}s over {judged['sampled']} sampled answers")
 

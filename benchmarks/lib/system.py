@@ -6,6 +6,12 @@ Builds what ``VectorStoreServer`` builds for its index — a
 and exposes the three calls ``ExternalIndexNode`` makes on it: the first
 of ``_embed_fns()`` (``data_embed``), ``add_batch_device``/``remove``, and
 ``search_batch`` on texts (the fused query program).
+
+Which architecture a model's name stands for is the program's to say
+(``SentenceTransformerEmbedder(model["name"])``, the normal path); the
+benchmark holds it to the configuration by geometry — sequence length,
+row width — and by the seed's leaves matching its parameter tree one for
+one, names and shapes, whatever the family.
 """
 
 from __future__ import annotations
@@ -19,7 +25,8 @@ from . import reference, weights as weights_mod
 
 
 class System:
-    def __init__(self, config: dict, weights: dict):
+    def __init__(self, config: dict, weights):
+        """``weights``: the handle of ``weights.py``."""
         from pathway_tpu.stdlib.indexing.nearest_neighbors import UsearchKnn
         from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
 
@@ -31,7 +38,7 @@ class System:
             mesh = resolve_mesh(config["mesh"])
         self.embedder = SentenceTransformerEmbedder(model["name"], mesh=mesh)
         encoder = self.embedder._encoder
-        if encoder.max_seq_len != model["max_seq_len"] or encoder.cfg.num_layers != model["num_hidden_layers"]:
+        if encoder.max_seq_len != model["max_seq_len"] or encoder.dim != index["dimensions"]:
             raise SystemExit("the program's encoder is not the one the configuration describes")
         encoder.params = _lay_over(encoder.params, weights)
         knn = UsearchKnn(
@@ -107,20 +114,28 @@ class System:
         self.embedder._encoder.params = None
 
 
-def _lay_over(tree, flat: dict):
-    """The benchmark's weights in the shape of the program's parameter
-    tree: same leaves, same shapes, or an error."""
+def _lay_over(tree, weights):
+    """The seed's weights in the shape and the types of the program's
+    parameter tree: same leaves, same shapes, or an error. Made a group
+    of the handle's at a time, each leaf cast to the type of the one it
+    replaces, so that no more float32 than a group is ever beside them."""
     paths, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    leaves, used = [], set()
-    for path, leaf in paths:
+    shapes = {name: weights.shape(name) for name in weights.names()}
+    place = {}
+    for at, (path, leaf) in enumerate(paths):
         parts = [getattr(p, "key", getattr(p, "name", None)) for p in path]
         name = "/".join(p for p in parts if p not in (None, "params", "value"))
-        if name not in flat or tuple(flat[name].shape) != tuple(leaf.shape):
+        if shapes.get(name) != tuple(leaf.shape):
             raise SystemExit(f"the program's parameter {name!r} {leaf.shape} has no match in the seed's weights")
-        leaves.append(flat[name])
-        used.add(name)
-    if used != set(flat):
-        raise SystemExit(f"weights the program has no place for: {sorted(set(flat) - used)}")
+        place[name] = (at, leaf.dtype)
+    grouped = [name for group in weights.groups() for name in group]
+    if sorted(grouped) != sorted(place):
+        raise SystemExit(f"weights the program has no place for: {sorted(set(grouped) ^ set(place))}")
+    leaves = [None] * len(paths)
+    for group in weights.groups():
+        for name, made in weights.take(group).items():
+            at, dtype = place[name]
+            leaves[at] = made.astype(dtype)
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

@@ -1,45 +1,21 @@
-"""The encoder's weights, made on the device from the seed in one jitted
-call, in float32 as the program holds them. A flat dict, name -> array;
-``system.py`` lays it over the program's parameter tree and
-``reference.py`` reads it as it is.
+"""The seed's weights as a function of (seed, leaf), not a resident tree.
 
-The scales are the configuration's (``weights`` in its file): BERT's
-0.02 for every matrix, a larger table of word vectors and smaller
-position and type vectors, so that a mean-pooled embedding depends on
-the words and not on what every document shares (PERF.md, Findings,
-PR 21: seeded flax defaults give cosine 0.9995 between unrelated texts).
+``make_weights`` returns a handle. What leaves there are, their shapes
+and how each is drawn is the configuration's family's to say
+(``leaves``, ``make_leaf``: ``spec.py``); the handle gives every leaf a key
+of its own, ``fold_in(seed_key(seed, 7), i)`` with ``i`` its rank in the
+sorted names, and makes the leaves asked for on the device in one jitted
+call, in float32. It keeps no array: ``system.py`` takes the leaves to lay
+them over the program's parameter tree, in the program's types, and the
+family's reference takes them again once the program's are freed — all at
+once where the model is small, a layer at a time where it is not.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
-import jax.numpy as jnp
-
-
-def weight_shapes(model: dict) -> dict[str, tuple[tuple[int, ...], str]]:
-    """name -> (shape, kind) for a BERT-style encoder of these sizes."""
-    d, inter = model["hidden_size"], model["intermediate_size"]
-    shapes = {
-        "tok_embed/embedding": ((model["vocab_size"], d), "word"),
-        "pos_embed/embedding": ((model["max_position_embeddings"], d), "position"),
-        "type_embed/embedding": ((model["type_vocab_size"], d), "type"),
-        "ln_embed/scale": ((d,), "one"),
-        "ln_embed/bias": ((d,), "zero"),
-    }
-    for i in range(model["num_hidden_layers"]):
-        p = f"layer_{i}/"
-        for name, shape in (
-            ("attention/qkv", (d, 3 * d)),
-            ("attention/out", (d, d)),
-            ("mlp_in", (d, inter)),
-            ("mlp_out", (inter, d)),
-        ):
-            shapes[p + name + "/kernel"] = (shape, "matrix")
-            shapes[p + name + "/bias"] = ((shape[1],), "zero")
-        for ln in ("ln_att", "ln_mlp"):
-            shapes[p + ln + "/scale"] = ((d,), "one")
-            shapes[p + ln + "/bias"] = ((d,), "zero")
-    return shapes
 
 
 def seed_key(seed: int, stream: int):
@@ -48,22 +24,40 @@ def seed_key(seed: int, stream: int):
     return jax.random.fold_in(jax.random.fold_in(key, seed >> 31), stream)
 
 
-def make_weights(model: dict, scales: dict, seed: int) -> dict:
-    shapes = weight_shapes(model)
-    names = sorted(shapes)
+class Weights:
+    def __init__(self, family, model: dict, scales: dict, seed: int):
+        self._leaves = family.leaves(model)
+        self._names = sorted(self._leaves)
+        self._rank = {name: i for i, name in enumerate(self._names)}
+        self._groups = [list(g) for g in family.take_groups(model)]
+        self._seed = seed
 
-    @jax.jit
-    def build(key):
-        out = {}
-        for i, name in enumerate(names):
-            shape, kind = shapes[name]
-            if kind == "one":
-                out[name] = jnp.ones(shape, jnp.float32)
-            elif kind == "zero":
-                out[name] = jnp.zeros(shape, jnp.float32)
-            else:
-                std = scales[kind + "_std"]
-                out[name] = std * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
-        return out
+        # a compiled program per set of names asked for; no array
+        @functools.partial(jax.jit, static_argnums=(1,))
+        def build(key, names):
+            out = {}
+            for name in names:
+                shape, kind = self._leaves[name]
+                out[name] = family.make_leaf(kind, shape, jax.random.fold_in(key, self._rank[name]), scales)
+            return out
 
-    return build(seed_key(seed, 7))
+        self._build = build
+
+    def names(self) -> list[str]:
+        return list(self._names)
+
+    def shape(self, name: str) -> tuple[int, ...]:
+        return tuple(self._leaves[name][0])
+
+    def groups(self) -> list[list[str]]:
+        """The names in the groups the family wants them made in, each
+        small enough to sit on the device beside what is resident."""
+        return self._groups
+
+    def take(self, names) -> dict:
+        """name -> float32 array on the device, made now and kept nowhere."""
+        return self._build(seed_key(self._seed, 7), tuple(names))
+
+
+def make_weights(family, model: dict, scales: dict, seed: int) -> Weights:
+    return Weights(family, model, scales, seed)

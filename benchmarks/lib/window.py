@@ -68,8 +68,11 @@ class Spans:
 
 
 class Window:
-    def __init__(self, system, traffic, mix: dict, seed: int, spans: Spans, first_batch: int):
+    def __init__(self, system, traffic, pool_tokens, mix: dict, seed: int, spans: Spans, first_batch: int):
+        """``pool_tokens``: token length of each pool document, as the
+        configuration's family counts it."""
         self.system, self.traffic, self.mix, self.seed, self.spans = system, traffic, mix, seed, spans
+        self.pool_tokens = pool_tokens
         self.cap = int(mix["queries"]["cap"])
         self.lock = threading.Condition()
         self.queue: collections.deque[Query] = collections.deque()
@@ -170,7 +173,7 @@ class Window:
                     keys=keys,
                     texts=[self.traffic.pool_texts[d] for d in docs],
                     handed=time.perf_counter(),
-                    tokens=self.traffic.pool_words[docs] + 2,
+                    tokens=self.pool_tokens[docs],
                 )
                 with self.lock:
                     self.pending = last

@@ -15,7 +15,7 @@ import sys
 import numpy as np
 import pytest
 
-from benchmarks.lib import reference, runner, spec, system
+from benchmarks.lib import runner, spec, system
 from benchmarks.lib.traffic import make_traffic
 from benchmarks.lib.weights import make_weights
 
@@ -115,15 +115,15 @@ def test_reference_agrees_with_the_program_at_a_small_size():
     texts = traffic.pool_texts[:24] + ["Punctuation, too: (yes) -- it's split!", "", "MiXed Case 123abc"]
     enc = SentenceEncoder(config["model"]["name"])
     got = enc.tokenizer.batch_encode_matrix(texts, enc.max_seq_len)
-    ids, lens = reference.tokenize(texts, config["model"]["max_seq_len"], config["model"]["vocab_size"])
+    ids, lens = cell.family.tokenize(texts, config["model"])
     assert (np.asarray(got[1]) == lens).all() and (np.asarray(got[0]) == ids).all()
 
-    weights = make_weights(config["model"], config["weights"], 11)
+    weights = make_weights(cell.family, config["model"], config["weights"], 11)
     params = system._lay_over(enc.params, weights)
     module = TextEncoder(dataclasses.replace(enc.cfg, layer_impl="xla", attention_impl="xla"))
     mask = np.arange(ids.shape[1])[None, :] < lens[:, None]
     theirs = np.asarray(jax.jit(module.apply)(params, ids, mask))
-    ours = np.asarray(reference.encode(weights, config["model"], texts))
+    ours = np.asarray(cell.family.encode(weights, config["model"], texts))
     assert (theirs * ours).sum(axis=1).min() > 0.9999
     assert np.abs(theirs - ours).max() < 5e-3
     # unrelated documents are far apart: the cure for collinear seeded embeddings holds

@@ -19,11 +19,12 @@ L6 = {"hidden_size": 384, "intermediate_size": 1536, "num_hidden_layers": 6}
 def test_encoder_flops_by_hand():
     # one layer, one token among 128: qkv 2*384*1152, scores and values
     # 4*128*384, output 2*384*384, FFN 4*384*1536
+    bert = spec.load_family("bert")
     per_layer = 884_736 + 196_608 + 294_912 + 2_359_296
-    assert workarith.encoder_flops_per_token(L6, 128) == 6 * per_layer
-    assert workarith.encoder_flops(L6, [128, 128]) == 2 * 128 * 6 * per_layer
+    assert bert.flops(L6, [128]) == 128 * 6 * per_layer
+    assert bert.flops(L6, [128, 128]) == 2 * 128 * 6 * per_layer
     l12 = dict(L6, num_hidden_layers=12)
-    assert workarith.encoder_flops(l12, [64]) == 2 * workarith.encoder_flops(L6, [64])
+    assert bert.flops(l12, [64]) == 2 * bert.flops(L6, [64])
 
 
 def test_scan_work_by_hand():
