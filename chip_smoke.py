@@ -57,6 +57,11 @@ TOPK_SCORE_TOL = 2.0**-8
 # dots the two differ by bf16-grade rounding of values up to ~3; a lane of
 # length 1 returns v itself, rounded on one side only. Measured 7.8e-3.
 PAGED_ATOL = 2e-2
+# Selective scan at the published shape of the hybrid embedder (32 x 256 x
+# 5,120 channels, state 16): float32 state on both sides; the kernel's exp
+# and the order of its sums differ from XLA's, and its output is rounded to
+# bf16 (2^-9 relative) on both. Relative to the largest output.
+SCAN_RTOL = 1e-2
 # Decode: greedy tokens must equal the impl="xla" engine's, except that the
 # first token to differ may be one the plain XLA forward scores within this
 # of its best (logits have std ~0.3 and a typical top-2 gap of 0.05).
@@ -334,6 +339,33 @@ def check_paged_attention(decoder, rng) -> None:
 
 
 # ---- the server ------------------------------------------------------------
+
+
+def check_selective_scan(rng) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops.selective_scan import selective_scan, selective_scan_reference
+
+    batch, seq, channels, n = 32, 256, 5120, 16
+    u = jnp.asarray(rng.normal(size=(batch, seq, channels)), jnp.bfloat16)
+    z = jnp.asarray(rng.normal(size=(batch, seq, channels)), jnp.bfloat16)
+    dt = jax.nn.softplus(jnp.asarray(rng.normal(size=(batch, seq, channels)) - 3.0, jnp.float32))
+    b = jnp.asarray(rng.normal(size=(batch, seq, n)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(batch, seq, n)), jnp.float32)
+    a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (channels, n))
+    d_skip = jnp.ones((channels,), jnp.float32)
+    check(
+        has_mosaic_kernel(selective_scan, u, dt, z, b, c, a, d_skip),
+        "selective scan at 32 x 256 x 5120, state 16, compiles to a Mosaic kernel",
+    )
+    got = np.asarray(selective_scan(u, dt, z, b, c, a, d_skip), np.float32)
+    want = np.asarray(jax.jit(selective_scan_reference)(u, dt, z, b, c, a, d_skip), np.float32)
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    check(
+        bool(np.isfinite(got).all()) and err <= SCAN_RTOL,
+        f"selective scan vs the lax.scan recurrence: max err {err:.2e} of the largest output (<= {SCAN_RTOL})",
+    )
 
 
 def free_port() -> int:
@@ -700,6 +732,7 @@ def main() -> None:
     check_fused_attention(minilm, rng)
     check_pallas_knn(rng, resolve_mesh(mesh_chips))
     check_paged_attention(DecoderConfig(), rng)
+    check_selective_scan(rng)
 
     docs = make_corpus(rng)
     serve_and_check(docs, mesh_chips)

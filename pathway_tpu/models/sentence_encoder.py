@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..tracing import span as _span
-from .batching import chunks, pad_token_batch
+from .batching import DEFAULT_SEQ_BUCKETS, chunks, pad_token_batch
 from .encoder import (
     CrossEncoderHead,
     EncoderConfig,
@@ -28,7 +28,24 @@ from .encoder import (
     init_params,
     load_hf_weights,
 )
-from .tokenizer import default_tokenizer
+from .hybrid_ssm import HybridSSMConfig, HybridSSMEncoder
+from .tokenizer import WordPieceTokenizer, default_tokenizer
+
+#: model name -> the configuration it stands for; a name that is not
+#: here is served by the default all-MiniLM-L6-v2 block
+ARCHITECTURES = {
+    "all-minilm-l6-v2": EncoderConfig.minilm_l6,
+    "all-minilm-l12-v2": EncoderConfig.minilm_l12,
+    "ai21-jamba2-3b": HybridSSMConfig.jamba2_3b,
+    # no published model: both kinds of hybrid layer at test widths
+    "hybrid-ssm-tiny-for-tests": HybridSSMConfig.tiny_for_tests,
+}
+
+
+def architecture_of(model: str):
+    """The configuration ``model`` stands for, with or without its
+    organisation ("ai21labs/AI21-Jamba2-3B"), in any case."""
+    return ARCHITECTURES.get(model.rsplit("/", 1)[-1].lower(), EncoderConfig.minilm_l6)()
 
 
 def _checkpoint_or_seeded(params, checkpoint_dir: str | None):
@@ -57,20 +74,40 @@ class SentenceEncoder:
         data_axis: str = "data",
     ):
         if config is None:
-            if "L12" in model or "l12" in model:
-                config = EncoderConfig.minilm_l12()
-            else:
-                config = EncoderConfig.minilm_l6()
+            config = architecture_of(model)
         self.cfg = config
         self.model_name = model
         self.max_seq_len = max_seq_len
         self.max_batch = max_batch
-        self.module = TextEncoder(config)
         checkpoint_dir = checkpoint_dir or os.environ.get("PATHWAY_TPU_CKPT")
-        self.params = _checkpoint_or_seeded(
-            init_params(self.module, config, seed=seed), checkpoint_dir
-        )
-        self.tokenizer = default_tokenizer(checkpoint_dir)
+        if isinstance(config, HybridSSMConfig):
+            if checkpoint_dir and os.path.isdir(checkpoint_dir):
+                raise NotImplementedError(
+                    f"no checkpoint loader for the hybrid state-space tree of {model!r}: "
+                    f"{checkpoint_dir} would be ignored and seeded weights scored in its place"
+                )
+            if mesh is not None:
+                raise NotImplementedError("the hybrid state-space encoder has no mesh path")
+            self.module = HybridSSMEncoder(config)
+            # a dispatch group is bounded by tokens, not by rows: the
+            # module says how many it lets be alive at once
+            self.max_batch = min(max_batch, max(8, config.max_group_tokens // max_seq_len))
+            self.params = self.module.init(seed)
+            self.tokenizer = WordPieceTokenizer(vocab_size=config.vocab_size)
+        else:
+            self.module = TextEncoder(config)
+            self.params = _checkpoint_or_seeded(
+                init_params(self.module, config, seed=seed), checkpoint_dir
+            )
+            self.tokenizer = default_tokenizer(checkpoint_dir)
+        # rows of several texts (segment packing) and the length-sorted
+        # fast path are the bidirectional blocks', and both ship their
+        # ids as int16; a recurrent or causal module says it cannot be
+        # packed
+        self._packable = getattr(config, "packable", True) and config.vocab_size < 32768
+        # every sequence bucket is a compiled program of the whole model:
+        # a module whose program is expensive names fewer
+        self._seq_buckets = getattr(config, "seq_buckets", DEFAULT_SEQ_BUCKETS)
         self.mesh = mesh
         self.data_axis = data_axis
         fwd = self.module.apply
@@ -128,7 +165,7 @@ class SentenceEncoder:
 
         ndata = self.mesh.shape[self.data_axis] if self.mesh is not None else 1
         return predict_compile_keys(
-            lengths, max_batch=self.max_batch, mesh_ndata=ndata
+            lengths, seq_buckets=self._seq_buckets, max_batch=self.max_batch, mesh_ndata=ndata
         )
 
     def _run_padded(self, ids, mask):
@@ -205,7 +242,7 @@ class SentenceEncoder:
         """Bucketed dispatch straight from the native tokenizer's padded
         ids matrix — no per-row Python lists on the hot path. Yields
         (group_indices, n_real, device_embeddings)."""
-        from .batching import DEFAULT_BATCH_BUCKETS, DEFAULT_SEQ_BUCKETS, bucket
+        from .batching import DEFAULT_BATCH_BUCKETS, bucket
 
         n = len(lens)
         order = np.argsort(lens, kind="stable")  # dense length buckets
@@ -219,7 +256,7 @@ class SentenceEncoder:
             ng = len(group)
             with _span("embed_pack", rows=ng):
                 L = min(
-                    bucket(int(lens[group].max()), DEFAULT_SEQ_BUCKETS),
+                    bucket(int(lens[group].max()), self._seq_buckets),
                     ids_mat.shape[1],
                 )
                 ids = np.take(ids_mat[:, :L], group, axis=0)
@@ -300,7 +337,7 @@ class SentenceEncoder:
 
             depth = max(2, int(os.environ.get("PATHWAY_WIRE_RING_DEPTH", "2")))
             self._wire_ring = DeviceRing(depth=depth, name="sentence_encoder.wire")
-        with _span("embed_dispatch", rows=ids.shape[0]):
+        with _span("embed_dispatch", rows=ids.shape[0], tokens=ids.size):
             ids_dev, lens_dev = self._wire_ring.stage(
                 [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]
             )
@@ -324,25 +361,33 @@ class SentenceEncoder:
         """MFU / pad-waste attribution for one group dispatch (feeds the
         dashboard column, the pathway_encoder_* gauges and the
         kernel.dispatch flight-recorder events)."""
-        if not self._fused_layer_ok(seq):
-            return
         from ..internals.profiler import ENCODER_KERNEL_STATS
-        from ..ops.fused_layer import _pack_rows, encoder_flops_per_token
 
-        real = int(lens.sum())
-        n_live = int(np.count_nonzero(lens))
-        # real rows are a prefix (length-sorted groups pad at the tail),
-        # so live blocks = ceil(n_live / p); the ragged kernel skips the
-        # all-padding tail blocks entirely
-        p = _pack_rows(seq)
-        total_rows = batch + (-batch) % p  # kernel pads rows to p-multiples
-        live_rows = min(-(-n_live // p) * p, total_rows)
+        own_flops = getattr(self.cfg, "flops_per_token", None)
+        if own_flops is not None:
+            # a module that counts its own work computes every row of
+            # the program, padding included
+            total_rows = live_rows = batch
+            per_token = own_flops(seq)
+        elif self._fused_layer_ok(seq):
+            from ..ops.fused_layer import _pack_rows, encoder_flops_per_token
+
+            n_live = int(np.count_nonzero(lens))
+            # real rows are a prefix (length-sorted groups pad at the tail),
+            # so live blocks = ceil(n_live / p); the ragged kernel skips the
+            # all-padding tail blocks entirely
+            p = _pack_rows(seq)
+            total_rows = batch + (-batch) % p  # kernel pads rows to p-multiples
+            live_rows = min(-(-n_live // p) * p, total_rows)
+            per_token = encoder_flops_per_token(self.cfg, seq)
+        else:
+            return  # the per-op XLA lowering of a BERT block: no gauge reads it
         ENCODER_KERNEL_STATS.record_dispatch(
             seq=seq,
             batch=total_rows,
-            real_tokens=real,
+            real_tokens=int(lens.sum()),
             computed_tokens=live_rows * seq,
-            flops=live_rows * seq * encoder_flops_per_token(self.cfg, seq),
+            flops=live_rows * seq * per_token,
         )
 
     def _encode_matrix(self, ids_mat: np.ndarray, lens: np.ndarray) -> np.ndarray:
@@ -457,7 +502,7 @@ class SentenceEncoder:
         block-diagonal attention (ops/fused_attention._seg_kernel), and
         mean-pool per segment on device. Token occupancy is ~95%+ at
         TokenCountSplitter chunk sizes."""
-        if self.mesh is not None or self.cfg.vocab_size >= 32768:
+        if self.mesh is not None or not self._packable:
             return None
         if not self.cfg.normalize or self.cfg.pooling != "mean":
             return None  # packed pooling bakes mean+normalize in
@@ -608,7 +653,7 @@ class SentenceEncoder:
         epoch once cost a 17s mid-run XLA compile)."""
         from .batching import DEFAULT_SEQ_BUCKETS, bucket
 
-        if self.mesh is not None or self.cfg.vocab_size >= 32768:
+        if self.mesh is not None or not self._packable:
             return None
         n = len(lens)
         B = self.max_batch

@@ -40,10 +40,15 @@ class BaseEmbedder(udfs.UDF):
 
 class SentenceTransformerEmbedder(BaseEmbedder):
     """TPU-native replacement for the sentence_transformers hot path
-    (reference embedders.py:270-329). ``model`` picks a MiniLM config;
-    weights load from PATHWAY_TPU_CKPT when present, otherwise the
-    encoder runs with deterministic random init (sufficient for tests
-    and throughput benchmarking).
+    (reference embedders.py:270-329). ``model`` names the architecture
+    (``models/sentence_encoder.py`` ``ARCHITECTURES``: the all-MiniLM
+    BERT blocks, and the hybrid state-space / attention encoder
+    ``AI21-Jamba2-3B`` with masked mean pooling over its last hidden
+    states; an unknown name is served by all-MiniLM-L6-v2's block).
+    BERT-block weights load from PATHWAY_TPU_CKPT when present,
+    otherwise the encoder runs with deterministic random init
+    (sufficient for tests and throughput benchmarking); the hybrid has no
+    loader yet and raises when a checkpoint directory is given.
     """
 
     def __init__(

@@ -30,6 +30,7 @@ from pathway_tpu.ops.fused_attention import attention
 from pathway_tpu.ops.fused_layer import _pack_rows, encoder_forward
 from pathway_tpu.ops.paged_attention import paged_decode_attention
 from pathway_tpu.ops.pallas_knn import knn_topk, knn_topk_sharded
+from pathway_tpu.ops.selective_scan import selective_scan
 
 # MiniLM-L6 at its published width; depth cut to one layer, since every
 # layer lowers the same kernel and lowering time is linear in depth
@@ -100,6 +101,23 @@ def _paged_case(page_size: int):
     )
 
 
+def _scan_case(batch: int, seq: int):
+    # the hybrid embedder's mixer at its published width: 5,120 channels,
+    # a state of 16; the write batch and the query program's shape
+    d, n = 5120, 16
+    seqs = _spec((batch, seq, d), jnp.bfloat16)
+    cols = _spec((batch, seq, n), jnp.float32)
+    return selective_scan, (
+        seqs,
+        _spec((batch, seq, d), jnp.float32),
+        seqs,
+        cols,
+        cols,
+        _spec((d, n), jnp.float32),
+        _spec((d,), jnp.float32),
+    )
+
+
 SINGLE_DEVICE_CASES = {
     **{
         f"encoder_forward[S={s}]": functools.partial(_encoder_case, s)
@@ -118,6 +136,8 @@ SINGLE_DEVICE_CASES = {
         f"paged_decode_attention[page={p}]": functools.partial(_paged_case, p)
         for p in (8, 16, 32)
     },
+    "selective_scan[B=32,S=256]": functools.partial(_scan_case, 32, 256),
+    "selective_scan[B=8,S=16]": functools.partial(_scan_case, 8, 16),
 }
 
 

@@ -338,9 +338,27 @@ def _scatter_tomb(enc):
     return knn._scatter_tomb_fn, _slab(jax.ShapeDtypeStruct((8,), np.int32))[1:], {}
 
 
+def _hybrid_forward(enc):
+    """The hybrid state-space / attention encoder's forward, at its test
+    preset (the scan in the Pallas interpreter: this lowers for the CPU)."""
+    from pathway_tpu.models.hybrid_ssm import HybridSSMConfig, HybridSSMEncoder
+
+    module = HybridSSMEncoder(HybridSSMConfig.tiny_for_tests(scan_impl="interpret"))
+    shapes = (
+        jax.eval_shape(module.init),
+        jax.ShapeDtypeStruct((8, 16), np.int32),
+        jax.ShapeDtypeStruct((8, 16), np.bool_),
+    )
+    return (lambda: jax.jit(module.apply)), shapes, {}
+
+
+HYBRID_SCOPES = tuple("pw.encode." + s for s in ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "attn", "mlp", "pool"))
+
+
 @pytest.mark.parametrize(
     "program, scopes",
     [
+        (_hybrid_forward, HYBRID_SCOPES),
         (_fused, ("pw.query.encode", "pw.query.scan", "pw.query.topk")),
         (_scatter_dev, ("pw.index.scatter",)),
         (_scatter_tomb, ("pw.index.tomb",)),
