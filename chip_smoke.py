@@ -169,7 +169,7 @@ def check_fused_layer(minilm, rng) -> None:
         ids = rng.integers(999, minilm.vocab_size, (batch, seq)).astype(np.int32)
         lens = np.zeros((batch,), np.int32)
         lens[:live] = rng.integers(max(2, seq // 3), seq + 1, live)
-        lens[0] = seq
+        lens[0], lens[1] = seq, 1  # every row tile live, and one token of one tile
         got = np.asarray(kernel(params, ids, lens))
         want = np.asarray(reference(params, ids, lens))
         check(bool(np.isfinite(got).all()), f"fused_layer S={seq}: finite")

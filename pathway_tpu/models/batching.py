@@ -39,10 +39,15 @@ def pad_fraction(lens, seq_buckets=DEFAULT_SEQ_BUCKETS, group: int | None = None
     sorted by length, split into groups of ``group`` rows (None = one
     group), and each group padded to its own seq bucket.
 
-    This is the FLOP-waste model the batching layer optimises: the
-    kernel's dead-block skip removes all-padding rows, so the tax that
-    remains is (bucket - len) inside live rows — exactly what this
-    reports."""
+    This is the FLOP-waste model the batching layer optimises: what it
+    reports is (bucket - len) inside live rows, the tax of a forward
+    that computes every row of a live sequence — the flax module, and
+    the whole-layer kernel up to sequence 128. In the buckets over 128
+    the kernel (ops/fused_layer.py) computes a sequence's live row
+    tiles only, so the tax it still pays there is
+    ceil(len / ROW_TILE) * ROW_TILE - len (never past the bucket),
+    which ``fused_layer.computed_tokens`` counts; all-padding rows cost
+    nothing on either side."""
     lens = sorted(int(l) for l in lens)
     if not lens:
         return 0.0

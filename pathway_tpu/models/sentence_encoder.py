@@ -337,7 +337,8 @@ class SentenceEncoder:
 
             depth = max(2, int(os.environ.get("PATHWAY_WIRE_RING_DEPTH", "2")))
             self._wire_ring = DeviceRing(depth=depth, name="sentence_encoder.wire")
-        with _span("embed_dispatch", rows=ids.shape[0], tokens=ids.size):
+        computed = self._record_dispatch(ids.shape[0], ids.shape[1], lens)
+        with _span("embed_dispatch", rows=ids.shape[0], tokens=computed):
             ids_dev, lens_dev = self._wire_ring.stage(
                 [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]
             )
@@ -354,41 +355,40 @@ class SentenceEncoder:
             else:
                 out = self._fwd_group(self.params, ids_dev, lens_dev)
             self._wire_ring.retire([ids_dev, lens_dev])
-        self._record_dispatch(ids.shape[0], ids.shape[1], lens)
         return out
 
-    def _record_dispatch(self, batch: int, seq: int, lens: np.ndarray) -> None:
-        """MFU / pad-waste attribution for one group dispatch (feeds the
-        dashboard column, the pathway_encoder_* gauges and the
-        kernel.dispatch flight-recorder events)."""
+    def _record_dispatch(self, batch: int, seq: int, lens: np.ndarray) -> int:
+        """MFU / pad-waste attribution for one group dispatch, counted
+        as it is issued (feeds the dashboard column, the
+        pathway_encoder_* gauges and the kernel.dispatch flight-recorder
+        events). -> the token rows the compiled forward computes for the
+        group: what the whole-layer kernel's tile rule leaves of
+        ``batch * seq``, every row of the program for a module that
+        skips nothing."""
         from ..internals.profiler import ENCODER_KERNEL_STATS
 
+        computed = batch * seq
         own_flops = getattr(self.cfg, "flops_per_token", None)
         if own_flops is not None:
             # a module that counts its own work computes every row of
             # the program, padding included
-            total_rows = live_rows = batch
             per_token = own_flops(seq)
         elif self._fused_layer_ok(seq):
-            from ..ops.fused_layer import _pack_rows, encoder_flops_per_token
+            from ..ops.fused_layer import _pack_rows, computed_tokens, encoder_flops_per_token
 
-            n_live = int(np.count_nonzero(lens))
-            # real rows are a prefix (length-sorted groups pad at the tail),
-            # so live blocks = ceil(n_live / p); the ragged kernel skips the
-            # all-padding tail blocks entirely
-            p = _pack_rows(seq)
-            total_rows = batch + (-batch) % p  # kernel pads rows to p-multiples
-            live_rows = min(-(-n_live // p) * p, total_rows)
+            computed = computed_tokens(lens, seq)
+            batch += (-batch) % _pack_rows(seq)  # kernel pads rows to p-multiples
             per_token = encoder_flops_per_token(self.cfg, seq)
         else:
-            return  # the per-op XLA lowering of a BERT block: no gauge reads it
+            return computed  # the per-op XLA lowering of a BERT block: no gauge reads it
         ENCODER_KERNEL_STATS.record_dispatch(
             seq=seq,
-            batch=total_rows,
+            batch=batch,
             real_tokens=int(lens.sum()),
-            computed_tokens=live_rows * seq,
-            flops=live_rows * seq * per_token,
+            computed_tokens=computed,
+            flops=computed * per_token,
         )
+        return computed
 
     def _encode_matrix(self, ids_mat: np.ndarray, lens: np.ndarray) -> np.ndarray:
         out = np.empty((len(lens), self.dim), np.float32)

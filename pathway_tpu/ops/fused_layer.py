@@ -25,6 +25,20 @@ MFU round (ROADMAP item 1) tiling:
 * **Dead-block skip.**  A block whose sequences are all padding (the
   tail of a batch bucket) writes zeros and does no matmul — padded
   tiles are skipped, not computed.
+* **Live row tiles only, for seq > 128.**  Where a packed sequence has
+  a score tile of its own and more than one row tile of ROW_TILE rows
+  (``tile_rule``), a sequence of real length ``len`` computes its first
+  ceil(len / ROW_TILE) * ROW_TILE rows (never more than seq): those
+  rows go through the whole layer attending to each other, under the
+  KEY_OFF bias from ``len``; rows past the last live tile are written
+  as zeros and cost nothing.  A 70-token document in a 256 bucket pays
+  for 128 rows, not 256.  No live tile is the dead sequence: the
+  dead-block skip is the zero-tiles case of the same rule, taken a
+  sequence at a time.  The tile count comes from ``live_tiles``, which
+  the dispatch counter (``computed_tokens``) shares.  A block whose
+  sequences all have their last tile live (a group of a length-sorted
+  batch at its own bucket) has nothing to leave out and goes through
+  in one pass, as every live block does where the rule does not apply.
 * **Diagonal-only attention for seq >= 128.**  The old kernel computed
   a full rows x rows score matrix per head and masked off-diagonal
   sequence pairs with BLOCK_OFF — at seq=160 / p=3 that is 3x the
@@ -62,6 +76,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -76,12 +91,23 @@ DIAG_ATTENTION_MIN_SEQ = 128
 # columns, accumulating in f32 — bounds peak VMEM at large row blocks.
 FFN_CHUNK = 512
 
+# Row tile of the ragged path: in a program of seq >=
+# DIAG_ATTENTION_MIN_SEQ with more than one such tile to a sequence, a
+# sequence of real length ``len`` computes its first
+# ceil(len / ROW_TILE) * ROW_TILE rows (never more than seq) and writes
+# the rest as zeros. Chosen from chip runs (PERF.md, section 6, PR 30):
+# every tile count is a copy of the layer body in every program; 128 is
+# the one measured in full, 64 gave ~7% more documents a second in the
+# probes and is the next to measure.
+ROW_TILE = 128
 
-def _ln(x32, scale_ref, bias_ref, eps):
+
+def _ln(x32, scale, bias, eps):
+    """LayerNorm over the last axis; ``scale`` / ``bias`` are (1, n) rows."""
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
     inv = jax.lax.rsqrt(var + eps)
-    return (x32 - mu) * inv * scale_ref[0:1, :] + bias_ref[0:1, :]
+    return (x32 - mu) * inv * scale + bias
 
 
 def _gelu_tanh(x32):
@@ -112,9 +138,39 @@ def _head_attention(qkv, bias, d: int, hd: int, n_heads: int, scale: float):
     return jnp.concatenate(parts, axis=1)
 
 
-def _layer_kernel(
-    lens_ref,
-    x_ref,
+def live_tiles(length):
+    """Row tiles of ``ROW_TILE`` that a sequence of real ``length``
+    computes: ``ceil(length / ROW_TILE)``, none for padding.  A python
+    int, a numpy array or a scalar read from SMEM — the kernel's branch
+    and the dispatch counter (``computed_tokens``) share it."""
+    return (length + ROW_TILE - 1) // ROW_TILE
+
+
+def tile_rule(seq: int) -> bool:
+    """Whether a program of ``seq`` computes a live sequence by its live
+    row tiles: each packed sequence has a score tile of its own and
+    more than one row tile to choose from."""
+    return seq >= DIAG_ATTENTION_MIN_SEQ and live_tiles(seq) > 1
+
+
+def computed_tokens(lens, seq: int) -> int:
+    """Token rows one layer call computes for a batch of real lengths
+    ``lens`` (numpy, zeros for padding rows) at the program's ``seq``.
+    Under the tile rule a sequence costs its live tiles,
+    ``ceil(len / ROW_TILE) * ROW_TILE`` rows and never more than
+    ``seq``; elsewhere a block of ``p`` packed sequences costs all its
+    ``p * seq`` rows unless every one of them is padding."""
+    lens = np.asarray(lens, np.int64)
+    if tile_rule(seq):
+        return int(np.minimum(live_tiles(lens) * ROW_TILE, seq).sum())
+    p = _pack_rows(seq)
+    blocks = np.pad(lens, (0, (-len(lens)) % p)).reshape(-1, p)
+    return int(np.count_nonzero(blocks.max(axis=1))) * p * seq
+
+
+def _layer_rows(
+    x,
+    biases,
     wqkv_ref,
     bqkv_ref,
     wout_ref,
@@ -127,84 +183,140 @@ def _layer_kernel(
     b2_ref,
     ln2s_ref,
     ln2b_ref,
-    out_ref,
     *,
+    n_heads: int,
+    scale: float,
+    eps: float,
+):
+    """The layer over token rows ``x``: as many equal groups as
+    ``biases``, each attending to itself under its bias and to no other
+    group (everything but attention is row-wise)."""
+    d = x.shape[1]
+    n = x.shape[0] // len(biases)
+    qkv = (
+        jnp.dot(x, wqkv_ref[...], preferred_element_type=jnp.float32) + bqkv_ref[...]
+    ).astype(x.dtype)
+    ctx = jnp.concatenate(
+        [
+            _head_attention(qkv[j * n : (j + 1) * n], bias, d, d // n_heads, n_heads, scale)
+            for j, bias in enumerate(biases)
+        ],
+        axis=0,
+    ).astype(x.dtype)
+    att = jnp.dot(ctx, wout_ref[...], preferred_element_type=jnp.float32) + bout_ref[...]
+    h1 = _ln(x.astype(jnp.float32) + att, ln1s_ref[...], ln1b_ref[...], eps)
+    h1b = h1.astype(x.dtype)
+    interm = w1_ref.shape[1]
+    chunk = FFN_CHUNK if interm % FFN_CHUNK == 0 else interm
+    # residual + mlp_out bias seed the f32 accumulator; each chunk
+    # adds gelu(x @ W1[:, c]) @ W2[c, :]
+    acc = h1 + b2_ref[...]
+    for c0 in range(0, interm, chunk):
+        mid = (
+            jnp.dot(h1b, w1_ref[:, c0 : c0 + chunk], preferred_element_type=jnp.float32)
+            + b1_ref[:, c0 : c0 + chunk]
+        )
+        acc = acc + jnp.dot(
+            _gelu_tanh(mid).astype(x.dtype),
+            w2_ref[c0 : c0 + chunk, :],
+            preferred_element_type=jnp.float32,
+        )
+    return _ln(acc, ln2s_ref[...], ln2b_ref[...], eps).astype(x.dtype)
+
+
+def _layer_kernel(
+    lens_ref,
+    x_ref,
+    *refs,
     n_heads: int,
     seq: int,
     scale: float,
     eps: float,
 ):
+    *weights, out_ref = refs
     rows, d = out_ref.shape
     p = rows // seq
-    hd = d // n_heads
-
+    layer = dict(n_heads=n_heads, scale=scale, eps=eps)
     # this block's p real lengths out of the prefetched [bp * p] vector
-    # (scalar SMEM reads), and their max
+    # (scalar SMEM reads)
     base = pl.program_id(0) * p
     blk_lens = [lens_ref[base + j] for j in range(p)]
-    live = functools.reduce(jnp.maximum, blk_lens)
 
-    @pl.when(live == 0)
-    def _dead_block():
-        # whole block is batch-bucket padding: skipped, not computed.
-        # Pad rows are masked off at pooling/scatter downstream.
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(live > 0)
-    def _live_block():
-        x = x_ref[...]
-        qkv = (
-            jnp.dot(x, wqkv_ref[...], preferred_element_type=jnp.float32)
-            + bqkv_ref[0:1, :]
-        ).astype(x.dtype)
+    def whole_block():
         kiota = jax.lax.broadcasted_iota(jnp.int32, (1, seq), 1)
+        key_bias = [jnp.where(kiota < ln, 0.0, KEY_OFF) for ln in blk_lens]
         if seq >= DIAG_ATTENTION_MIN_SEQ:
             # ragged diagonal tiling: one (seq, seq) score tile per
             # packed sequence; cross-sequence tiles never computed
-            blocks = []
-            for j in range(p):
-                kb = jnp.where(kiota < blk_lens[j], 0.0, KEY_OFF)
-                sub = qkv[j * seq : (j + 1) * seq, :]
-                blocks.append(_head_attention(sub, kb, d, hd, n_heads, scale))
-            ctx = jnp.concatenate(blocks, axis=0).astype(x.dtype)
+            biases = tuple(key_bias)
         else:
             # packed short sequences: one rows x rows matmul (good MXU
             # shapes); block-diagonal bias isolates the sequences and
             # the per-sequence key bias masks padding
             qi = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0) // seq
             ki = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1) // seq
-            kb = jnp.concatenate(
-                [jnp.where(kiota < ln, 0.0, KEY_OFF) for ln in blk_lens],
-                axis=1,
-            )  # (1, rows)
-            bias = jnp.where(qi == ki, 0.0, BLOCK_OFF) + kb
-            ctx = _head_attention(qkv, bias, d, hd, n_heads, scale).astype(x.dtype)
-        att = (
-            jnp.dot(ctx, wout_ref[...], preferred_element_type=jnp.float32)
-            + bout_ref[0:1, :]
-        )
-        h1 = _ln(x.astype(jnp.float32) + att, ln1s_ref, ln1b_ref, eps)
-        h1b = h1.astype(x.dtype)
-        interm = w1_ref.shape[1]
-        chunk = FFN_CHUNK if interm % FFN_CHUNK == 0 else interm
-        # residual + mlp_out bias seed the f32 accumulator; each chunk
-        # adds gelu(x @ W1[:, c]) @ W2[c, :]
-        acc = h1 + b2_ref[0:1, :]
-        for c0 in range(0, interm, chunk):
-            mid = (
-                jnp.dot(
-                    h1b,
-                    w1_ref[:, c0 : c0 + chunk],
-                    preferred_element_type=jnp.float32,
+            biases = (
+                jnp.where(qi == ki, 0.0, BLOCK_OFF) + jnp.concatenate(key_bias, axis=1),
+            )
+        out_ref[...] = _layer_rows(x_ref[...], biases, *weights, **layer)
+
+    if not tile_rule(seq):
+        live = functools.reduce(jnp.maximum, blk_lens)
+
+        @pl.when(live == 0)
+        def _dead_block():
+            # whole block is batch-bucket padding: skipped, not computed.
+            # Pad rows are masked off at pooling/scatter downstream.
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        pl.when(live > 0)(whole_block)
+        return
+
+    # the tile rule: of a sequence only its live tiles — the first
+    # ceil(len / ROW_TILE) * ROW_TILE rows go through the layer,
+    # attending to each other; rows past the last live tile are written
+    # as zeros (the next layer reads them as keys under KEY_OFF and
+    # pooling multiplies them by the mask, so they must be finite).
+    # No live tile = the dead sequence.
+    def sequence(j, carry):
+        row0 = j * seq if isinstance(j, int) else pl.multiple_of(j * seq, 32)
+        length = jnp.minimum(lens_ref[base + j], seq)  # a length past the bucket has no variant
+        tiles = live_tiles(length)
+
+        @pl.when(tiles == 0)
+        def _dead_sequence():
+            out_ref[pl.ds(row0, seq), :] = jnp.zeros((seq, d), out_ref.dtype)
+
+        for k in range(1, live_tiles(seq) + 1):
+            r = min(k * ROW_TILE, seq)
+
+            @pl.when(tiles == k)
+            def _live_tiles(r=r):
+                kiota = jax.lax.broadcasted_iota(jnp.int32, (1, r), 1)
+                kb = jnp.where(kiota < length, 0.0, KEY_OFF)
+                out_ref[pl.ds(row0, r), :] = _layer_rows(
+                    x_ref[pl.ds(row0, r), :], (kb,), *weights, **layer
                 )
-                + b1_ref[0:1, c0 : c0 + chunk]
-            )
-            acc = acc + jnp.dot(
-                _gelu_tanh(mid).astype(x.dtype),
-                w2_ref[c0 : c0 + chunk, :],
-                preferred_element_type=jnp.float32,
-            )
-        out_ref[...] = _ln(acc, ln2s_ref, ln2b_ref, eps).astype(out_ref.dtype)
+                if r < seq:
+                    out_ref[pl.ds(row0 + r, seq - r), :] = jnp.zeros(
+                        (seq - r, d), out_ref.dtype
+                    )
+
+        return carry
+
+    if p == 1:
+        sequence(0, None)
+        return
+    # the last tile of every sequence live (a group of a length-sorted
+    # batch at its own bucket): nothing to leave out, so the block goes
+    # through in one pass, its row-wise matmuls p * seq rows deep
+    dense = live_tiles(functools.reduce(jnp.minimum, blk_lens)) == live_tiles(seq)
+    pl.when(dense)(whole_block)
+
+    @pl.when(jnp.logical_not(dense))
+    def _by_sequence():
+        # traced once for the block's p sequences, not p times
+        jax.lax.fori_loop(0, p, sequence, None)
 
 
 def _pack_rows(s: int) -> int:
@@ -233,6 +345,16 @@ def block_lens(lens, s: int):
     return lens.reshape(-1, p)
 
 
+# Jitted, inline: the layers of a program share one trace and one
+# lowering of the kernel (the call's jaxpr is cached by shape, so the
+# lowering of layer 1 is found again for the others) where each layer
+# call used to trace and lower its own copy of the body — seconds of
+# every start-up that no compile cache saves (PERF.md, section 7), more
+# with every row-tile variant. Inlined, the call leaves nothing in the
+# name stack: the device op keeps the name of the jit or scope above it.
+@functools.partial(
+    jax.jit, static_argnames=("n_heads", "seq", "eps", "interpret"), inline=True
+)
 def fused_layer_tokens(
     tokens,
     lens,

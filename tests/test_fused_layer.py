@@ -12,7 +12,11 @@ import jax.numpy as jnp
 from pathway_tpu.models.batching import DEFAULT_SEQ_BUCKETS
 from pathway_tpu.models.encoder import EncoderConfig, TextEncoder, init_params
 from pathway_tpu.ops.fused_layer import (
+    ROW_TILE,
+    computed_tokens,
     encoder_forward,
+    fused_layer_tokens,
+    live_tiles,
     pack_tokens,
     supports_fused_encoder,
     unpack_tokens,
@@ -81,6 +85,119 @@ def test_every_bucket_parity_with_all_padding_rows(tiny, s):
     err = np.abs(ref[live] - got[live]).max()
     assert err < 3e-2, (s, err)
     assert np.all(got[~live] == 0.0), "all-padding row must embed to zero"
+
+
+# ---- the tile rule: a live sequence computes its live row tiles only --------
+
+TILED_SEQS = (160, 192, 224, 256)
+
+
+def _computed_rows(lens, s):
+    """ceil(len / ROW_TILE) * ROW_TILE, never past the bucket — written
+    out here, not taken from the helper under test."""
+    return np.minimum(-(-np.asarray(lens) // ROW_TILE) * ROW_TILE, s)
+
+
+def _edge_lens(s):
+    """Lengths that hit every live-tile count of a bucket, both edges of
+    every tile, an all-padding row in the middle and at the tail — and,
+    after them, one block whose sequences all reach their last tile
+    (the kernel takes such a block in one pass)."""
+    from pathway_tpu.ops.fused_layer import _pack_rows
+
+    edges = {1, s - 1, s}
+    for k in range(1, -(-s // ROW_TILE) + 1):
+        edges |= {k * ROW_TILE - 1, k * ROW_TILE, k * ROW_TILE + 1}
+    lens = sorted(n for n in edges if 1 <= n <= s)
+    lens.insert(len(lens) // 2, 0)
+    p = _pack_rows(s)
+    lens += [0] * (1 + (-len(lens) - 1) % p)
+    return np.asarray(lens + [s - j for j in range(p)], np.int64)
+
+
+def _edge_batch(s, vocab=999):
+    lens = _edge_lens(s)
+    ids = np.random.default_rng(s).integers(5, vocab, (len(lens), s)).astype(np.int32)
+    return ids, lens, np.arange(s)[None, :] < lens[:, None]
+
+
+def test_edge_lens_hit_every_live_tile_count():
+    for s in TILED_SEQS:
+        lens = _edge_lens(s)
+        assert set(live_tiles(lens).tolist()) == set(range(-(-s // ROW_TILE) + 1))
+        assert {0, 1, ROW_TILE, ROW_TILE + 1, s - 1, s} <= set(lens.tolist())
+
+
+@pytest.mark.parametrize("s", TILED_SEQS)
+def test_tile_rule_matches_module_at_every_live_tile_count(tiny, s):
+    cfg, module, params = tiny
+    ids, lens, mask = _edge_batch(s)
+    got = np.asarray(encoder_forward(params, cfg, jnp.asarray(ids), jnp.asarray(mask), interpret=True))
+    ref = np.asarray(module.apply(params, jnp.asarray(ids), jnp.asarray(mask)))
+    live = lens > 0
+    err = np.abs(ref[live] - got[live]).max()
+    cos = (ref[live] * got[live]).sum(axis=1).min()
+    assert err < 3e-2 and cos > 0.999, (s, err, cos)
+    assert np.all(got[~live] == 0.0), "an all-padding row embeds to zero wherever it rides"
+
+
+@pytest.mark.parametrize("s", TILED_SEQS)
+def test_rows_past_the_last_live_tile_are_zero_after_every_layer(tiny, s):
+    """The next layer reads those rows as keys under KEY_OFF and pooling
+    multiplies them by the mask: zeros, never stale memory — and the
+    rows of the live tiles are what the kernel computed, pad rows of the
+    last live tile included."""
+    from flax.core import meta
+
+    cfg, _, params = tiny
+    layers = meta.unbox(params["params"])
+    _, lens, mask = _edge_batch(s)
+    rng = np.random.default_rng(s)
+    x = jnp.asarray(rng.normal(size=(len(lens), s, cfg.hidden_size)), cfg.dtype)
+    tokens, lens_blk, b0 = pack_tokens(x, jnp.asarray(mask))
+    past = np.arange(s)[None, :] >= _computed_rows(lens, s)[:, None]
+    for i in range(cfg.num_layers):
+        tokens = fused_layer_tokens(
+            tokens, lens_blk, layers[f"layer_{i}"],
+            n_heads=cfg.num_heads, seq=s, eps=cfg.layer_norm_eps, interpret=True,
+        )
+        states = np.asarray(unpack_tokens(tokens, b0, s), np.float32)
+        assert np.isfinite(states).all()
+        assert np.all(states[past] == 0.0), f"layer {i}: rows past the last live tile"
+        computed = np.abs(states).max(axis=2)[~past]
+        assert np.all(computed > 0.0), f"layer {i}: a row of a live tile was left out"
+
+
+@pytest.mark.parametrize("s", TILED_SEQS)
+def test_pooled_output_ignores_the_ids_at_pad_positions(tiny, s):
+    cfg, _, params = tiny
+    ids, lens, mask = _edge_batch(s)
+    noise = np.random.default_rng(s + 1).integers(5, 999, ids.shape).astype(np.int32)
+    other = np.where(mask, ids, noise)
+    assert (other != ids).any()
+    run = lambda i: np.asarray(
+        encoder_forward(params, cfg, jnp.asarray(i), jnp.asarray(mask), interpret=True)
+    )
+    np.testing.assert_array_equal(run(ids), run(other))
+
+
+@pytest.mark.parametrize("s", (16, 96, 128) + TILED_SEQS + (512,))
+def test_computed_tokens_is_the_sum_of_live_tiles(s):
+    from pathway_tpu.ops.fused_layer import _pack_rows, tile_rule
+
+    rng = np.random.default_rng(s)
+    lens = np.concatenate([rng.integers(1, s + 1, 37), np.zeros(11, np.int64)])
+    assert tile_rule(s) == (s > 128)
+    if tile_rule(s):
+        assert computed_tokens(lens, s) == int(_computed_rows(lens, s).sum())
+        assert computed_tokens(_edge_lens(s), s) == int(_computed_rows(_edge_lens(s), s).sum())
+        assert computed_tokens(np.full(5, s), s) == 5 * s
+    else:
+        # no tile rule: every row of a block with one live sequence
+        p = _pack_rows(s)
+        assert computed_tokens(lens, s) == -(-37 // p) * p * s
+    assert computed_tokens(np.zeros(8, np.int64), s) == 0
+    assert int(lens.sum()) <= computed_tokens(lens, s) <= len(lens) * s + _pack_rows(s) * s
 
 
 def test_fused_encoder_cls_pooling(minilm):

@@ -262,6 +262,45 @@ def test_embed_batch_is_one_span_over_the_encoder_s_halves(enc, embedder):
     assert stage_totals()["embed_dispatch"]["calls"] == 10
 
 
+def test_embed_dispatch_tokens_are_what_the_layer_kernel_computes():
+    """Through the whole-layer kernel (interpret mode) a group costs its
+    sequences' live row tiles, not rows x bucket: the span's ``tokens``,
+    the kernel gauges' ``computed_tokens`` and the helper the kernel's
+    branch shares are one number."""
+    from pathway_tpu.internals.profiler import ENCODER_KERNEL_STATS
+    from pathway_tpu.models.batching import bucket
+    from pathway_tpu.ops.fused_layer import ROW_TILE, computed_tokens
+
+    cfg = EncoderConfig(
+        vocab_size=30000, hidden_size=32, num_layers=1, num_heads=2,
+        intermediate_size=64, max_position=256, layer_impl="interpret",
+    )
+    kernel_enc = SentenceEncoder(config=cfg, checkpoint_dir="/nonexistent", max_seq_len=256, max_batch=8)
+    texts = ["short", "word " * 60, "word " * 140, "word " * 200, "word " * 127]
+    lens = np.asarray([len(kernel_enc.tokenizer.encode(t, 256)) for t in texts])
+    seq = bucket(int(lens.max()), kernel_enc._seq_buckets)
+    assert lens.min() < ROW_TILE < lens.max() and seq > ROW_TILE
+    ENCODER_KERNEL_STATS.reset()
+    set_tracing_enabled(True)
+    with span("embed_batch", new_trace=True, rows=len(texts)):  # as the embedder opens it
+        kernel_enc.encode_device(texts)
+    totals = stage_totals()
+    snap = ENCODER_KERNEL_STATS.snapshot()
+    ENCODER_KERNEL_STATS.reset()
+    want = int(np.minimum(-(-lens // ROW_TILE) * ROW_TILE, seq).sum())
+    assert totals["embed_dispatch"]["calls"] == snap["dispatches"] == 1
+    assert totals["embed_dispatch"]["tokens"] == snap["computed_tokens"] == want == computed_tokens(lens, seq)
+    assert totals["embed_tokenize"]["tokens"] == snap["real_tokens"] == int(lens.sum())
+    assert int(lens.sum()) < want < 8 * seq  # the batch bucket's three padding rows cost nothing
+    # the flax module computes every row of the program, and says so
+    set_tracing_enabled(False)
+    TRACING_METRICS.reset()
+    plain = SentenceEncoder(config=EncoderConfig(num_layers=1), max_seq_len=256, max_batch=8)
+    set_tracing_enabled(True)
+    plain.encode_device(texts)
+    assert stage_totals()["embed_dispatch"]["tokens"] == 8 * seq
+
+
 def test_totals_sum_workers_and_take_units_from_attributes():
     TRACING_METRICS.observe("index_add", 0.25, "", worker=0, units={"rows": 7, "index": "docs"})
     TRACING_METRICS.observe("index_add", 0.5, "ab" * 16, worker=1, units={"rows": 5, "tokens": 11})
