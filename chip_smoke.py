@@ -199,11 +199,7 @@ def check_fused_attention(minilm, rng) -> None:
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.ops.fused_attention import (
-        _xla_packed_reference,
-        _xla_reference,
-        attention,
-    )
+    from pathway_tpu.ops.fused_attention import _xla_reference, attention
 
     d, heads, batch = minilm.hidden_size, minilm.num_heads, 24
     fused = jax.jit(lambda qkv, m: attention(qkv, m, n_heads=heads, impl="fused"))
@@ -219,28 +215,6 @@ def check_fused_attention(minilm, rng) -> None:
             bool(np.isfinite(got).all()) and err <= ATTENTION_TOL,
             f"fused attention S={seq} vs _xla_reference: max err {err:.2e} (<= {ATTENTION_TOL})",
         )
-    # sequence-packed variant at the ingest path's row length: four
-    # chunks back to back in each 512-token row, padding marked -1
-    seq, rows = 512, 8
-    qkv = jnp.asarray(rng.normal(size=(rows, seq, 3 * d)), jnp.bfloat16)
-    seg = np.full((rows, seq), -1, np.int32)
-    for r in range(rows):
-        cuts = np.sort(rng.choice(np.arange(8, seq - 8), size=4, replace=False))
-        for s, (a, b) in enumerate(zip(np.r_[0, cuts[:-1]], cuts)):
-            seg[r, a:b] = r * 8 + s
-    seg = jnp.asarray(seg)
-    packed = jax.jit(
-        lambda qkv, sg: attention(qkv, None, n_heads=heads, impl="fused", segment_ids=sg)
-    )
-    got = np.asarray(packed(qkv, seg), np.float32)
-    want = np.asarray(
-        jax.jit(lambda qkv, sg: _xla_packed_reference(qkv, sg, heads))(qkv, seg), np.float32
-    )
-    err = attention_error(got, want, np.asarray(seg) >= 0)
-    check(
-        bool(np.isfinite(got).all()) and err <= ATTENTION_TOL,
-        f"packed attention S={seq} vs _xla_packed_reference: max err {err:.2e} (<= {ATTENTION_TOL})",
-    )
 
 
 def neighbours_within_tolerance(idx, true_scores, k: int) -> tuple[bool, float]:

@@ -81,7 +81,7 @@ class SelfAttention(nn.Module):
     cfg: EncoderConfig
 
     @nn.compact
-    def __call__(self, x, mask, segment_ids=None):
+    def __call__(self, x, mask):
         cfg = self.cfg
         d = cfg.hidden_size
         h = cfg.num_heads
@@ -90,9 +90,7 @@ class SelfAttention(nn.Module):
         qkv = _dense(3 * d, "qkv", (EMBED, HEADS), cfg.dtype)(x)
         from ..ops.fused_attention import attention
 
-        ctx = attention(
-            qkv, mask, n_heads=h, impl=cfg.attention_impl, segment_ids=segment_ids
-        )
+        ctx = attention(qkv, mask, n_heads=h, impl=cfg.attention_impl)
         return _dense(d, "out", (HEADS, EMBED), cfg.dtype)(ctx)
 
 
@@ -100,9 +98,9 @@ class EncoderLayer(nn.Module):
     cfg: EncoderConfig
 
     @nn.compact
-    def __call__(self, x, mask, segment_ids=None):
+    def __call__(self, x, mask):
         cfg = self.cfg
-        a = SelfAttention(cfg, name="attention")(x, mask, segment_ids)
+        a = SelfAttention(cfg, name="attention")(x, mask)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="ln_att")(x + a)
         m = _dense(cfg.intermediate_size, "mlp_in", (EMBED, MLP), cfg.dtype)(x)
         m = jax.nn.gelu(m, approximate=True)
@@ -117,19 +115,7 @@ class TextEncoder(nn.Module):
     cfg: EncoderConfig
 
     @nn.compact
-    def __call__(
-        self,
-        ids,
-        mask,
-        token_type_ids=None,
-        return_tokens=False,
-        position_ids=None,
-        segment_ids=None,
-    ):
-        """``position_ids``/``segment_ids`` enable SEQUENCE PACKING:
-        several chunks share one row; positions restart per chunk and
-        attention is block-diagonal by segment (ops/fused_attention).
-        Packed calls return token states (pool per segment outside)."""
+    def __call__(self, ids, mask, token_type_ids=None, return_tokens=False):
         cfg = self.cfg
         embed = nn.Embed(
             cfg.vocab_size,
@@ -140,14 +126,9 @@ class TextEncoder(nn.Module):
             ),
             name="tok_embed",
         )(ids)
-        pos_index = (
-            position_ids
-            if position_ids is not None
-            else jnp.arange(ids.shape[1])[None, :]
-        )
         pos = nn.Embed(
             cfg.max_position, cfg.hidden_size, dtype=cfg.dtype, name="pos_embed"
-        )(pos_index)
+        )(jnp.arange(ids.shape[1])[None, :])
         typ = 0
         if cfg.type_vocab_size:
             tt = token_type_ids if token_type_ids is not None else jnp.zeros_like(ids)
@@ -157,8 +138,8 @@ class TextEncoder(nn.Module):
         x = embed + pos + typ
         x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name="ln_embed")(x)
         for i in range(cfg.num_layers):
-            x = EncoderLayer(cfg, name=f"layer_{i}")(x, mask, segment_ids)
-        if return_tokens or segment_ids is not None:
+            x = EncoderLayer(cfg, name=f"layer_{i}")(x, mask)
+        if return_tokens:
             return x
         if cfg.pooling == "cls":
             pooled = x[:, 0]

@@ -81,9 +81,6 @@ class HybridSSMConfig:
     #: the whole-layer kernel of ``ops/fused_layer.py`` is the BERT
     #: block's; ``use_fused_encoder`` reads this and stays out
     layer_impl = "xla"
-    #: packing rows of several texts needs a state reset at every
-    #: segment boundary, which neither the conv nor the scan has
-    packable = False
     #: a sequence bucket is a 28-layer program (~10 s to compile on the
     #: v5e): powers of two, not the BERT blocks' fourteen
     seq_buckets = (16, 32, 64, 128, 256, 512)

@@ -99,12 +99,7 @@ class SentenceEncoder:
             self.params = _checkpoint_or_seeded(
                 init_params(self.module, config, seed=seed), checkpoint_dir
             )
-            self.tokenizer = default_tokenizer(checkpoint_dir)
-        # rows of several texts (segment packing) and the length-sorted
-        # fast path are the bidirectional blocks', and both ship their
-        # ids as int16; a recurrent or causal module says it cannot be
-        # packed
-        self._packable = getattr(config, "packable", True) and config.vocab_size < 32768
+            self.tokenizer = default_tokenizer(checkpoint_dir, config.vocab_size)
         # every sequence bucket is a compiled program of the whole model:
         # a module whose program is expensive names fewer
         self._seq_buckets = getattr(config, "seq_buckets", DEFAULT_SEQ_BUCKETS)
@@ -266,9 +261,7 @@ class SentenceEncoder:
                     if B > ng:
                         ids = np.pad(ids, ((0, B - ng), (0, 0)))
             if self.mesh is None:
-                # same compiled program as the uniform fast path (one
-                # (B, L)-shaped jit serves every batch size) — distinct
-                # programs per path would each pay their own compile
+                # one (B, L)-shaped jit serves every batch size
                 ln = np.zeros((ids.shape[0],), np.int32)
                 ln[:ng] = lens[group]
                 pending.append((group, ng, self._run_group(ids, ln)))
@@ -289,8 +282,7 @@ class SentenceEncoder:
 
     def _run_group(self, ids: np.ndarray, lens: np.ndarray):
         """The one non-mesh compiled forward: (B, L) int ids + lengths
-        (mask built on device). Shared by _matrix_groups and
-        _pack_uniform so all ingest paths hit the same program cache."""
+        (mask built on device)."""
         import jax
         import jax.numpy as jnp
 
@@ -426,28 +418,6 @@ class SentenceEncoder:
         m = self._tokenize_matrix(texts)
         return self._dispatch_tokenized(texts, m, pad_to)
 
-    def encode_device_many(self, batches, pad_to: int | None = None) -> list:
-        """Staged multi-epoch dispatch: drain a queue of >= 2 pending
-        text batches with batch i+1 tokenizing/packing on host while
-        batch i's dispatch is in flight (the per-dispatch latency
-        amortizes across the queue; wire ids ride the donated ring in
-        :meth:`_run_group`). Returns one DEVICE-resident
-        [n_i, dim] (or [pad_to, dim]) array per input batch, in order —
-        the caller blocks only when it consumes a result on host."""
-        batches = [["" if t is None else str(t) for t in b] for b in batches]
-        if len(batches) < 2:
-            return [self.encode_device(b, pad_to=pad_to) for b in batches]
-        prepared = self._tokenize_matrix(batches[0])
-        out = []
-        for i, texts in enumerate(batches):
-            m = prepared
-            out.append(self._dispatch_tokenized(texts, m, pad_to))
-            if i + 1 < len(batches):
-                # tokenize the NEXT epoch's batch while this one's
-                # dispatch (async on device backends) is still crunching
-                prepared = self._tokenize_matrix(batches[i + 1])
-        return out
-
     def _dispatch_tokenized(self, texts, m, pad_to: int | None = None):
         """Device tail of :meth:`encode_device`: bucket-pack an already
         tokenized matrix and dispatch the shared group forward."""
@@ -462,220 +432,24 @@ class SentenceEncoder:
             return embs
         ids_mat, lens = m
         n_out = pad_to or len(lens)
-        with _span("embed_pack"):
-            # the packed path's one dispatch is part of this span; the
-            # rows are counted by the path that packs them
-            packed = self._pack_segments(ids_mat, lens)
-        if packed is None:
-            packed = self._pack_uniform(ids_mat, lens)
-        if packed is None:
-            pending = self._matrix_groups(ids_mat, lens)
-            if pad_to:
-                # keep full bucket-shaped group outputs; rows past each
-                # group's real count scatter out of bounds and drop
-                embs = jnp.concatenate([emb for _, _, emb in pending], axis=0)
-                order = np.full((int(embs.shape[0]),), n_out, np.int64)
-                off = 0
-                for group, ng, emb in pending:
-                    order[off : off + ng] = group
-                    off += int(emb.shape[0])
-            else:
-                embs = jnp.concatenate([emb[:ng] for _, ng, emb in pending], axis=0)
-                order = np.concatenate([group for group, _, _ in pending])
+        pending = self._matrix_groups(ids_mat, lens)
+        if pad_to:
+            # keep full bucket-shaped group outputs; rows past each
+            # group's real count scatter out of bounds and drop
+            embs = jnp.concatenate([emb for _, _, emb in pending], axis=0)
+            order = np.full((int(embs.shape[0]),), n_out, np.int64)
+            off = 0
+            for group, ng, emb in pending:
+                order[off : off + ng] = group
+                off += int(emb.shape[0])
         else:
-            order, embs = packed
+            # a full group is taken as it is: no slice op per group
+            embs = jnp.concatenate(
+                [emb if ng == emb.shape[0] else emb[:ng] for _, ng, emb in pending], axis=0
+            )
+            order = np.concatenate([group for group, _, _ in pending])
         out = jnp.zeros((n_out, self.dim), jnp.float32)
         return out.at[jnp.asarray(order)].set(embs.astype(jnp.float32), mode="drop")
-
-    #: packed-row geometry: chunks concatenate back-to-back into rows of
-    #: PACK_L tokens (block-diagonal attention by segment id), at most
-    #: PACK_SEGS chunks per row; PACK_ROWS rows per scan step
-    PACK_L = 512
-    PACK_SEGS = 8
-    PACK_ROWS = 1024
-
-    def _pack_segments(self, ids_mat: np.ndarray, lens: np.ndarray):
-        """SEQUENCE PACKING: instead of padding each chunk to a seq
-        bucket (a ~137-wordpiece chunk pads to the 256 bucket — 46% of
-        the FLOPs wasted on pad tokens), concatenate chunks back-to-back
-        into 512-token rows with per-chunk positions and segment-id
-        block-diagonal attention (ops/fused_attention._seg_kernel), and
-        mean-pool per segment on device. Token occupancy is ~95%+ at
-        TokenCountSplitter chunk sizes."""
-        if self.mesh is not None or not self._packable:
-            return None
-        if not self.cfg.normalize or self.cfg.pooling != "mean":
-            return None  # packed pooling bakes mean+normalize in
-        n = len(lens)
-        L, SEGS, ROWS = self.PACK_L, self.PACK_SEGS, self.PACK_ROWS
-        if self.cfg.max_position < L:
-            return None
-        if int(lens.max()) > L:
-            # a chunk longer than the row capacity would overflow its
-            # packed row (silent cross-chunk corruption)
-            return None
-        mean_len = float(lens.mean())
-        # short chunks would need many segments per row; the bucketed
-        # paths handle those fine (their pad waste is bounded)
-        if n < 512 or mean_len < L / SEGS:
-            return None
-        # engage only when the bucketed path would waste a LOT of pad
-        # FLOPs: measured on v5e, the segment kernel runs ~1.5-1.9x
-        # slower per token than the uniform kernel (seg-bias build +
-        # larger attention area), so packing must cut tokens by more
-        # than that to win
-        from .batching import DEFAULT_SEQ_BUCKETS, bucket as _bucket
-
-        sorted_lens = np.sort(lens)
-        B = self.max_batch
-        bucketed_tokens = sum(
-            len(g) * _bucket(int(g[-1]), DEFAULT_SEQ_BUCKETS)
-            for g in (sorted_lens[i : i + B] for i in range(0, n, B))
-        )
-        if float(lens.sum()) / max(bucketed_tokens, 1) > 0.45:
-            return None
-        import jax
-        import jax.numpy as jnp
-
-        # shelf packing in descending length order (= first-fit here,
-        # since lengths only shrink): the per-chunk loop is plain int
-        # arithmetic; ALL matrix writes happen as one vectorized gather/
-        # scatter below — a 32k-chunk batch packs in ~100ms, not seconds
-        order = np.argsort(lens, kind="stable")[::-1].astype(np.int64)
-        lns = lens[order].astype(np.int64)
-        row_of = np.empty(n, np.int64)
-        off_of = np.empty(n, np.int64)
-        slot_of = np.empty(n, np.int64)
-        r = 0
-        off = 0
-        s_i = 0
-        for j in range(n):
-            ln = int(lns[j])
-            if off + ln > L or s_i == SEGS:
-                r += 1
-                off = 0
-                s_i = 0
-            row_of[j] = r
-            off_of[j] = off
-            slot_of[j] = s_i
-            off += ln
-            s_i += 1
-        R = r + 1
-        G = (R + ROWS - 1) // ROWS
-        R_pad = G * ROWS
-        ids = np.zeros((R_pad * L,), np.int16)
-        pos = np.zeros((R_pad * L,), np.int16)
-        seg = np.full((R_pad * L,), -1, np.int32)
-        # per-slot [start, end) token offsets: segments are CONTIGUOUS
-        # ranges inside their row, so pooling is a cumsum + two gathers
-        # — not a scatter-add (TPU scatter-adds are slow)
-        starts = np.zeros((R_pad * SEGS,), np.int32)
-        ends = np.zeros((R_pad * SEGS,), np.int32)
-        # slot map: (row, seg_in_row) -> original chunk index; empty
-        # slots point one past the real chunks (out of bounds even when
-        # pad_to == n, and int32-safe) so the final scatter mode="drop"
-        # discards them — a negative sentinel would WRAP to real rows
-        slot_to_chunk = np.full((R_pad * SEGS,), n, np.int64)
-        slot_index = row_of * SEGS + slot_of
-        starts[slot_index] = off_of
-        ends[slot_index] = off_of + lns
-        slot_to_chunk[slot_index] = order
-        # token-level flat scatter: one position per real token
-        total = int(lns.sum())
-        within = np.arange(total) - np.repeat(np.cumsum(lns) - lns, lns)
-        flat_pos = np.repeat(row_of * L + off_of, lns) + within
-        ids[flat_pos] = ids_mat[np.repeat(order, lns), within]
-        pos[flat_pos] = within.astype(np.int16)
-        seg[flat_pos] = (np.repeat(slot_index, lns)).astype(np.int32)
-        ids = ids.reshape(R_pad, L)
-        pos = pos.reshape(R_pad, L)
-        seg = seg.reshape(R_pad, L)
-        starts = starts.reshape(R_pad, SEGS)
-        ends = ends.reshape(R_pad, SEGS)
-        ids = ids.reshape(G, ROWS, L)
-        pos = pos.reshape(G, ROWS, L)
-        seg = seg.reshape(G, ROWS, L)
-        starts = starts.reshape(G, ROWS, SEGS)
-        ends = ends.reshape(G, ROWS, SEGS)
-
-        if getattr(self, "_fwd_packed", None) is None:
-            module = self.module
-            dim = self.dim
-
-            def fwd_packed(p, ids16, pos16, seg32, st, en):
-                def body(c, batch):
-                    i, po, sg, s0, s1 = batch
-                    toks = module.apply(
-                        p,
-                        i.astype(jnp.int32),
-                        sg >= 0,
-                        position_ids=po.astype(jnp.int32),
-                        segment_ids=sg,
-                    )  # (ROWS, L, dim) token states
-                    toks = toks.astype(jnp.float32) * (sg >= 0)[:, :, None]
-                    # exclusive prefix sums along the row; slot sum =
-                    # cs[end] - cs[start]
-                    cs = jnp.cumsum(toks, axis=1)
-                    cs = jnp.concatenate(
-                        [jnp.zeros((cs.shape[0], 1, dim), cs.dtype), cs], axis=1
-                    )  # (ROWS, L+1, dim)
-                    g1 = jnp.take_along_axis(cs, s1[:, :, None], axis=1)
-                    g0 = jnp.take_along_axis(cs, s0[:, :, None], axis=1)
-                    sums = g1 - g0  # (ROWS, SEGS, dim)
-                    counts = (s1 - s0).astype(jnp.float32)  # (ROWS, SEGS)
-                    pooled = sums / jnp.maximum(counts, 1.0)[:, :, None]
-                    pooled = pooled / jnp.maximum(
-                        jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-12
-                    )
-                    return c, pooled.reshape(-1, dim)
-
-                return jax.lax.scan(body, 0, (ids16, pos16, seg32, st, en))[1]
-
-            self._fwd_packed = jax.jit(fwd_packed)
-        embs = self._fwd_packed(
-            self.params, ids, pos, seg, starts, ends
-        )  # (G, ROWS*SEGS, dim)
-        embs = embs.reshape(R_pad * SEGS, self.dim)
-        return slot_to_chunk, embs
-
-    def _pack_uniform(self, ids_mat: np.ndarray, lens: np.ndarray):
-        """Length-sorted fast path: rows sort by length once, split into
-        max_batch groups, and EACH group pads to ITS OWN seq bucket —
-        with sorted rows a group's max length sits near its bucket, so
-        the pad tax is the gap to the next bucket instead of the batch's
-        global max (the 150-wordpiece headline runs its groups at 160,
-        not 256).  Groups dispatch async back-to-back through the same
-        compiled-program cache as _matrix_groups (results stay on
-        device; nothing blocks until the caller consumes them).
-        Per-group dispatch instead of one lax.scan keeps the
-        compiled-shape set bounded by the bucket set — streaming epochs
-        of arbitrary size must never recompile the ingest chain (a G=3
-        epoch once cost a 17s mid-run XLA compile)."""
-        from .batching import DEFAULT_SEQ_BUCKETS, bucket
-
-        if self.mesh is not None or not self._packable:
-            return None
-        n = len(lens)
-        B = self.max_batch
-        if n < 2 * B or n % B:
-            return None
-        import jax
-        import jax.numpy as jnp
-
-        with _span("embed_pack", rows=n):
-            order = np.argsort(lens, kind="stable")
-            G = n // B
-            ln = lens[order].reshape(G, B).astype(np.int32)
-        parts = []
-        for g in range(G):
-            with _span("embed_pack"):
-                grp = order[g * B : (g + 1) * B]
-                # sorted ascending, so the group's last row holds its max
-                Lg = min(bucket(int(ln[g, -1]), DEFAULT_SEQ_BUCKETS), ids_mat.shape[1])
-                ids_g = np.take(ids_mat[:, :Lg], grp, axis=0).astype(np.int16)
-            parts.append(self._run_group(ids_g, ln[g]))
-        embs = jnp.concatenate(parts, axis=0)  # (n, dim), device-resident
-        return order, embs
 
     def __call__(self, texts: Sequence[str]) -> np.ndarray:
         return self.encode(texts)
@@ -703,7 +477,7 @@ class CrossEncoderScorer:
         self.params = _checkpoint_or_seeded(
             init_params(self.module, self.cfg, seed=seed), checkpoint_dir
         )
-        self.tokenizer = default_tokenizer(checkpoint_dir)
+        self.tokenizer = default_tokenizer(checkpoint_dir, self.cfg.vocab_size)
         from ..internals.profiler import wrap_jit
 
         self._fwd = wrap_jit("cross_encoder.fwd", jax.jit(self.module.apply))

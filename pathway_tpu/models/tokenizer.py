@@ -186,7 +186,12 @@ class WordPieceTokenizer:
         return ids, tt
 
 
-def default_tokenizer(model_dir: str | None = None) -> WordPieceTokenizer:
+def default_tokenizer(model_dir: str | None = None, vocab_size: int = 30522) -> WordPieceTokenizer:
+    """The vocabulary file of ``model_dir`` (or ``PATHWAY_TPU_VOCAB``)
+    when there is one, else the seeded hash tokenizer. ``vocab_size`` is
+    the rows of the embedding table the ids will index: an id past the
+    table embeds to NaN without an error, so the seeded tokenizer is
+    built at that size and a larger vocabulary file is refused."""
     candidates = []
     if model_dir:
         candidates.append(os.path.join(model_dir, "vocab.txt"))
@@ -195,5 +200,10 @@ def default_tokenizer(model_dir: str | None = None) -> WordPieceTokenizer:
         candidates.append(env)
     for c in candidates:
         if os.path.exists(c):
-            return WordPieceTokenizer(vocab_file=c)
-    return WordPieceTokenizer()
+            tok = WordPieceTokenizer(vocab_file=c)
+            if len(tok.vocab) > vocab_size:
+                raise ValueError(
+                    f"{c} holds {len(tok.vocab)} tokens, the embedding table {vocab_size} rows"
+                )
+            return tok
+    return WordPieceTokenizer(vocab_size=vocab_size)

@@ -173,7 +173,7 @@ def _bwd(n_heads, interpret, res, g):
 _fused_attention.defvjp(_fwd, _bwd)
 
 
-def attention(qkv, key_mask, *, n_heads: int, impl: str = "auto", segment_ids=None):
+def attention(qkv, key_mask, *, n_heads: int, impl: str = "auto"):
     """Multi-head self-attention on fused qkv.
 
     qkv: [B, S, 3*D] (q | k | v, heads minor within each), key_mask:
@@ -181,115 +181,14 @@ def attention(qkv, key_mask, *, n_heads: int, impl: str = "auto", segment_ids=No
     "xla" (reference chain), "interpret" (kernel in interpret mode, for
     tests), or "auto" — the kernel on TPU when S fits a packed block,
     XLA otherwise.
-
-    ``segment_ids``: [B, S] int32 — SEQUENCE PACKING mode: several
-    independent chunks share one row; a token attends exactly the
-    tokens with its segment id (-1 marks padding, which attends
-    nothing real). key_mask is ignored in this mode.
     """
     s = qkv.shape[1]
     fits = s <= 512 and qkv.shape[2] % (3 * n_heads) == 0
     if impl == "auto":
         impl = "fused" if (jax.default_backend() == "tpu" and fits) else "xla"
-    if segment_ids is not None:
-        if impl == "fused":
-            return _packed_attention(qkv, segment_ids, n_heads, False)
-        if impl == "interpret":
-            return _packed_attention(qkv, segment_ids, n_heads, True)
-        return _xla_packed_reference(qkv, segment_ids, n_heads)
     if impl == "fused":
         return _fused_attention(qkv, key_mask, n_heads, False)
     if impl == "interpret":
         return _fused_attention(qkv, key_mask, n_heads, True)
     return _xla_reference(qkv, key_mask, n_heads)
 
-
-# ------------------------- sequence-packed attention -------------------------
-
-
-def _seg_kernel(qkv_ref, seg_ref, segc_ref, out_ref, *, n_heads: int, scale: float):
-    """Same fused pattern as _kernel, but the block-diagonal structure
-    comes from explicit segment ids (chunks packed back-to-back in one
-    row) instead of fixed-length sequence strides. The q-side segment
-    column arrives pre-transposed (segc_ref) — an in-kernel (1, rows)
-    -> (rows, 1) transpose is a lane->sublane shuffle Mosaic does
-    slowly."""
-    d = out_ref.shape[1]
-    qkv = qkv_ref[...]
-    seg = seg_ref[0, 0:1, :]  # (1, rows) int32 — key side
-    segc = segc_ref[:, 0:1]  # (rows, 1) int32 — query side
-    bias = jnp.where(segc == seg, 0.0, BLOCK_OFF)  # attend iff same segment
-    out_ref[...] = _heads_softmax_pv(qkv, bias, d, n_heads, scale, out_ref.dtype)
-
-
-def _xla_packed_reference(qkv, segment_ids, n_heads: int):
-    """XLA segment-packed attention (CPU path + backward)."""
-    b, s, three_d = qkv.shape
-    d = three_d // 3
-    hd = d // n_heads
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    fold = lambda t: t.reshape(b, s, n_heads, hd)
-    q, k, v = fold(q), fold(k), fold(v)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
-    same = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
-    scores = jnp.where(same, scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(qkv.dtype)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    return ctx.reshape(b, s, d)
-
-
-def _packed_call(qkv, segment_ids, n_heads: int, interpret: bool):
-    b, s, three_d = qkv.shape
-    d = three_d // 3
-    p = max(1, 256 // s)
-    rows = p * s
-    pad = (-b) % p
-    if pad:
-        qkv = jnp.pad(qkv, ((0, pad), (0, 0), (0, 0)))
-        segment_ids = jnp.pad(segment_ids, ((0, pad), (0, 0)), constant_values=-1)
-    bp = qkv.shape[0] // p
-    tokens = qkv.reshape(bp * rows, three_d)
-    # contract: segment ids are unique ACROSS rows (callers use
-    # row * max_segs + local), so rows sharing a 256-token block can
-    # never attend each other. -1 pads of different rows do attend each
-    # other — garbage in padding positions, never read, never NaN.
-    seg_rows = segment_ids.reshape(bp, rows).astype(jnp.int32)
-    seg = jnp.broadcast_to(seg_rows[:, None, :], (bp, 8, rows))
-    # pre-transposed query-side copy, tiled to a 128-lane minor dim
-    segc = jnp.broadcast_to(
-        seg_rows.reshape(bp * rows, 1), (bp * rows, 128)
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _seg_kernel, n_heads=n_heads, scale=1.0 / math.sqrt(d // n_heads)
-        ),
-        grid=(bp,),
-        in_specs=[
-            pl.BlockSpec((rows, three_d), lambda i: (i, 0)),
-            pl.BlockSpec((1, 8, rows), lambda i: (i, 0, 0)),
-            pl.BlockSpec((rows, 128), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp * rows, d), qkv.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(tokens, seg, segc)
-    return out.reshape(bp * p, s, d)[:b]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _packed_attention(qkv, segment_ids, n_heads: int, interpret: bool):
-    return _packed_call(qkv, segment_ids, n_heads, interpret)
-
-
-def _packed_fwd(qkv, segment_ids, n_heads, interpret):
-    return _packed_call(qkv, segment_ids, n_heads, interpret), (qkv, segment_ids)
-
-
-def _packed_bwd(n_heads, interpret, res, g):
-    qkv, segment_ids = res
-    _, vjp = jax.vjp(lambda t: _xla_packed_reference(t, segment_ids, n_heads), qkv)
-    return (vjp(g)[0], None)
-
-
-_packed_attention.defvjp(_packed_fwd, _packed_bwd)

@@ -514,8 +514,8 @@ def encoder_flops_per_token(cfg, seq: int) -> float:
 
 def supports_fused_encoder(cfg, seq_len: int) -> bool:
     """Geometry gate: the fused-layer path covers the inference encoder
-    exactly when the attention kernel's packing fits and the module has
-    no segment packing in play."""
+    exactly when the attention kernel's packing fits: whole heads, a
+    sequence of at most 512, and a pooling the kernel path has."""
     return (
         cfg.hidden_size % cfg.num_heads == 0
         and seq_len <= 512

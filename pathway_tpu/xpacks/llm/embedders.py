@@ -88,13 +88,6 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         with _span("embed_batch", new_trace=True, rows=len(texts)):
             return self._encoder.encode_device(texts, pad_to=pad_to)
 
-    def encode_device_many(self, batches, pad_to: int | None = None) -> list:
-        """Staged multi-epoch ingest: >= 2 pending input batches drain
-        through the overlapped pipeline — batch i+1 tokenizes while
-        batch i's dispatch is in flight, wire uploads ride the donated
-        DeviceRing. One device-resident [n_i, dim] array per batch."""
-        return self._encoder.encode_device_many(batches, pad_to=pad_to)
-
     def get_embedding_dimension(self, **kwargs) -> int:
         return self._encoder.dim
 

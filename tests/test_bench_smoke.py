@@ -107,35 +107,6 @@ def tiny_encoder():
     )
 
 
-def test_bench_smoke_embedder(tiny_encoder):
-    """Multi-epoch embedder drain: encode_device_many (tokenize batch
-    i+1 while batch i's dispatch is in flight, wire uploads through the
-    donated ring) is byte-identical to per-batch encode_device, and the
-    ring counters show the staging actually happened."""
-    enc = tiny_encoder
-    batches = [
-        [f"document {i} about topic {i % 3}" for i in range(j, j + 5)]
-        for j in range(0, 20, 5)
-    ]
-    singles = [np.asarray(enc.encode_device(b)) for b in batches]
-    many = [np.asarray(a) for a in enc.encode_device_many(batches)]
-    assert len(many) == len(singles)
-    for a, b in zip(many, singles):
-        assert np.array_equal(a, b), "depth-2 embedder drain diverged from depth-1"
-    ring = enc._wire_ring
-    assert ring is not None and ring.staged > 0
-    assert ring.in_flight() == 0
-
-
-def test_bench_smoke_embedder_single_batch_passthrough(tiny_encoder):
-    """< 2 pending batches short-circuits to the per-batch path."""
-    enc = tiny_encoder
-    one = [["just one pending batch of text"]]
-    (a,) = enc.encode_device_many(one)
-    b = enc.encode_device(one[0])
-    assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
 @pytest.fixture(scope="module")
 def tiny_kernel_encoder():
     """``layer_impl="interpret"`` routes the inference jit through the
@@ -181,23 +152,6 @@ def test_bench_smoke_ragged_kernel_matches_dense_xla(tiny_kernel_encoder):
     assert snap["dispatches"] > 0
     assert snap["real_tokens"] > 0
     assert 0.0 <= snap["pad_fraction"] < 1.0
-
-
-def test_bench_smoke_ragged_kernel_depth2_matches_depth1(tiny_kernel_encoder):
-    """Kernel parity must hold at pipeline depth 1 AND 2: the overlapped
-    encode_device_many drain (tokenize batch i+1 while batch i's kernel
-    dispatch is in flight, wire uploads through the donated ring) is
-    byte-identical to the strict per-batch loop."""
-    enc = tiny_kernel_encoder
-    batches = [
-        [f"kernel document {i} about topic {i % 3}" for i in range(j, j + 5)]
-        for j in range(0, 20, 5)
-    ]
-    singles = [np.asarray(enc.encode_device(b)) for b in batches]
-    many = [np.asarray(a) for a in enc.encode_device_many(batches)]
-    assert len(many) == len(singles)
-    for a, b in zip(many, singles):
-        assert np.array_equal(a, b), "depth-2 kernel drain diverged from depth-1"
 
 
 def test_bench_smoke_encoder_mfu_suite_runs_green():

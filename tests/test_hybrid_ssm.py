@@ -198,7 +198,7 @@ def test_names_resolve_at_construction(monkeypatch):
     from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
 
     l12 = SentenceTransformerEmbedder("all-MiniLM-L12-v2")._encoder
-    assert isinstance(l12.cfg, EncoderConfig) and l12.cfg.num_layers == 12 and l12._packable
+    assert isinstance(l12.cfg, EncoderConfig) and l12.cfg.num_layers == 12
     assert architecture_of("sentence-transformers/all-MiniLM-L12-v2").num_layers == 12
     assert architecture_of("all-MiniLM-L6-v2").num_layers == 6
     assert architecture_of("some-unknown-model") == EncoderConfig.minilm_l6()
@@ -209,7 +209,7 @@ def test_names_resolve_at_construction(monkeypatch):
     )
     emb = SentenceTransformerEmbedder("hybrid-ssm-tiny-for-tests")
     enc = emb._encoder
-    assert isinstance(enc.module, HybridSSMEncoder) and not enc._packable
+    assert isinstance(enc.module, HybridSSMEncoder)
     assert enc.tokenizer.vocab_size == enc.cfg.vocab_size == 2048
     assert emb.get_embedding_dimension() == 64
     rows = np.asarray(emb.encode_device(TEXTS))

@@ -64,14 +64,9 @@ def _encoder_case(seq: int):
     )
 
 
-def _attention_case(seq: int, packed: bool):
+def _attention_case(seq: int):
     d, heads, batch = MINILM.hidden_size, MINILM.num_heads, 64
     qkv = _spec((batch, seq, 3 * d), jnp.bfloat16)
-    if packed:
-        fn = lambda qkv, seg: attention(
-            qkv, None, n_heads=heads, impl="fused", segment_ids=seg
-        )
-        return fn, (qkv, _spec((batch, seq), jnp.int32))
     fn = lambda qkv, mask: attention(qkv, mask, n_heads=heads, impl="fused")
     return fn, (qkv, _spec((batch, seq), jnp.bool_))
 
@@ -124,11 +119,8 @@ SINGLE_DEVICE_CASES = {
         for s in DEFAULT_SEQ_BUCKETS
     },
     **{
-        f"attention[{'packed' if packed else 'fused'},S={s}]": functools.partial(
-            _attention_case, s, packed
-        )
-        for packed, seqs in ((False, (32, 160, 256, 512)), (True, (256, 512)))
-        for s in seqs
+        f"attention[fused,S={s}]": functools.partial(_attention_case, s)
+        for s in (32, 160, 256, 512)
     },
     "knn_topk[Q=1,N=10k,k=16]": functools.partial(_knn_case, 1, 10_000, 16),
     "knn_topk[Q=100,N=625k,k=64]": functools.partial(_knn_case, 100, 625_000, 64),
