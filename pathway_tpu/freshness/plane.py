@@ -44,6 +44,8 @@ import time
 from dataclasses import dataclass
 from typing import Any
 
+from ..ops.index_metrics import drain_owed
+
 _TRUTHY = ("1", "true", "on", "yes")
 _FALSY = ("0", "false", "off", "no", "none")
 
@@ -166,7 +168,13 @@ class _SourceStats:
 
 class FreshnessPlane:
     """Process-wide watermark registry. Thread-safe; every public hook
-    is a no-op single flag check while the plane is disabled."""
+    is a no-op single flag check while the plane is disabled.
+
+    A run of ``remove`` calls on an index notes its scatter commit once,
+    late (``index_metrics.drain_owed``): whatever reads a watermark, or
+    changes what a note means — an epoch starting to execute or
+    committing, the plane switched on or off — has the indexes pay
+    first, before it takes the lock."""
 
     def __init__(self) -> None:
         self._lock = threading.RLock()
@@ -204,6 +212,7 @@ class FreshnessPlane:
     def set_enabled(self, on: bool | None) -> None:
         """Run-scoped override: True/False wins over the env default,
         ``None`` restores env-driven behavior."""
+        drain_owed()
         self._override = on
 
     def configure(self, cfg: FreshnessConfig | None) -> None:
@@ -220,6 +229,7 @@ class FreshnessPlane:
     def active(self) -> bool:
         """True once the enabled plane actually recorded something —
         the /metrics and /status gate (off runs stay byte-identical)."""
+        drain_owed()
         return self._touched
 
     # -- arrival watermarks (connector threads) --
@@ -305,6 +315,7 @@ class FreshnessPlane:
     def epoch_exec(self, t: int) -> None:
         if not self.enabled():
             return
+        drain_owed()  # removes made outside the epoch are not its own
         with self._lock:
             rec = self._epochs.get(int(t))
             if rec is not None:
@@ -318,6 +329,7 @@ class FreshnessPlane:
         every shard the epoch touched to the epoch's drain cutoff."""
         if not self.enabled():
             return
+        drain_owed()  # the epoch's removes touch their shards before it closes
         now = time.time()
         with self._lock:
             t = int(t)
@@ -413,6 +425,7 @@ class FreshnessPlane:
         (the dual-answer dedup window serves under the same bound)."""
         if not self.enabled():
             return
+        drain_owed()
         with self._lock:
             self._touched = True
             old_key = self.index_key(old_index)
@@ -443,6 +456,7 @@ class FreshnessPlane:
     def visible_wm(self, index: Any, shards=None):
         """``(wm_epoch, wm_wall)`` — the index's visible watermark (min
         over its shards, or the given subset); None before any publish."""
+        drain_owed()
         with self._lock:
             wm = self._min_wm_locked(self.index_key(index), shards)
             return (wm[0], wm[1]) if wm is not None else None
@@ -457,6 +471,7 @@ class FreshnessPlane:
         bound). None until some shard published a watermark."""
         if not self.enabled():
             return None
+        drain_owed()
         now = time.time() if now is None else float(now)
         with self._lock:
             if index is not None:
@@ -541,6 +556,7 @@ class FreshnessPlane:
     def snapshot(self, now: float | None = None) -> dict:
         """Everything the /metrics, /status, journal, CLI and watchdog
         surfaces consume, in one dict."""
+        drain_owed()
         now = time.time() if now is None else float(now)
         with self._lock:
             planes = {
@@ -596,6 +612,7 @@ class FreshnessPlane:
             }
 
     def reset(self) -> None:
+        drain_owed()
         with self._lock:
             self._touched = False
             self.slo_ms = None

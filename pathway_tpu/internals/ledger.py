@@ -39,7 +39,8 @@ flight-recorder dump on first critical, and fold into a
 machine-readable :meth:`HealthWatchdog.verdict` — the green/yellow/red
 the ``pathway doctor`` CLI renders and ``RunResult.health`` carries.
 
-Module top imports stdlib only; the live samplers import their
+Module top imports stdlib only (and ``ops.index_metrics``, which does
+too, for ``drain_owed``); the live samplers import their
 registries lazily so the analysis plane stays device-free.
 """
 
@@ -50,6 +51,8 @@ import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Callable
+
+from ..ops.index_metrics import drain_owed
 
 __all__ = [
     "parse_bytes",
@@ -231,6 +234,8 @@ class DeviceLedger:
     aggregation happens at scrape time. ``used_bytes`` is optional —
     accounts that report it get a fragmentation gauge
     (1 − used/allocated); those that don't read as fully used.
+    Every read first has the indexes pay what their removes owe
+    (``drain_owed``), before the lock, which the payment takes.
     """
 
     def __init__(self) -> None:
@@ -282,16 +287,19 @@ class DeviceLedger:
     def active(self) -> bool:
         """Anything ever reported? Gates every ``pathway_hbm_*`` line so
         runs that never touch the ledger scrape byte-identical."""
+        drain_owed()
         with self._lock:
             return self._touched
 
     def total_bytes(self) -> int:
+        drain_owed()
         with self._lock:
             return sum(row[0] for row in self._rows.values())
 
     def accounts(self) -> dict[str, dict]:
         """Aggregate per-account view: bytes, used, high-water,
         fragmentation, owner count."""
+        drain_owed()
         with self._lock:
             out: dict[str, dict] = {}
             for (account, _owner), (nbytes, used) in self._rows.items():
@@ -336,6 +344,7 @@ class DeviceLedger:
             }
 
     def reset(self) -> None:
+        drain_owed()
         with self._lock:
             self._rows.clear()
             self._high.clear()

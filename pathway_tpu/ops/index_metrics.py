@@ -14,6 +14,7 @@ the local MXU scan).
 from __future__ import annotations
 
 import threading
+import weakref
 
 #: Merge-collective latency buckets in seconds. The merge moves
 #: [q, n_shards*k] floats — microseconds on ICI, sub-ms on a CPU
@@ -67,9 +68,39 @@ class MergeHistogram:
         return out
 
 
+#: Indexes that owe the planes a publish: ``DeviceKnnIndex.remove``
+#: records the shard it touched and publishes nothing. Weak, so an
+#: index that dies owing takes its debt with it.
+_OWING: weakref.WeakSet = weakref.WeakSet()
+_OWING_LOCK = threading.Lock()
+
+
+def note_owing(index) -> None:
+    """``index`` has shards owed a publish (called under its own
+    publish lock, as its owed set goes from empty to not)."""
+    with _OWING_LOCK:
+        _OWING.add(index)
+
+
+def drain_owed() -> None:
+    """Every live index that owes the planes a publish pays it, once.
+    A plane calls this at the top of each read and of ``reset``, before
+    it takes its own lock (the publish takes that lock), so no reader
+    can tell that a ``remove`` published late. One length check while
+    nothing is owed."""
+    if not _OWING:
+        return
+    with _OWING_LOCK:
+        owing = list(_OWING)
+        _OWING.clear()
+    for index in owing:
+        index._pay_owed()
+
+
 class IndexMetrics:
     """Thread-safe accounting for device-backed indexes: shard layout,
-    occupancy, imbalance, and merge-collective latency."""
+    occupancy, imbalance, and merge-collective latency. What an index
+    still owes (``drain_owed``) is paid before any read."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -150,6 +181,7 @@ class IndexMetrics:
     def active(self) -> bool:
         """Anything to render? (keeps /metrics byte-identical for runs
         that never touch a device-backed index)"""
+        drain_owed()
         with self._lock:
             return bool(self.indexes)
 
@@ -157,6 +189,7 @@ class IndexMetrics:
         """Any tiered accounting recorded? Gates every
         ``pathway_index_tier_*`` line so flat-index runs keep /metrics,
         /status, and the dashboard byte-identical."""
+        drain_owed()
         with self._lock:
             return any(
                 "cold_docs_shard" in e or "promotions" in e or "hot_hits" in e
@@ -164,6 +197,7 @@ class IndexMetrics:
             )
 
     def snapshot(self) -> dict:
+        drain_owed()
         with self._lock:
             tiered = False
             out = {}
@@ -220,6 +254,7 @@ class IndexMetrics:
             return snap
 
     def reset(self) -> None:
+        drain_owed()  # the other planes get theirs; nothing stays owed
         with self._lock:
             self.indexes.clear()
             self.merge = MergeHistogram()

@@ -31,12 +31,14 @@ compiled program is keyed on (per-shard capacity, k, metric) and a
 from __future__ import annotations
 
 import itertools
+import threading
 from typing import Any, Callable
 
 import numpy as np
 
 from ..freshness.plane import FRESHNESS
 from ..tracing import span as _span
+from .index_metrics import note_owing as _note_owing
 
 _NEG = -3.0e38
 
@@ -639,6 +641,13 @@ class DeviceKnnIndex:
     flags of ``_valid_host`` in shard ``s``'s slab. Every site that
     flips a flag moves the count with it, so a write publishes the
     counts and never reads the mask.
+
+    ``remove`` publishes nothing: it records the shard in ``_owed``, and
+    one publish pays for the whole run of removes at the first of the
+    index's next ``_publish``, its next ``_sync`` (where the tombstones
+    reach the device), and a read of a plane (``index_metrics.
+    drain_owed``). ``_publish_lock`` orders every publish of this index,
+    since that last one comes from the reader's thread.
     """
 
     def __init__(
@@ -675,6 +684,8 @@ class DeviceKnnIndex:
             for s in range(self.n_shards)
         ]
         self._docs_shard: list[int] = [0] * self.n_shards
+        self._owed: set[int] = set()  # shards whose removes are unpublished
+        self._publish_lock = threading.RLock()
         self._full = True  # device needs a full host upload
         self._host_stale = False  # device rows newer than host mirror
         self._pending: dict[int, np.ndarray | None] = {}  # slot -> vec | tombstone
@@ -738,11 +749,28 @@ class DeviceKnnIndex:
         return list(self._docs_shard)
 
     def _publish(self, shards) -> None:
-        """What every write tells the other planes: the freshness
-        watermark of the shards touched, the index gauges, the ledger."""
-        with _span("index_publish"):
-            FRESHNESS.note_index_add(self, shards)
-            self._publish_metrics()
+        """What a write tells the other planes: the freshness watermark
+        of the shards touched — these and the ones earlier removes left
+        owed — the index gauges, the ledger."""
+        with self._publish_lock:
+            if self._owed:
+                shards = self._owed.union(shards)
+                self._owed.clear()
+            with _span("index_publish"):
+                FRESHNESS.note_index_add(self, shards)
+                self._publish_metrics()
+
+    def _owe_publish(self, shard: int) -> None:
+        """A row of ``shard`` went and the planes were not told."""
+        with self._publish_lock:
+            if not self._owed:
+                _note_owing(self)
+            self._owed.add(shard)
+
+    def _pay_owed(self) -> None:
+        with self._publish_lock:
+            if self._owed:
+                self._publish(())
 
     def _publish_metrics(self) -> None:
         from .index_metrics import INDEX_METRICS
@@ -936,11 +964,12 @@ class DeviceKnnIndex:
             self._check_fence()
             shard = self._drop(key)
             if shard is not None:
-                self._publish((shard,))
+                self._owe_publish(shard)
 
     def _drop(self, key) -> int | None:
         """Take ``key``'s row out of the host bookkeeping; the shard it
-        lay in, or None for a key that has no row. Publishes nothing."""
+        lay in, or None for a key that has no row. Publishes nothing:
+        the caller publishes, or owes the shard (``_owe_publish``)."""
         slot = self._slot_of.pop(key, None)
         if slot is None:
             return None
@@ -1147,9 +1176,11 @@ class DeviceKnnIndex:
         self._dev_bias = _pallas_bias(self.metric, self._dev_matrix, self._dev_valid)
         self._full = False
         self._pending.clear()
-        self._ledger_update()
+        with self._publish_lock:
+            self._ledger_update()
 
     def _sync(self) -> None:
+        self._pay_owed()  # the removes' tombstones go to the device here
         if self._full or self._dev_matrix is None:
             self._upload_full()
             return

@@ -503,18 +503,21 @@ class TieredKnnIndex:
 
         hrb = hot_row_bytes(self.dim, self.tiers.hot_dtype)
         crb = self._cold.bytes_per_row
-        INDEX_METRICS.update_index(
-            self.name,
-            list(self.hot._docs_shard),
-            self.hot.shard_capacity,
-            cold_docs_shard=list(self._cold_docs_shard),
-            hot_bytes_shard=[int(d) * hrb for d in self.hot._docs_shard],
-            cold_bytes_shard=[int(d) * crb for d in self._cold_docs_shard],
-        )
-        # The hot tier is a DeviceKnnIndex whose publish hook this method
-        # replaces — keep its HBM ledger account (bytes + used fraction)
-        # current here instead.
-        self.hot._ledger_update()
+        # under the hot tier's publish lock, as its own publishes are: a
+        # plane's reader may be paying what the hot tier's removes owe
+        with self.hot._publish_lock:
+            INDEX_METRICS.update_index(
+                self.name,
+                list(self.hot._docs_shard),
+                self.hot.shard_capacity,
+                cold_docs_shard=list(self._cold_docs_shard),
+                hot_bytes_shard=[int(d) * hrb for d in self.hot._docs_shard],
+                cold_bytes_shard=[int(d) * crb for d in self._cold_docs_shard],
+            )
+            # The hot tier is a DeviceKnnIndex whose publish hook this
+            # method replaces — keep its HBM ledger account (bytes + used
+            # fraction) current here instead.
+            self.hot._ledger_update()
 
     # -- cluster assignment ------------------------------------------------
 
@@ -665,7 +668,7 @@ class TieredKnnIndex:
             self._cold.erase([slot])
         self._meta.pop(key, None)
         if key in self.hot._slot_of:
-            self.hot.remove(key)  # publishes via the tiered override
+            self.hot.remove(key)  # owes a publish, paid via the tiered override
         else:
             self._cold_keys[c].discard(key)
             self._cold_docs_shard[_shard_of_key(key, self.n_shards)] -= 1

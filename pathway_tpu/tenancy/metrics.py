@@ -19,6 +19,8 @@ from __future__ import annotations
 import os
 import threading
 
+from ..ops.index_metrics import drain_owed
+
 _DEFAULT_METRIC_TENANTS = 50
 
 #: fold label for tenants past the cardinality cap
@@ -108,6 +110,7 @@ class TenancyMetrics:
 
     def active(self) -> bool:
         """Any tenant ever named? Gates every tenant-labeled line."""
+        drain_owed()
         with self._lock:
             return bool(self._tenants)
 
@@ -115,6 +118,7 @@ class TenancyMetrics:
         """Folded per-tenant view: the first ``metric_tenants()``
         tenants by name, the rest summed into ``tenant="other"``."""
         cap = metric_tenants()
+        drain_owed()
         with self._lock:
             names = list(self._tenants)
             named, folded = names[:cap], names[cap:]
@@ -143,6 +147,7 @@ class TenancyMetrics:
             }
 
     def reset(self) -> None:
+        drain_owed()
         with self._lock:
             self._tenants.clear()
 

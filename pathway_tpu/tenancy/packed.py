@@ -304,10 +304,11 @@ class TenantPackedIndex(DeviceKnnIndex):
 
     def remove(self, key) -> None:
         self._check_fence()
-        if self._drop(key) is None:
+        shard = self._drop(key)
+        if shard is None:
             self._cold_remove(key)
         else:
-            self._publish_metrics()
+            self._owe_publish(shard)
 
     def _free_slot(self, key, slot: int) -> None:
         # the slot stays reserved to its tenant's segment
@@ -622,8 +623,9 @@ class TenantPackedIndex(DeviceKnnIndex):
         return str(tenant) in self._cold
 
     def _publish_metrics(self) -> None:
-        super()._publish_metrics()
-        self._publish_tenants()
+        with self._publish_lock:  # a reader may be paying what removes owe
+            super()._publish_metrics()
+            self._publish_tenants()
 
     def _publish_tenants(self) -> None:
         """Book every tenant's segment bytes under the ``index.tenant``

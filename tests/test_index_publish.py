@@ -6,14 +6,24 @@ index gauges hold the same numbers: so a publish reads the counts and
 never the mask. The first test drives seeded random write sequences and
 sums the mask itself after each call; the second puts a mask in place
 whose reductions raise, and counts the publishes.
+
+``remove`` publishes nothing itself: the index owes the planes a publish
+and pays it once — at its next publish, at its next ``_sync``, or when a
+plane is read — so the gauges are read through ``snapshot()``, as a
+scrape reads them. The tests after those two hold that contract.
 """
 
 from __future__ import annotations
+
+import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pathway_tpu.freshness.plane import FRESHNESS
+from pathway_tpu.internals.ledger import LEDGER
 from pathway_tpu.ops.index_metrics import INDEX_METRICS
 from pathway_tpu.ops.knn import DeviceKnnIndex
 from pathway_tpu.ops.tiered_knn import TierConfig, TieredKnnIndex
@@ -28,6 +38,7 @@ TENANTS = ("a", "b", "c")
 @pytest.fixture(autouse=True)
 def _reset_planes():
     prev = set_tracing_enabled(False)
+    INDEX_METRICS.reset()  # an index an earlier test left owing pays here, uncounted
     yield
     set_tracing_enabled(prev)
     TRACE_STORE.reset()
@@ -40,8 +51,9 @@ def _assert_counts_are_the_mask(slab: DeviceKnnIndex) -> None:
     live = [int(n) for n in mask.sum(axis=1)]
     assert slab._docs_shard == live
     assert all(type(n) is int for n in slab._docs_shard)
-    assert INDEX_METRICS.indexes[slab.name]["docs_shard"] == live
-    assert INDEX_METRICS.indexes[slab.name]["shard_capacity"] == slab.shard_capacity
+    gauges = INDEX_METRICS.snapshot()["indexes"][slab.name]
+    assert gauges["docs_shard"] == live
+    assert gauges["shard_capacity"] == slab.shard_capacity
     assert sum(live) == len(slab._slot_of)
 
 
@@ -85,8 +97,10 @@ class _Flat:
     def remove(self, key) -> None:
         self.slab.remove(key)
 
-    def extra(self, rng) -> None:
+    def search(self, rng) -> None:
         self.slab.search_batch(rng.standard_normal((2, DIM)).astype(np.float32), 3)  # syncs
+
+    extra = search
 
 
 class _Packed:
@@ -137,6 +151,9 @@ class _TieredHot:
 
     def remove(self, key) -> None:
         self.tier.remove(key)
+
+    def search(self, rng) -> None:
+        self.tier.search_batch(rng.standard_normal((2, DIM)).astype(np.float32), 3)
 
     def extra(self, rng) -> None:
         c = int(rng.integers(max(1, self.tier._n_centroids)))
@@ -231,9 +248,9 @@ def test_the_write_path_never_reduces_the_slab_and_publishes_per_call(entry, rep
         call()
         return stage_totals().get("index_publish", {"calls": 0})["calls"]
 
-    assert publishes(lambda: idx.remove(0)) == 1
-    assert publishes(lambda: idx.remove(0)) == 0  # no row, nothing to tell
-    assert publishes(lambda: add([100, 101, 102])) == 1
+    assert publishes(lambda: idx.remove(0)) == 0 and idx._owed == {0}  # owed, not told
+    assert publishes(lambda: idx.remove(0)) == 0 and idx._owed == {0}  # no row, nothing more to tell
+    assert publishes(lambda: add([100, 101, 102])) == 1 and not idx._owed  # the add's pays the remove's
     # n keys added again: one publish for the rows that went, whatever
     # n, and the add's own
     keys = list(range(1, 1 + replaced)) + [200]
@@ -243,4 +260,213 @@ def test_the_write_path_never_reduces_the_slab_and_publishes_per_call(entry, rep
 
     mask = idx._valid_host.view(np.ndarray)
     assert idx._docs_shard == [int(mask.sum())] == [len(idx)]
-    assert INDEX_METRICS.indexes[idx.name]["docs_shard"] == idx._docs_shard
+    assert INDEX_METRICS.snapshot()["indexes"][idx.name]["docs_shard"] == idx._docs_shard
+
+
+# -- a run of removes publishes once ---------------------------------------
+
+OWING_KINDS = {
+    "flat": lambda: _Flat("arrays"),
+    "mesh8": lambda: _Flat("device", mesh_n=8),
+    "tiered_hot": _TieredHot,
+}
+
+
+@pytest.fixture()
+def freshness():
+    LEDGER.reset()
+    FRESHNESS.reset()
+    FRESHNESS.set_enabled(True)
+    yield FRESHNESS
+    FRESHNESS.set_enabled(None)
+    FRESHNESS.reset()
+    LEDGER.reset()
+
+
+def _publish_calls() -> int:
+    return stage_totals().get("index_publish", {"calls": 0})["calls"]
+
+
+def _resident_with_removes(kind: str, n_removed: int = 9):
+    """40 rows resident on the device, the planes told, then
+    ``n_removed`` of them removed with the publishes counted from the
+    first remove; the shards those rows lay in and the time before the
+    first of them come back too."""
+    rng = np.random.default_rng(32)
+    drv = OWING_KINDS[kind]()
+    drv.add(list(range(40)), rng.standard_normal((40, DIM)).astype(np.float32))
+    drv.search(rng)
+    slab = drv.slab
+    assert slab._dev_matrix is not None and not slab._owed
+    time.sleep(0.002)  # the watermarks so far lie before ``before``
+    before = time.time()
+    set_tracing_enabled(True)
+    TRACING_METRICS.reset()
+    touched = set()
+    for key in range(n_removed):
+        touched.add(slab._slot_of[key] // slab.shard_capacity)
+        drv.remove(key)
+        assert slab._docs_shard == [
+            int(n) for n in slab._valid_host.reshape(slab.n_shards, -1).sum(axis=1)
+        ]
+    assert _publish_calls() == 0 and slab._owed == touched
+    assert stage_totals().get("index_remove", {"rows": 0})["rows"] == n_removed
+    return drv, rng, touched, before
+
+
+def _assert_planes_are_exact(slab: DeviceKnnIndex, touched, before) -> None:
+    _assert_counts_are_the_mask(slab)
+    alloc = sum(int(a.nbytes) for a in (slab._dev_matrix, slab._dev_valid, slab._dev_bias))
+    hot = LEDGER.accounts()["index.hot"]
+    assert (hot["bytes"], hot["used_bytes"]) == (alloc, int(alloc * len(slab._slot_of) / slab.capacity))
+    for shard in range(slab.n_shards):
+        wm = FRESHNESS.visible_wm(slab, [shard])
+        if shard in touched:
+            assert wm[1] >= before
+        else:
+            assert wm is None or wm[1] < before  # nothing was removed there
+
+
+@pytest.mark.parametrize("then", ["add", "search"])
+@pytest.mark.parametrize("kind", list(OWING_KINDS))
+def test_removes_then_a_call_on_the_index_publish_once(kind, then, freshness):
+    drv, rng, touched, before = _resident_with_removes(kind)
+    if then == "add":
+        drv.add([100, 101], rng.standard_normal((2, DIM)).astype(np.float32))
+        touched |= {drv.slab._slot_of[k] // drv.slab.shard_capacity for k in (100, 101)}
+    else:
+        drv.search(rng)
+    assert _publish_calls() == 1 and not drv.slab._owed
+    _assert_planes_are_exact(drv.slab, touched, before)
+    assert _publish_calls() == 1  # the reads found nothing owed
+
+
+@pytest.mark.parametrize(
+    "read",
+    [
+        lambda slab: INDEX_METRICS.snapshot(),
+        lambda slab: INDEX_METRICS.active(),
+        lambda slab: LEDGER.accounts(),
+        lambda slab: LEDGER.snapshot(),
+        lambda slab: LEDGER.total_bytes(),
+        lambda slab: FRESHNESS.visible_wm(slab),
+        lambda slab: FRESHNESS.answer_bound(slab),
+        lambda slab: FRESHNESS.snapshot(),
+    ],
+    ids=[
+        "index_snapshot", "index_active", "ledger_accounts", "ledger_snapshot",
+        "ledger_total_bytes", "visible_wm", "answer_bound", "freshness_snapshot",
+    ],
+)
+@pytest.mark.parametrize("kind", list(OWING_KINDS))
+def test_removes_then_nothing_are_exact_on_the_first_read_of_any_plane(kind, read, freshness):
+    drv, rng, touched, before = _resident_with_removes(kind)
+    read(drv.slab)
+    assert _publish_calls() == 1 and not drv.slab._owed
+    _assert_planes_are_exact(drv.slab, touched, before)
+    read(drv.slab)
+    assert _publish_calls() == 1  # no second publish on the next
+
+
+@pytest.mark.parametrize("kind", list(OWING_KINDS))
+def test_removes_inside_an_epoch_move_its_watermark_when_it_commits(kind, freshness):
+    """The engine's turn: removes while an epoch executes, then the
+    commit. The owed shards are the epoch's, and read its drain cutoff."""
+    rng = np.random.default_rng(33)
+    drv = OWING_KINDS[kind]()
+    drv.add(list(range(40)), rng.standard_normal((40, DIM)).astype(np.float32))
+    slab = drv.slab
+    stale = slab._slot_of[0] // slab.shard_capacity
+    drv.remove(0)  # before the epoch: not the epoch's
+    FRESHNESS.begin_epoch(7)
+    FRESHNESS.epoch_staged(7)
+    FRESHNESS.epoch_exec(7)
+    assert not slab._owed and FRESHNESS.visible_wm(slab, [stale])[0] == -1
+    touched = set()
+    for key in range(1, 6):
+        touched.add(slab._slot_of[key] // slab.shard_capacity)
+        drv.remove(key)
+    assert slab._owed == touched
+    FRESHNESS.epoch_committed(7)
+    assert not slab._owed
+    for shard in touched:
+        assert FRESHNESS.visible_wm(slab, [shard])[0] == 7
+
+
+@pytest.mark.parametrize("kind", list(OWING_KINDS))
+def test_a_remove_of_a_key_with_no_row_owes_nothing(kind, freshness):
+    drv, rng, touched, before = _resident_with_removes(kind, n_removed=0)
+    wm = FRESHNESS.visible_wm(drv.slab)
+    drv.remove(10**9)
+    drv.remove(10**9 + 1)
+    assert not drv.slab._owed and _publish_calls() == 0
+    _assert_planes_are_exact(drv.slab, touched, before)
+    assert _publish_calls() == 0 and FRESHNESS.visible_wm(drv.slab) == wm
+
+
+def test_reset_settles_what_is_owed_and_forgets_it(freshness):
+    drv, rng, touched, before = _resident_with_removes("flat")
+    INDEX_METRICS.reset()
+    assert not drv.slab._owed and INDEX_METRICS.snapshot()["indexes"] == {}
+    assert _publish_calls() == 1  # the other planes were told before the gauges went
+    assert FRESHNESS.visible_wm(drv.slab)[1] >= before
+    drv.remove(20)
+    _assert_planes_are_exact(drv.slab, touched, before)  # and a later remove owes again
+
+
+def test_an_index_that_dies_owing_takes_its_debt_with_it():
+    from pathway_tpu.ops import index_metrics
+
+    drv, *_ = _resident_with_removes("flat")
+    name = drv.slab.name
+    told = INDEX_METRICS.snapshot()["indexes"][name]["docs"]
+    drv.remove(30)
+    assert drv.slab in index_metrics._OWING
+    del drv
+    assert len(index_metrics._OWING) == 0
+    assert INDEX_METRICS.snapshot()["indexes"][name]["docs"] == told
+
+
+@pytest.mark.parametrize("kind", ["flat", "mesh8"])
+def test_a_reader_draining_while_a_writer_removes_loses_no_shard(kind, freshness):
+    """A scrape thread reads every plane in a loop while the writer
+    removes key by key and adds now and then: both finish, and what the
+    planes hold at the end is what the index holds."""
+    rng = np.random.default_rng(34)
+    drv = OWING_KINDS[kind]()
+    n = 600
+    drv.add(list(range(n)), rng.standard_normal((n, DIM)).astype(np.float32))
+    drv.search(rng)
+    slab = drv.slab
+    time.sleep(0.002)
+    before = time.time()
+    stop = threading.Event()
+    failures: list = []
+
+    def scrape():
+        try:
+            while not stop.is_set():
+                INDEX_METRICS.snapshot()
+                LEDGER.accounts()
+                FRESHNESS.visible_wm(slab)
+                FRESHNESS.snapshot()
+        except Exception as exc:  # noqa: BLE001 - the test reports it
+            failures.append(exc)
+
+    reader = threading.Thread(target=scrape, daemon=True)
+    reader.start()
+    touched = set()
+    try:
+        for key in range(n - 40):
+            touched.add(slab._slot_of[key] // slab.shard_capacity)
+            drv.remove(key)
+            if key % 97 == 0:
+                fresh = [n + key, n + key + 1]
+                drv.add(fresh, rng.standard_normal((2, DIM)).astype(np.float32))
+                touched |= {slab._slot_of[k] // slab.shard_capacity for k in fresh}
+    finally:
+        stop.set()
+        reader.join(timeout=60)
+    assert not reader.is_alive() and not failures
+    _assert_planes_are_exact(slab, touched, before)
+    assert not slab._owed

@@ -8,10 +8,12 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
 
+from pathway_tpu.freshness.plane import FRESHNESS
 from pathway_tpu.internals.ledger import LEDGER, hot_row_bytes, parse_bytes
 from pathway_tpu.ops.index_metrics import INDEX_METRICS
 from pathway_tpu.ops.knn import DeviceKnnIndex
@@ -627,12 +629,44 @@ def test_imbalance_counts_live_rows_not_granted_extents():
     rng = _rng(11)
     idx.add_tenant_batch("a", [0, 1, 2], rng.standard_normal((3, 8)))
     assert idx._tenant_rows["a"] == _MIN_EXTENT  # 8 rows reserved
-    idx._publish_metrics()
-    entry = INDEX_METRICS.indexes[idx.name]
+    entry = INDEX_METRICS.snapshot()["indexes"][idx.name]
     assert entry["docs_shard"] == [3]  # live rows, not the 8-row grant
     idx.remove_tenant("a", 0)
-    idx._publish_metrics()
-    assert INDEX_METRICS.indexes[idx.name]["docs_shard"] == [2]
+    assert INDEX_METRICS.snapshot()["indexes"][idx.name]["docs_shard"] == [2]
+
+
+def test_a_run_of_tenant_removes_publishes_once_and_moves_the_watermark():
+    """``TenantPackedIndex.remove`` owes its publish as the flat index's
+    does: no plane is told key by key, every plane is exact when read,
+    and the freshness watermark moves, which the per-key publish of the
+    gauges alone never did."""
+    FRESHNESS.reset()
+    FRESHNESS.set_enabled(True)
+    try:
+        idx = TenantPackedIndex(8, reserved_space=64)
+        rng = _rng(32)
+        idx.add_tenant_batch("a", list(range(6)), rng.standard_normal((6, 8)))
+        idx.add_tenant_batch("b", [0, 1], rng.standard_normal((2, 8)))
+        idx.search_tenant_batch("a", rng.standard_normal((1, 8)), 2)  # resident: the ledger has its rows
+        time.sleep(0.002)
+        before = time.time()
+        told = []
+        publish = idx._publish_metrics
+        idx._publish_metrics = lambda: (told.append(1), publish())[1]
+        for key in range(4):
+            idx.remove_tenant("a", key)
+        idx.remove_tenant("a", 99)  # no row, hot or cold
+        assert not told and idx._owed == {0}
+        assert FRESHNESS.visible_wm(idx)[1] >= before and len(told) == 1 and not idx._owed
+        assert INDEX_METRICS.snapshot()["indexes"][idx.name]["docs_shard"] == [4]
+        assert TENANCY_METRICS.snapshot()["tenants"]["a"]["docs"] == 2
+        seg = LEDGER.accounts()["index.tenant"]
+        assert seg["used_bytes"] == 4 * hot_row_bytes(8) and len(told) == 1
+        idx.remove_tenant("b", 0)
+        assert TENANCY_METRICS.snapshot()["tenants"]["b"]["docs"] == 1 and len(told) == 2
+    finally:
+        FRESHNESS.set_enabled(None)
+        FRESHNESS.reset()
 
 
 def test_live_docs_shard_matches_valid_mask_on_plain_index():

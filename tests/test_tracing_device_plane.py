@@ -25,6 +25,7 @@ from pathway_tpu import tracing
 from pathway_tpu.models.encoder import EncoderConfig
 from pathway_tpu.models.sentence_encoder import SentenceEncoder
 from pathway_tpu.ops import knn
+from pathway_tpu.ops.index_metrics import INDEX_METRICS
 from pathway_tpu.tracing import TRACE_STORE, TRACING_METRICS, set_tracing_enabled, span, stage_totals
 from pathway_tpu.tracing import store as trace_store
 from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
@@ -147,7 +148,7 @@ def test_on_a_write_batch_gives_every_stage_with_its_units(index, enc, embedder)
     totals = stage_totals()
     assert WRITE_STAGES <= set(totals) and not QUERY_STAGES & set(totals)
     assert totals["index_remove"]["calls"] == totals["index_remove"]["rows"] == len(keys)
-    assert totals["index_publish"]["calls"] == len(keys) + 1  # each remove, then the add
+    assert totals["index_publish"]["calls"] == 1  # the add's, which pays what the removes owe
     assert totals["index_add"]["calls"] == 1 and totals["index_add"]["rows"] == len(keys)
     assert totals["index_scatter"]["rows"] == len(keys)
     assert totals["index_flush"]["rows"] == len(keys)  # the removes' tombstones
@@ -206,7 +207,8 @@ def test_under_a_request_the_batches_join_its_trace(index, embedder):
     for stage in ("index_remove", "embed_batch", "index_add", "query_batch"):
         (sp,) = spans[stage]
         assert (sp["trace"], sp["parent"]) == (request.trace_id, request.span_id)
-    assert spans["index_publish"][0]["parent"] == spans["index_remove"][0]["span"]
+    (publish,) = spans["index_publish"]  # one for the remove and the add, inside the add
+    assert publish["parent"] == spans["index_add"][0]["span"]
 
 
 def test_a_bare_remove_builds_no_span(index):
@@ -217,10 +219,15 @@ def test_a_bare_remove_builds_no_span(index):
     assert TRACE_STORE.spans_total == 0 and TRACE_STORE.traces_total == 0
     assert TRACE_STORE.recent_spans() == [] and TRACE_STORE.exemplar_traces() == []
     totals = stage_totals()
-    assert set(totals) == {"index_remove", "index_publish"}
+    assert set(totals) == {"index_remove"}  # the publish is owed: twelve removes, none told
     assert totals["index_remove"]["calls"] == totals["index_remove"]["rows"] == 13
-    assert totals["index_publish"]["calls"] == 12
-    assert 0 < totals["index_publish"]["seconds"] <= totals["index_remove"]["seconds"]
+    # a scrape is told once, by a publish that builds no span either
+    assert INDEX_METRICS.snapshot()["indexes"][index.name]["docs"] == 12
+    INDEX_METRICS.snapshot()
+    assert TRACE_STORE.spans_total == 0 and TRACE_STORE.recent_spans() == []
+    totals = stage_totals()
+    assert set(totals) == {"index_remove", "index_publish"}
+    assert totals["index_publish"]["calls"] == 1 and totals["index_publish"]["seconds"] > 0
 
 
 def test_replaced_keys_nest_in_the_add_and_can_be_taken_out(index, embedder):
