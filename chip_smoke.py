@@ -342,6 +342,35 @@ def check_selective_scan(rng) -> None:
     )
 
 
+def check_latent_moe(rng) -> None:
+    """The latent-attention / sparse-expert encoder at its test preset,
+    through the embedder's table, ``encode_device``, ``add_batch_device``
+    and the fused text-query program."""
+    import jax
+
+    from pathway_tpu.models.sentence_encoder import SentenceEncoder
+    from pathway_tpu.ops.knn import DeviceKnnIndex
+
+    enc = SentenceEncoder("latent-moe-tiny-for-tests")
+    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(n))) for n in rng.integers(8, 60, size=64)]
+    rows = enc.encode_device(docs)
+    ids = jax.ShapeDtypeStruct((8, 64), np.int16)
+    check(
+        has_mosaic_kernel(enc._fwd_group.__wrapped__, enc.params, ids, jax.ShapeDtypeStruct((8,), np.int32)),
+        "latent-moe-tiny-for-tests: the group forward compiles its expert layer to a Mosaic kernel",
+    )
+    index = DeviceKnnIndex(enc.dim, metric="cos", reserved_space=64)
+    index.attach_encoder(enc)
+    index.add_batch_device(list(range(len(docs))), rows, None)
+    answers = index.search_texts_batch(docs[:16], 1)
+    norms = np.linalg.norm(np.asarray(rows), axis=1)
+    check(
+        bool(np.isfinite(norms).all()) and float(np.abs(norms - 1.0).max()) < 1e-3
+        and all(a and a[0][0] == i and a[0][1] > 0.99 for i, a in enumerate(answers)),
+        "latent-moe-tiny-for-tests embeds 64 documents to unit rows and each of 16 finds itself first through DeviceKnnIndex",
+    )
+
+
 def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -707,6 +736,7 @@ def main() -> None:
     check_pallas_knn(rng, resolve_mesh(mesh_chips))
     check_paged_attention(DecoderConfig(), rng)
     check_selective_scan(rng)
+    check_latent_moe(rng)
 
     docs = make_corpus(rng)
     serve_and_check(docs, mesh_chips)

@@ -46,6 +46,7 @@ registries lazily so the analysis plane stays device-free.
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 import time
@@ -196,12 +197,17 @@ def footprint(
 def pytree_nbytes(tree: Any) -> int:
     """Sum ``nbytes`` over an arbitrarily nested dict/list/tuple of
     arrays (a flax param pytree) without importing jax — works on
-    device arrays and host numpy alike."""
+    device arrays and host numpy alike, and on leaves that are only a
+    shape and a type (``jax.ShapeDtypeStruct``: a tree whose values are
+    not made yet), which count what their arrays will."""
     if isinstance(tree, (list, tuple)):
         return sum(pytree_nbytes(x) for x in tree)
     if hasattr(tree, "items"):
         return sum(pytree_nbytes(v) for v in tree.values())
-    return int(getattr(tree, "nbytes", 0) or 0)
+    nbytes = getattr(tree, "nbytes", None)
+    if nbytes is None and hasattr(tree, "shape") and hasattr(tree, "dtype"):
+        nbytes = math.prod(tree.shape) * tree.dtype.itemsize
+    return int(nbytes or 0)
 
 
 #: Nominal bytes per compiled executable for the ``compile_cache``

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..tracing import span as _span
+from ..tracing import TRACING_METRICS, span as _span, tracing_enabled as _tracing_enabled
 from .batching import DEFAULT_SEQ_BUCKETS, chunks, pad_token_batch
 from .encoder import (
     CrossEncoderHead,
@@ -29,6 +29,7 @@ from .encoder import (
     load_hf_weights,
 )
 from .hybrid_ssm import HybridSSMConfig, HybridSSMEncoder
+from .latent_moe import LatentMoEConfig, LatentMoEEncoder
 from .tokenizer import WordPieceTokenizer, default_tokenizer
 
 #: model name -> the configuration it stands for; a name that is not
@@ -39,7 +40,27 @@ ARCHITECTURES = {
     "ai21-jamba2-3b": HybridSSMConfig.jamba2_3b,
     # no published model: both kinds of hybrid layer at test widths
     "hybrid-ssm-tiny-for-tests": HybridSSMConfig.tiny_for_tests,
+    # one chip's share of the published model: 16 of 256 experts a
+    # layer, 1 + 4 of its 3 + 58 layers, an eighth of its vocabulary
+    "openpangu-ultra-moe-718b.ep16-l5": LatentMoEConfig.pangu_ultra_moe_ep16_l5,
+    # no published model: a dense and two sparse-expert layers at test widths
+    "latent-moe-tiny-for-tests": LatentMoEConfig.tiny_for_tests,
 }
+
+#: the modules that are not the flax BERT block: they make their own
+#: parameter tree, leaf by leaf in its final types, and say how many
+#: tokens a dispatch group may hold
+_OWN_MODULES = {HybridSSMConfig: HybridSSMEncoder, LatentMoEConfig: LatentMoEEncoder}
+
+
+def _param_shapes(module):
+    """``module.param_kinds()`` as a tree of ``jax.ShapeDtypeStruct``: what
+    ``module.init`` would make, and no array."""
+    return jax.tree_util.tree_map(
+        lambda kind: jax.ShapeDtypeStruct(kind[0], jnp.dtype(kind[1])),
+        module.param_kinds(),
+        is_leaf=lambda x: isinstance(x, tuple),
+    )
 
 
 def architecture_of(model: str):
@@ -80,19 +101,23 @@ class SentenceEncoder:
         self.max_seq_len = max_seq_len
         self.max_batch = max_batch
         checkpoint_dir = checkpoint_dir or os.environ.get("PATHWAY_TPU_CKPT")
-        if isinstance(config, HybridSSMConfig):
+        self._seed = seed
+        if type(config) in _OWN_MODULES:
             if checkpoint_dir and os.path.isdir(checkpoint_dir):
                 raise NotImplementedError(
-                    f"no checkpoint loader for the hybrid state-space tree of {model!r}: "
+                    f"no checkpoint loader for the parameter tree of {model!r}: "
                     f"{checkpoint_dir} would be ignored and seeded weights scored in its place"
                 )
             if mesh is not None:
-                raise NotImplementedError("the hybrid state-space encoder has no mesh path")
-            self.module = HybridSSMEncoder(config)
+                raise NotImplementedError(f"the encoder of {model!r} has no mesh path")
+            self.module = _OWN_MODULES[type(config)](config)
             # a dispatch group is bounded by tokens, not by rows: the
             # module says how many it lets be alive at once
             self.max_batch = min(max_batch, max(8, config.max_group_tokens // max_seq_len))
-            self.params = self.module.init(seed)
+            # shapes and types until a forward needs values: whoever
+            # brings weights of their own (a loader, the benchmark) lays
+            # them over this tree, and a second copy never exists
+            self.params = _param_shapes(self.module)
             self.tokenizer = WordPieceTokenizer(vocab_size=config.vocab_size)
         else:
             self.module = TextEncoder(config)
@@ -144,6 +169,17 @@ class SentenceEncoder:
     def dim(self) -> int:
         return self.cfg.hidden_size
 
+    def live_params(self):
+        """The parameter tree with values: the seeded leaves are made,
+        leaf by leaf, the first time a forward asks and nobody assigned
+        a tree of arrays in the meantime."""
+        leaf = self.params
+        while isinstance(leaf, dict) and leaf:
+            leaf = next(iter(leaf.values()))
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            self.params = self.module.init(self._seed)
+        return self.params
+
     def jit_cache_size(self) -> int:
         """Distinct compiled entries in the forward jit's cache — the
         ground truth the deep verifier's recompilation predictor
@@ -172,7 +208,7 @@ class SentenceEncoder:
                 mask = np.concatenate([mask, np.zeros((pad, mask.shape[1]), bool)])
             ids = jax.device_put(ids, self._data_sharding)
             mask = jax.device_put(mask, self._data_sharding)
-        return self._fwd(self.params, ids, mask)
+        return self._fwd(self.live_params(), ids, mask)
 
     def encode_tokens(self, toks: Sequence[list[int]], as_numpy: bool = True):
         """Embed pre-tokenized sequences. Dispatch is async: all buckets
@@ -308,7 +344,9 @@ class SentenceEncoder:
                         lens=lens_.astype(jnp.int32),
                         interpret=fused_encoder_interpret(self.cfg),
                     )
-                return self.module.apply(p, ids32, mask)
+                # a module with sparse experts also says how many real
+                # tokens each held expert got: (rows, loads)
+                return getattr(self.module, "apply_with_loads", self.module.apply)(p, ids32, mask)
 
             from ..internals.profiler import wrap_jit
 
@@ -342,11 +380,17 @@ class SentenceEncoder:
                 # dispatch pipelining); jit compiles nested in this window
                 # book under `compile`, not here
                 with CHIP_LEDGER.timed("encode"):
-                    out = self._fwd_group(self.params, ids_dev, lens_dev)
+                    out = self._fwd_group(self.live_params(), ids_dev, lens_dev)
                     jax.block_until_ready(out)
             else:
-                out = self._fwd_group(self.params, ids_dev, lens_dev)
+                out = self._fwd_group(self.live_params(), ids_dev, lens_dev)
             self._wire_ring.retire([ids_dev, lens_dev])
+        if isinstance(out, tuple):
+            out, loads = out
+            if _tracing_enabled():
+                # the device's own count, handed over un-fetched: whoever
+                # reads the stage totals pays the transfer, not this path
+                TRACING_METRICS.owe_expert_loads("embed_experts", loads)
         return out
 
     def _record_dispatch(self, batch: int, seq: int, lens: np.ndarray) -> int:

@@ -376,7 +376,7 @@ class FusedRagPipeline:
         chip = CHIP_LEDGER.on()
         with CHIP_LEDGER.timed("rag.fused") if chip else nullcontext():
             fslots, fvals, _, _ = self._fused_fn()(
-                self.enc.params,
+                self.enc.live_params(),
                 self.cross.params if self.cross is not None else None,
                 ids,
                 lens_p,
@@ -455,7 +455,7 @@ class FusedRagPipeline:
         ids, lens_p, kr = self._padded_queries(texts, k_retrieve)
         use_cross = rerank and self.cross is not None
         fslots, fvals, gen = self._answer_fn(int(max_new), use_cross)(
-            self.enc.params,
+            self.enc.live_params(),
             self.cross.params if use_cross else None,
             self._dec_params,
             ids,

@@ -1571,7 +1571,7 @@ class DeviceKnnIndex:
             with _span("query_device", queries=n):
                 packed = np.asarray(
                     self._fused_jit(
-                        enc.params,
+                        enc.live_params(),
                         ids,
                         lens_p,
                         self._dev_matrix,

@@ -14,6 +14,12 @@ Beside calls and seconds a stage's totals hold the sums of the work
 units its sites pass as span attributes (``rows``, ``queries``,
 ``tokens``), so a per-layer ratio is taken where the work happens;
 :meth:`TracingMetrics.totals` reads them, summed over workers.
+
+A count that only the device knows — the tokens a sparse layer routed to
+each expert held here — is *owed*: the dispatch site hands over the
+device array un-fetched (:meth:`TracingMetrics.owe_expert_loads`) and
+every read of the registry folds what is owed into the stage's totals
+first, so the transfer is the reader's and never the write path's.
 """
 
 from __future__ import annotations
@@ -21,10 +27,17 @@ from __future__ import annotations
 import threading
 import time as _time
 
+import numpy as np
+
 from ..serving.metrics import STAGE_BUCKETS
 
 #: span attributes that count work: summed into the stage's totals
 WORK_UNITS = ("rows", "queries", "tokens")
+#: and two more that only a stage fed from the device's own counts has
+LOAD_UNITS = ("max_load", "mean_load")
+#: owed arrays kept before the hand-over itself folds them: a bound for
+#: a process that traces and never reads
+_OWED_MOST = 1024
 
 
 class _ExemplarHistogram:
@@ -55,10 +68,10 @@ class _ExemplarHistogram:
         self.total += seconds
         self.count += 1
         if units:
-            for name in WORK_UNITS:
+            for name in WORK_UNITS + LOAD_UNITS:
                 n = units.get(name)
                 if n:
-                    self.units[name] += int(n)
+                    self.units[name] = self.units.get(name, 0) + (n if isinstance(n, float) else int(n))
 
     def cumulative(self) -> list[tuple[str, int, tuple[str, float, float] | None]]:
         """(le, cumulative count, bucket exemplar) ending at +Inf."""
@@ -78,6 +91,32 @@ class TracingMetrics:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._hists: dict[tuple[str, int], _ExemplarHistogram] = {}
+        self._owed: list[tuple[str, int, object]] = []  # (stage, worker, device array)
+
+    def owe_expert_loads(self, stage: str, loads, *, worker: int = 0) -> None:
+        """``loads``: a device array ``[layer calls, experts held]`` of
+        the real tokens a dispatch assigned to each held expert, not
+        fetched here. Folded at the next read: a call of ``stage`` per
+        row, ``rows`` its sum, ``max_load`` its largest entry and
+        ``mean_load`` its mean."""
+        with self._lock:
+            self._owed.append((stage, int(worker), loads))
+        if len(self._owed) > _OWED_MOST:
+            self._pay_owed()
+
+    def _fold(self, owed) -> None:
+        for stage, worker, loads in owed:
+            for row in np.asarray(loads):
+                units = {"rows": int(row.sum()), "max_load": int(row.max()), "mean_load": float(row.mean())}
+                self.observe(stage, 0.0, "", worker=worker, units=units)
+
+    def _pay_owed(self) -> None:
+        """Before every read, outside the lock ``observe`` takes."""
+        if not self._owed:
+            return
+        with self._lock:
+            owed, self._owed = self._owed, []
+        self._fold(owed)
 
     def observe(
         self,
@@ -100,12 +139,14 @@ class TracingMetrics:
     def active(self) -> bool:
         """Anything to render? (keeps /metrics byte-identical for runs
         that never record a span)"""
+        self._pay_owed()
         with self._lock:
             return bool(self._hists)
 
     def series(self) -> list[dict]:
         """Render-ready rows for the monitoring server, sorted for
         stable scrape output."""
+        self._pay_owed()
         with self._lock:
             items = sorted(self._hists.items())
             out = []
@@ -122,6 +163,7 @@ class TracingMetrics:
         return out
 
     def snapshot(self) -> dict:
+        self._pay_owed()
         with self._lock:
             return {
                 f"{stage}[w{worker}]": {
@@ -137,6 +179,7 @@ class TracingMetrics:
         """``{stage: {"calls", "seconds", "rows", "queries", "tokens"}}``,
         summed over workers: what a per-layer metric divides."""
         out: dict[str, dict] = {}
+        self._pay_owed()
         with self._lock:
             for (stage, _worker), h in self._hists.items():
                 t = out.setdefault(
@@ -145,12 +188,13 @@ class TracingMetrics:
                 t["calls"] += h.count
                 t["seconds"] += h.total
                 for name, n in h.units.items():
-                    t[name] += n
+                    t[name] = t.get(name, 0) + n
         return out
 
     def reset(self) -> None:
         with self._lock:
             self._hists.clear()
+            self._owed.clear()
 
 
 #: Process-wide registry surfaced on ``/metrics`` and ``/status``.

@@ -26,6 +26,7 @@ from pathway_tpu.decode.engine import DecoderConfig
 from pathway_tpu.models.batching import DEFAULT_SEQ_BUCKETS
 from pathway_tpu.models.encoder import EncoderConfig, TextEncoder, init_params
 from pathway_tpu.models.sentence_encoder import SentenceEncoder
+from pathway_tpu.ops.expert_dispatch import capacity_of, grouped_matmul
 from pathway_tpu.ops.fused_attention import attention
 from pathway_tpu.ops.fused_layer import _pack_rows, encoder_forward
 from pathway_tpu.ops.paged_attention import paged_decode_attention
@@ -113,6 +114,14 @@ def _scan_case(batch: int, seq: int):
     )
 
 
+def _experts_case(k: int, n: int):
+    # one round of a write batch of the expert-parallel embedder: the
+    # even share of 8,192 tokens' top-8 of 256 on 16 held experts, at
+    # its published widths (gate / up: 7,680 -> 2,048; down: back)
+    rows, held = capacity_of(8192, 8, 16, 256), 16
+    return grouped_matmul, (_spec((rows, k), jnp.bfloat16), _spec((held, k, n), jnp.bfloat16), _spec((held,), jnp.int32))
+
+
 SINGLE_DEVICE_CASES = {
     **{
         f"encoder_forward[S={s}]": functools.partial(_encoder_case, s)
@@ -130,6 +139,8 @@ SINGLE_DEVICE_CASES = {
     },
     "selective_scan[B=32,S=256]": functools.partial(_scan_case, 32, 256),
     "selective_scan[B=8,S=16]": functools.partial(_scan_case, 8, 16),
+    "expert_grouped_matmul[4096x7680x2048]": functools.partial(_experts_case, 7680, 2048),
+    "expert_grouped_matmul[4096x2048x7680]": functools.partial(_experts_case, 2048, 7680),
 }
 
 

@@ -398,6 +398,24 @@ def _hybrid_forward(enc):
     return (lambda: jax.jit(module.apply)), shapes, {}
 
 
+def _latent_moe_forward(enc):
+    """The latent-attention / sparse-expert encoder's forward, at its test
+    preset (the grouped product in the Pallas interpreter: this lowers
+    for the CPU)."""
+    from pathway_tpu.models.latent_moe import LatentMoEConfig, LatentMoEEncoder
+
+    module = LatentMoEEncoder(LatentMoEConfig.tiny_for_tests(expert_impl="interpret"))
+    shapes = (
+        jax.eval_shape(module.init),
+        jax.ShapeDtypeStruct((8, 16), np.int32),
+        jax.ShapeDtypeStruct((8, 16), np.bool_),
+    )
+    return (lambda: jax.jit(module.apply)), shapes, {}
+
+
+LATENT_MOE_SCOPES = tuple(
+    "pw.encode." + s for s in ("mla_q", "mla_kv", "attn", "moe_route", "moe_experts", "moe_shared", "mlp", "pool")
+)
 HYBRID_SCOPES = tuple("pw.encode." + s for s in ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out", "attn", "mlp", "pool"))
 
 
@@ -405,6 +423,7 @@ HYBRID_SCOPES = tuple("pw.encode." + s for s in ("ssm_in", "ssm_conv", "ssm_scan
     "program, scopes",
     [
         (_hybrid_forward, HYBRID_SCOPES),
+        (_latent_moe_forward, LATENT_MOE_SCOPES),
         (_fused, ("pw.query.encode", "pw.query.scan", "pw.query.topk")),
         (_scatter_dev, ("pw.index.scatter",)),
         (_scatter_tomb, ("pw.index.tomb",)),
