@@ -274,6 +274,27 @@ def check_pallas_knn(rng, mesh) -> None:
     check(ok, f"knn_topk_sharded over {mesh.shape['data']} chips: shortfall {shortfall:.2e}")
 
 
+def check_topk_select(rng) -> None:
+    """The query programs' two-stage selection against ``lax.top_k`` at
+    a row the shape rule sends through the blocks: seeded scores with
+    no ties, so values and slots must both be equal, exactly."""
+    import jax
+
+    from pathway_tpu.ops.knn import _select_topk, _topk_route
+
+    q, n, k = 16, 409_600, 16
+    scores = jax.numpy.asarray(rng.permutation(q * n).reshape(q, n).astype(np.float32) / (q * n))
+    vals, idx = jax.jit(_select_topk, static_argnames="k")(scores, k=k)
+    want_vals, want_idx = jax.lax.top_k(scores, k)
+    check(
+        _topk_route(n, k) == "blocks"
+        and np.array_equal(np.asarray(vals), np.asarray(want_vals))
+        and np.array_equal(np.asarray(idx), np.asarray(want_idx)),
+        f"_select_topk [{q}, {n}] k={k} takes the block route and returns lax.top_k's "
+        "values and slots exactly",
+    )
+
+
 def check_paged_attention(decoder, rng) -> None:
     import jax
 
@@ -734,6 +755,7 @@ def main() -> None:
     check_fused_layer(minilm, rng)
     check_fused_attention(minilm, rng)
     check_pallas_knn(rng, resolve_mesh(mesh_chips))
+    check_topk_select(rng)
     check_paged_attention(DecoderConfig(), rng)
     check_selective_scan(rng)
     check_latent_moe(rng)

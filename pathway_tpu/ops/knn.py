@@ -87,9 +87,12 @@ def _topk_fn(metric: str) -> Callable:
         @partial(jax.jit, static_argnames=("k",))
         def topk_dot(matrix, valid, queries, k):
             # cos: rows pre-normalized so cosine == dot; ip: raw dot
-            scores = queries @ matrix.T  # [q, cap] — the MXU hot loop
+            # the MXU hot loop: all [q, cap] scores are written once,
+            # and _select_topk picks from them (block maxima, then the
+            # winning blocks) without sorting the row
+            scores = queries @ matrix.T
             scores = jnp.where(valid[None, :], scores, _NEG)
-            return jax.lax.top_k(scores, k)
+            return _select_topk(scores, k)
 
         @partial(jax.jit, static_argnames=("k",))
         def topk_l2(matrix, valid, queries, k):
@@ -97,7 +100,7 @@ def _topk_fn(metric: str) -> Callable:
             sq = jnp.sum(matrix * matrix, axis=1)
             scores = 2.0 * (queries @ matrix.T) - sq[None, :]
             scores = jnp.where(valid[None, :], scores, _NEG)
-            neg_d2, idx = jax.lax.top_k(scores, k)
+            neg_d2, idx = _select_topk(scores, k)
             qq = jnp.sum(queries * queries, axis=1, keepdims=True)
             return neg_d2 - qq, idx
 
@@ -105,6 +108,82 @@ def _topk_fn(metric: str) -> Callable:
         _JIT["ip"] = topk_dot
         _JIT["l2"] = topk_l2
     return _JIT[metric]
+
+
+#: Columns a block of the two-stage top-k holds, and how many blocks a
+#: row must have for each of its k winners before that route pays: both
+#: from timings of the helper alone on the v5e (PERF.md, PR 34).
+_TOPK_BLOCK = 1024
+_TOPK_MIN_BLOCKS_PER_K = 4
+
+
+def _topk_route(n: int, k: int) -> str:
+    """Which way ``_select_topk`` goes for rows of ``n`` scores:
+    ``"blocks"`` or ``"full"``. Static per compiled shape, and a
+    function of nothing else: a small index, a capacity that is no
+    multiple of a block, a refetch whose k has grown towards n all take
+    ``lax.top_k`` over the whole row."""
+    blocks = n // _TOPK_BLOCK
+    if n % _TOPK_BLOCK == 0 and blocks >= _TOPK_MIN_BLOCKS_PER_K * k:
+        return "blocks"
+    return "full"
+
+
+def _select_topk(scores, k: int):
+    """``jax.lax.top_k(scores, k)`` of a wide ``[q, n]`` score matrix:
+    the same values and slots, ties to the lower slot.
+
+    On a long row it selects in two exact stages. Cut the row into
+    blocks of ``_TOPK_BLOCK`` consecutive slots: each of a query's k
+    best scores lies in a block whose maximum is at least the k-th best
+    score, and at most k blocks have such a maximum, so the k blocks
+    with the largest maxima hold the whole top-k. One pass takes the
+    block maxima; ``lax.top_k`` ranks ``n / _TOPK_BLOCK`` of them, not
+    n scores; only the winning blocks' scores go through the second
+    ``lax.top_k``."""
+    import jax
+
+    if _topk_route(scores.shape[1], k) == "full":
+        return jax.lax.top_k(scores, k)
+    return _select_blocks(scores, k)
+
+
+def _select_blocks(scores, k: int):
+    """The two-stage route. Everything wide stays in the (8 queries x
+    128 slots) tiles the matmul wrote the scores in — a reduction or a
+    gather along the row as XLA would lay it out costs a copy of the
+    whole matrix first (timed on the v5e: PERF.md, PR 34)."""
+    import jax
+    import jax.numpy as jnp
+
+    q, n = scores.shape
+    if q % 8:  # whole sublane groups of queries; no pad row is ranked
+        scores = jnp.pad(scores, ((0, -q % 8), (0, 0)), constant_values=_NEG)
+    groups, width = scores.shape[0] // 8, _TOPK_BLOCK
+    blocks, per = n // width, width // 128
+    # [group, tile along the row, query of the group, slot of the tile]
+    tiles = scores.reshape(groups, 8, n // 128, 128).transpose(0, 2, 1, 3)
+    # a block's maximum: first across its tiles, slot by slot, which
+    # reads the scores once and moves nothing; then across the slots of
+    # what is left, 1/per of the data. Kept apart: merged into one
+    # reduction XLA lays the whole matrix out anew for it
+    lane_max = tiles.reshape(groups, blocks, per, 8, 128).max(axis=2)
+    lane_max = jax.lax.optimization_barrier(lane_max)
+    block_max = lane_max.max(axis=3).transpose(0, 2, 1).reshape(groups * 8, blocks)
+    _, won = jax.lax.top_k(block_max[:q], k)
+    # ascending, so that candidates keep slot order and the second
+    # top_k breaks a tie the way one over the whole row would
+    won = jnp.sort(won, axis=1)
+    # the winning blocks' scores: whole tiles, then the query's own row
+    # of each (eight times the bytes, and no slice narrower than a tile)
+    query = jnp.arange(q, dtype=won.dtype)
+    first = (query // 8)[:, None] * (n // 128) + won * per
+    tile_ids = first[:, :, None] + jnp.arange(per, dtype=won.dtype)
+    got = jnp.take(tiles.reshape(groups * (n // 128), 8, 128), tile_ids.reshape(-1), axis=0)
+    got = got.reshape(q, k, per, 8, 128)
+    cand = jnp.take_along_axis(got, (query % 8)[:, None, None, None, None], axis=3)
+    vals, pos = jax.lax.top_k(cand.reshape(q, k * width), k)
+    return vals, jnp.take_along_axis(won, pos // width, axis=1) * width + pos % width
 
 
 def _pallas_eligible(metric: str, k: int, mesh) -> bool:
@@ -548,7 +627,7 @@ def _mesh_fns(mesh) -> dict[str, Callable]:
             if l2:
                 scores = 2.0 * scores - jnp.sum(m * m, axis=1)[None, :]
             scores = jnp.where(v[None, :], scores, _NEG)
-            vals, idx = jax.lax.top_k(scores, k_local)
+            vals, idx = _select_topk(scores, k_local)
             return vals, idx + jax.lax.axis_index(DATA_AXIS) * m.shape[0]
 
         return jax.shard_map(
@@ -588,8 +667,11 @@ def _mesh_fns(mesh) -> dict[str, Callable]:
 
 
 def _fused_query_fn(module, cfg) -> Callable:
-    """The text-query program: encode -> score every row -> top-k, one
-    dispatch. ``cfg`` picks the whole-layer kernel where it applies."""
+    """The text-query program, one dispatch: encode -> score every row
+    (the ``[q, capacity]`` float32 scores are written once) -> select
+    the top-k from them (``_select_topk``: on a long row the block
+    maxima and the k winning blocks, never a sort of the whole row).
+    ``cfg`` picks the whole-layer kernel where it applies."""
     import jax
     import jax.numpy as jnp
     from functools import partial
@@ -616,7 +698,7 @@ def _fused_query_fn(module, cfg) -> Callable:
                 scores = 2.0 * scores - sq[None, :] - 1.0  # |emb|=1
             scores = jnp.where(valid[None, :], scores, _NEG)
         with jax.named_scope("pw.query.topk"):
-            vals, idx = jax.lax.top_k(scores, k)
+            vals, idx = _select_topk(scores, k)
             # ONE packed host transfer: bitcast(scores) | idx — two
             # separate np.asarray pulls pay the device->host
             # round-trip twice per epoch. Packed as int32, not f32:
@@ -1568,7 +1650,8 @@ class DeviceKnnIndex:
             # the fused kernel scores every query each pass; refills
             # (rare, filter starvation) just deepen fetch for all
             kk = min(fetch, self.capacity)
-            with _span("query_device", queries=n):
+            route = _topk_route(int(self._dev_matrix.shape[0]), kk)
+            with _span("query_device", queries=n, topk=route):
                 packed = np.asarray(
                     self._fused_jit(
                         enc.live_params(),
@@ -1580,6 +1663,11 @@ class DeviceKnnIndex:
                         l2=self.metric == "l2",
                     )
                 )
+            if route == "blocks":
+                # a stage with no time of its own: its queries over
+                # query_batch's are the share the two-stage route served
+                with _span("query_topk_blocks", queries=n):
+                    pass
             return packed[:, :kk].view(np.float32)[todo], packed[:, kk:][todo]
 
         return self._assemble(n, k, filter_fns, dispatch)

@@ -262,3 +262,28 @@ def test_mesh_embedder_compiles_for_v5e(v5e, monkeypatch):
         jax.ShapeDtypeStruct((4 * N_MESH, 160), jnp.int32, sharding=data),
         jax.ShapeDtypeStruct((4 * N_MESH, 160), jnp.bool_, sharding=data),
     ).compile()
+
+
+@pytest.mark.slow
+def test_block_topk_leaves_the_scores_where_the_scan_wrote_them(v5e):
+    """The serve cells' scan + selection, compiled for the v5e: the two
+    stages read the 210 MB of scores in the matmul's own tiles. A form
+    that makes XLA lay the matrix out anew shows as a second 210 MB of
+    temporaries (and cost 0.5 ms a dispatch on the chip: PERF.md, PR 34)."""
+    from pathway_tpu.ops import knn
+
+    q, rows, dim, k = 16, 3_276_800, 384, 16
+    assert knn._topk_route(rows, k) == "blocks"
+
+    def scan_and_select(emb, matrix, valid):
+        scores = jnp.where(valid[None, :], emb @ matrix.T, knn._NEG)
+        return knn._select_topk(scores, k)
+
+    args = (
+        jax.ShapeDtypeStruct((q, dim), jnp.float32),
+        jax.ShapeDtypeStruct((rows, dim), jnp.float32),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_),
+    )
+    compiled = jax.jit(scan_and_select).lower(*_on(SingleDeviceSharding(v5e[0]), args)).compile()
+    scores_bytes = q * rows * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * scores_bytes
