@@ -62,6 +62,11 @@ PAGED_ATOL = 2e-2
 # and the order of its sums differ from XLA's, and its output is rounded to
 # bf16 (2^-9 relative) on both. Relative to the largest output.
 SCAN_RTOL = 1e-2
+# Power retention at the published heads (40 on 8 of 128): the same bf16
+# q, k, v on both sides; the kernel rounds a block's weights to bf16 before
+# they meet the values and its output to bf16 (2^-9 relative each), the
+# recurrence keeps float32 throughout. Relative to the largest output.
+RETENTION_RTOL = 2e-2
 # Decode: greedy tokens must equal the impl="xla" engine's, except that the
 # first token to differ may be one the plain XLA forward scores within this
 # of its best (logits have std ~0.3 and a typical top-2 gap of 0.05).
@@ -389,6 +394,67 @@ def check_latent_moe(rng) -> None:
         bool(np.isfinite(norms).all()) and float(np.abs(norms - 1.0).max()) < 1e-3
         and all(a and a[0][0] == i and a[0][1] > 0.99 for i, a in enumerate(answers)),
         "latent-moe-tiny-for-tests embeds 64 documents to unit rows and each of 16 finds itself first through DeviceKnnIndex",
+    )
+
+
+def check_power_retention(rng) -> None:
+    """The retention kernel at the published heads (40 on 8 of 128) over a
+    stream of three documents and padding, against the recurrence; then
+    the encoder at its test preset (heads of 128, kernel blocks the chip
+    can tile), through ``encode_device`` — one packed stream —
+    ``add_batch_device`` and the fused text-query program."""
+    import jax
+    import jax.numpy as jnp
+
+    from pathway_tpu.models.power_retention import PowerRetentionConfig
+    from pathway_tpu.models.sentence_encoder import SentenceEncoder
+    from pathway_tpu.ops.knn import DeviceKnnIndex
+    from pathway_tpu.ops.power_retention import power_retention, power_retention_reference
+
+    t, lens = 1024, (500, 130, 200)
+    seg, pos, at = np.full(t, -1, np.int32), np.zeros(t, np.int32), 0
+    for i, n in enumerate(lens):
+        seg[at : at + n], pos[at : at + n] = i, np.arange(n)
+        at += -(-n // 128) * 128
+
+    def unit_heads(x):  # as the per-head RMSNorm leaves them: |q_i . k_j| <= sqrt(128)
+        heads = x.reshape(t, -1, 128)
+        return (heads / np.linalg.norm(heads, axis=-1, keepdims=True) * 128**0.25).reshape(x.shape)
+
+    q = jnp.asarray(unit_heads(rng.normal(size=(t, 5120))), jnp.bfloat16)
+    k = jnp.asarray(unit_heads(rng.normal(size=(t, 1024))), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(t, 1024)), jnp.bfloat16)
+    log_g = jnp.asarray(-0.02 * np.abs(rng.normal(size=(t, 8))), jnp.float32)
+    args = (q, k, v, log_g, jnp.asarray(seg), jnp.asarray(pos))
+    kernel = jax.jit(power_retention)
+    check(has_mosaic_kernel(kernel, *args), "power retention at 1,024 x (40 | 8) x 128 compiles to a Mosaic kernel")
+    got = np.asarray(kernel(*args), np.float32)[seg >= 0]
+    want = np.asarray(jax.jit(power_retention_reference)(*args), np.float32)[seg >= 0]
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    check(
+        bool(np.isfinite(got).all()) and err <= RETENTION_RTOL,
+        f"power retention vs the lax.scan recurrence: max err {err:.2e} of the largest output (<= {RETENTION_RTOL})",
+    )
+
+    name = "power-retention-tiny-for-tests"
+    cfg = PowerRetentionConfig.tiny_for_tests(head_dim=128, token_chunk=256, blocks=(128, 128), max_group_tokens=1024)
+    enc = SentenceEncoder(name, config=cfg)
+    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(n))) for n in rng.integers(8, 250, size=64)]
+    rows = enc.encode_device(docs)
+    stream = jax.ShapeDtypeStruct((1024,), np.int32), *(jax.ShapeDtypeStruct((128,), np.int32),) * 2
+    check(
+        has_mosaic_kernel(enc._fwd_stream.__wrapped__, enc.params, *stream),
+        f"{name}: the stream forward compiles its retention to a Mosaic kernel",
+    )
+    index = DeviceKnnIndex(enc.dim, metric="cos", reserved_space=64)
+    index.attach_encoder(enc)
+    index.add_batch_device(list(range(len(docs))), rows, None)
+    answers = index.search_texts_batch(docs[:16], 1)
+    norms = np.linalg.norm(np.asarray(rows), axis=1)
+    check(
+        bool(np.isfinite(norms).all()) and float(np.abs(norms - 1.0).max()) < 1e-3
+        and all(a and a[0][0] == i and a[0][1] > 0.99 for i, a in enumerate(answers)),
+        f"{name} embeds 64 documents of 10-252 tokens as packed streams to unit rows and each of 16 finds itself first through DeviceKnnIndex",
     )
 
 
@@ -759,6 +825,7 @@ def main() -> None:
     check_paged_attention(DecoderConfig(), rng)
     check_selective_scan(rng)
     check_latent_moe(rng)
+    check_power_retention(rng)
 
     docs = make_corpus(rng)
     serve_and_check(docs, mesh_chips)

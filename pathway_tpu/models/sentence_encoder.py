@@ -30,6 +30,7 @@ from .encoder import (
 )
 from .hybrid_ssm import HybridSSMConfig, HybridSSMEncoder
 from .latent_moe import LatentMoEConfig, LatentMoEEncoder
+from .power_retention import PowerRetentionConfig, PowerRetentionEncoder
 from .tokenizer import WordPieceTokenizer, default_tokenizer
 
 #: model name -> the configuration it stands for; a name that is not
@@ -45,12 +46,20 @@ ARCHITECTURES = {
     "openpangu-ultra-moe-718b.ep16-l5": LatentMoEConfig.pangu_ultra_moe_ep16_l5,
     # no published model: a dense and two sparse-expert layers at test widths
     "latent-moe-tiny-for-tests": LatentMoEConfig.tiny_for_tests,
+    # the first pipeline stage of the published model: the embedding and 8 of its 40 layers
+    "brumby-14b-base.l8": PowerRetentionConfig.brumby_14b_base_l8,
+    # no published model: two power-retention layers, heads grouped 5 : 1, at test widths
+    "power-retention-tiny-for-tests": PowerRetentionConfig.tiny_for_tests,
 }
 
 #: the modules that are not the flax BERT block: they make their own
 #: parameter tree, leaf by leaf in its final types, and say how many
 #: tokens a dispatch group may hold
-_OWN_MODULES = {HybridSSMConfig: HybridSSMEncoder, LatentMoEConfig: LatentMoEEncoder}
+_OWN_MODULES = {
+    HybridSSMConfig: HybridSSMEncoder,
+    LatentMoEConfig: LatentMoEEncoder,
+    PowerRetentionConfig: PowerRetentionEncoder,
+}
 
 
 def _param_shapes(module):
@@ -88,7 +97,7 @@ class SentenceEncoder:
         *,
         config: EncoderConfig | None = None,
         checkpoint_dir: str | None = None,
-        max_seq_len: int = 256,
+        max_seq_len: int | None = None,
         max_batch: int = 1024,
         seed: int = 0,
         mesh=None,
@@ -98,6 +107,9 @@ class SentenceEncoder:
             config = architecture_of(model)
         self.cfg = config
         self.model_name = model
+        if max_seq_len is None:
+            # a module that takes documents whole says how long one may be
+            max_seq_len = getattr(config, "max_seq_len", 256)
         self.max_seq_len = max_seq_len
         self.max_batch = max_batch
         checkpoint_dir = checkpoint_dir or os.environ.get("PATHWAY_TPU_CKPT")
@@ -275,6 +287,8 @@ class SentenceEncoder:
         (group_indices, n_real, device_embeddings)."""
         from .batching import DEFAULT_BATCH_BUCKETS, bucket
 
+        if hasattr(self.module, "apply_stream"):
+            return self._stream_groups(ids_mat, lens)
         n = len(lens)
         order = np.argsort(lens, kind="stable")  # dense length buckets
         batch = self.max_batch
@@ -307,6 +321,77 @@ class SentenceEncoder:
                 )[:, None]
                 pending.append((group, ng, self._run_padded(ids, mask)))
         return pending
+
+    def _stream_groups(self, ids_mat: np.ndarray, lens: np.ndarray):
+        """Dispatch bounded by tokens, for a module that packs: the
+        documents, in the order they came, go to the device as token
+        streams of ``max_group_tokens`` — as many whole documents a
+        stream as fit, each padded to ``doc_align`` only — with where
+        each starts and how long it is beside them. One compiled
+        program, whatever the batch: it computes the stream's live
+        chunks. Yields what :meth:`_matrix_groups` yields."""
+        cfg = self.cfg
+        t, align = cfg.max_group_tokens, cfg.doc_align
+        most = t // align  # documents of one stream
+        padded = -(-lens.astype(np.int64) // align) * align
+        ends = np.cumsum(padded)  # in one endless stream
+        begins = ends - padded
+        pending, lo = [], 0
+        while lo < len(lens):
+            hi = int(np.searchsorted(ends, begins[lo] + t, side="right"))
+            hi = min(max(hi, lo + 1), lo + most)
+            with _span("embed_pack", rows=hi - lo):
+                starts = np.full((most,), t, np.int32)
+                starts[: hi - lo] = begins[lo:hi] - begins[lo]
+                doc_lens = np.zeros((most,), np.int32)
+                doc_lens[: hi - lo] = lens[lo:hi]
+                ids = np.zeros((t,), np.int32)
+                for at, i in zip(starts, range(lo, hi)):
+                    ids[at : at + lens[i]] = ids_mat[i, : lens[i]]
+            pending.append((np.arange(lo, hi), hi - lo, self._run_stream(ids, starts, doc_lens)))
+            lo = hi
+        return pending
+
+    def _ring(self):
+        """The donated ring the wire arrays of a dispatch stage through
+        (depth 2 by default, PATHWAY_WIRE_RING_DEPTH to deepen)."""
+        if self._wire_ring is None:
+            from ..engine.device_ring import DeviceRing
+
+            depth = max(2, int(os.environ.get("PATHWAY_WIRE_RING_DEPTH", "2")))
+            self._wire_ring = DeviceRing(depth=depth, name="sentence_encoder.wire")
+        return self._wire_ring
+
+    def _run_stream(self, ids: np.ndarray, starts: np.ndarray, lens: np.ndarray):
+        """The compiled forward of a module that packs: one stream of
+        token ids, its documents' starts and lengths -> their rows."""
+        from ..internals.profiler import ENCODER_KERNEL_STATS, wrap_jit
+
+        if getattr(self, "_fwd_stream", None) is None:
+            self._fwd_stream = wrap_jit("sentence_encoder.fwd_stream", jax.jit(self.module.apply_stream))
+        cfg = self.cfg
+        real = int(lens.sum())
+        live = int((starts + lens)[lens > 0].max()) if real else 0
+        computed = -(-live // cfg.token_chunk) * cfg.token_chunk
+        if _tracing_enabled():
+            # a retention layer call a layer, each over the stream's real
+            # tokens and their causal pairs: counted here, from the lengths
+            pairs = int((lens.astype(np.int64) * (lens + 1) // 2).sum())
+            units = {"tokens": real, "rows": pairs, "computed_tokens": computed}
+            for _ in range(cfg.num_hidden_layers):
+                TRACING_METRICS.observe("embed_retention", 0.0, "", units=units)
+        ENCODER_KERNEL_STATS.record_dispatch(
+            seq=cfg.max_group_tokens,  # the stream: what it leaves dead is what it skips
+            batch=1,
+            real_tokens=real,
+            computed_tokens=computed,
+            flops=float(sum(int(n) * cfg.flops_per_token(int(n)) for n in lens if n)),
+        )
+        with _span("embed_dispatch", rows=int((lens > 0).sum()), tokens=computed):
+            wire = self._ring().stage([ids, starts, lens])
+            out = self._fwd_stream(self.live_params(), *wire)
+            self._wire_ring.retire(wire)
+        return out
 
     def _fused_layer_ok(self, seq_len: int) -> bool:
         """Route the inference jit through the whole-layer pallas kernel
@@ -360,16 +445,9 @@ class SentenceEncoder:
         # flight) and slot reuse donates the previous group's buffers
         # instead of accumulating one upload per dispatch in HBM.
         wire = np.int16 if self.cfg.vocab_size < 32768 else np.int32
-        if self._wire_ring is None:
-            import os
-
-            from ..engine.device_ring import DeviceRing
-
-            depth = max(2, int(os.environ.get("PATHWAY_WIRE_RING_DEPTH", "2")))
-            self._wire_ring = DeviceRing(depth=depth, name="sentence_encoder.wire")
         computed = self._record_dispatch(ids.shape[0], ids.shape[1], lens)
         with _span("embed_dispatch", rows=ids.shape[0], tokens=computed):
-            ids_dev, lens_dev = self._wire_ring.stage(
+            ids_dev, lens_dev = self._ring().stage(
                 [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]
             )
             from ..internals.chip_ledger import CHIP_LEDGER

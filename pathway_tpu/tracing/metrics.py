@@ -33,8 +33,10 @@ from ..serving.metrics import STAGE_BUCKETS
 
 #: span attributes that count work: summed into the stage's totals
 WORK_UNITS = ("rows", "queries", "tokens")
-#: and two more that only a stage fed from the device's own counts has
-LOAD_UNITS = ("max_load", "mean_load")
+#: and those only some stages have: two that a stage fed from the device's
+#: own counts brings, and the token rows a packed stream computes for its
+#: real ``tokens``
+OTHER_UNITS = ("max_load", "mean_load", "computed_tokens")
 #: owed arrays kept before the hand-over itself folds them: a bound for
 #: a process that traces and never reads
 _OWED_MOST = 1024
@@ -68,7 +70,7 @@ class _ExemplarHistogram:
         self.total += seconds
         self.count += 1
         if units:
-            for name in WORK_UNITS + LOAD_UNITS:
+            for name in WORK_UNITS + OTHER_UNITS:
                 n = units.get(name)
                 if n:
                     self.units[name] = self.units.get(name, 0) + (n if isinstance(n, float) else int(n))

@@ -31,6 +31,7 @@ from pathway_tpu.ops.fused_attention import attention
 from pathway_tpu.ops.fused_layer import _pack_rows, encoder_forward
 from pathway_tpu.ops.paged_attention import paged_decode_attention
 from pathway_tpu.ops.pallas_knn import knn_topk, knn_topk_sharded
+from pathway_tpu.ops.power_retention import power_retention
 from pathway_tpu.ops.selective_scan import selective_scan
 
 # MiniLM-L6 at its published width; depth cut to one layer, since every
@@ -122,6 +123,19 @@ def _experts_case(k: int, n: int):
     return grouped_matmul, (_spec((rows, k), jnp.bfloat16), _spec((held, k, n), jnp.bfloat16), _spec((held,), jnp.int32))
 
 
+def _retention_case(tokens: int):
+    # the power-retention embedder at its published heads: 40 query
+    # heads on 8 key/value heads of 128, a stream of whole documents
+    return power_retention, (
+        _spec((tokens, 40 * 128), jnp.bfloat16),
+        _spec((tokens, 8 * 128), jnp.bfloat16),
+        _spec((tokens, 8 * 128), jnp.bfloat16),
+        _spec((tokens, 8), jnp.float32),
+        _spec((tokens,), jnp.int32),
+        _spec((tokens,), jnp.int32),
+    )
+
+
 SINGLE_DEVICE_CASES = {
     **{
         f"encoder_forward[S={s}]": functools.partial(_encoder_case, s)
@@ -141,6 +155,8 @@ SINGLE_DEVICE_CASES = {
     "selective_scan[B=8,S=16]": functools.partial(_scan_case, 8, 16),
     "expert_grouped_matmul[4096x7680x2048]": functools.partial(_experts_case, 7680, 2048),
     "expert_grouped_matmul[4096x2048x7680]": functools.partial(_experts_case, 2048, 7680),
+    "power_retention[T=8192,40|8x128]": functools.partial(_retention_case, 8192),
+    "power_retention[T=128,40|8x128]": functools.partial(_retention_case, 128),
 }
 
 

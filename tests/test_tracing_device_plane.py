@@ -413,6 +413,23 @@ def _latent_moe_forward(enc):
     return (lambda: jax.jit(module.apply)), shapes, {}
 
 
+def _power_retention_forward(enc):
+    """The power-retention encoder's packed forward, at its test preset
+    (the retention in the Pallas interpreter: this lowers for the CPU):
+    a stream of four chunks, so the loops over the chunks are there."""
+    from pathway_tpu.models.power_retention import PowerRetentionConfig, PowerRetentionEncoder
+
+    module = PowerRetentionEncoder(PowerRetentionConfig.tiny_for_tests(retention_impl="interpret"))
+    shapes = (
+        jax.eval_shape(module.init),
+        jax.ShapeDtypeStruct((256,), np.int32),
+        jax.ShapeDtypeStruct((32,), np.int32),
+        jax.ShapeDtypeStruct((32,), np.int32),
+    )
+    return (lambda: jax.jit(module.apply_stream)), shapes, {}
+
+
+RETENTION_SCOPES = tuple("pw.encode." + s for s in ("ret_qkv", "retention", "ret_out", "mlp", "pool"))
 LATENT_MOE_SCOPES = tuple(
     "pw.encode." + s for s in ("mla_q", "mla_kv", "attn", "moe_route", "moe_experts", "moe_shared", "mlp", "pool")
 )
@@ -424,6 +441,7 @@ HYBRID_SCOPES = tuple("pw.encode." + s for s in ("ssm_in", "ssm_conv", "ssm_scan
     [
         (_hybrid_forward, HYBRID_SCOPES),
         (_latent_moe_forward, LATENT_MOE_SCOPES),
+        (_power_retention_forward, RETENTION_SCOPES),
         (_fused, ("pw.query.encode", "pw.query.scan", "pw.query.topk")),
         (_scatter_dev, ("pw.index.scatter",)),
         (_scatter_tomb, ("pw.index.tomb",)),
