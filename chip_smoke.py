@@ -57,8 +57,8 @@ TOPK_SCORE_TOL = 2.0**-8
 # dots the two differ by bf16-grade rounding of values up to ~3; a lane of
 # length 1 returns v itself, rounded on one side only. Measured 7.8e-3.
 PAGED_ATOL = 2e-2
-# Selective scan at the published shape of the hybrid embedder (32 x 256 x
-# 5,120 channels, state 16): float32 state on both sides; the kernel's exp
+# Selective scan at the published width of the hybrid embedder (a packed
+# stream of 8,192 x 5,120 channels, state 16): float32 state on both sides; the kernel's exp
 # and the order of its sums differ from XLA's, and its output is rounded to
 # bf16 (2^-9 relative) on both. Relative to the largest output.
 SCAN_RTOL = 1e-2
@@ -345,26 +345,62 @@ def check_selective_scan(rng) -> None:
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.ops.selective_scan import selective_scan, selective_scan_reference
+    from pathway_tpu.ops.selective_scan import TIME_CHUNK, selective_scan, selective_scan_reference
 
-    batch, seq, channels, n = 32, 256, 5120, 16
-    u = jnp.asarray(rng.normal(size=(batch, seq, channels)), jnp.bfloat16)
-    z = jnp.asarray(rng.normal(size=(batch, seq, channels)), jnp.bfloat16)
-    dt = jax.nn.softplus(jnp.asarray(rng.normal(size=(batch, seq, channels)) - 3.0, jnp.float32))
-    b = jnp.asarray(rng.normal(size=(batch, seq, n)), jnp.float32)
-    c = jnp.asarray(rng.normal(size=(batch, seq, n)), jnp.float32)
+    channels, n = 5120, 16
     a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (channels, n))
     d_skip = jnp.ones((channels,), jnp.float32)
+    # a write batch: 32 documents of 18-256 tokens, each aligned to the
+    # kernel's chunk, in a stream of 8,192 with room for 512; and the query
+    # program's 8 x 16
+    lens = rng.integers(18, 257, size=32)
+    for tokens, doc_lens in ((8192, lens), (128, np.full(8, 11))):
+        padded = -(-doc_lens // TIME_CHUNK) * TIME_CHUNK
+        begins = np.cumsum(padded) - padded
+        starts = np.full((tokens // TIME_CHUNK,), tokens, np.int32)
+        starts[: len(begins)] = begins
+        live = int(begins[-1] + doc_lens[-1])
+        real = np.zeros(tokens, bool)
+        for at, length in zip(begins, doc_lens):
+            real[at : at + length] = True
+        u = jnp.asarray(rng.normal(size=(tokens, channels)), jnp.bfloat16)
+        z = jnp.asarray(rng.normal(size=(tokens, channels)), jnp.bfloat16)
+        dt = jax.nn.softplus(jnp.asarray(rng.normal(size=(tokens, channels)) - 3.0, jnp.float32))
+        b = jnp.asarray(rng.normal(size=(tokens, n)), jnp.float32)
+        c = jnp.asarray(rng.normal(size=(tokens, n)), jnp.float32)
+        args = (u, dt, z, b, c, a, d_skip, jnp.asarray(starts))
+        kernel = jax.jit(lambda *xs: selective_scan(*xs[:-1], live=xs[-1]))
+        shape = f"a stream of {tokens} x {channels}, state {n}, {len(doc_lens)} documents, {live} tokens live"
+        check(has_mosaic_kernel(kernel, *args, jnp.int32(live)), f"selective scan over {shape} compiles to a Mosaic kernel")
+        got = np.asarray(kernel(*args, jnp.int32(live)), np.float32)[real]
+        want = np.asarray(jax.jit(selective_scan_reference)(*args), np.float32)[real]
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        check(
+            bool(np.isfinite(got).all()) and err <= SCAN_RTOL,
+            f"selective scan over {shape} vs the lax.scan recurrence cleared at every start: max err {err:.2e} of the largest output (<= {SCAN_RTOL})",
+        )
+
+    from pathway_tpu.models.sentence_encoder import SentenceEncoder
+    from pathway_tpu.ops.knn import DeviceKnnIndex
+
+    name = "hybrid-ssm-tiny-for-tests"
+    enc = SentenceEncoder(name)
+    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(k))) for k in rng.integers(8, 250, size=64)]
+    rows = enc.encode_device(docs)
+    stream = jax.ShapeDtypeStruct((1024,), np.int32), *(jax.ShapeDtypeStruct((64,), np.int32),) * 2
     check(
-        has_mosaic_kernel(selective_scan, u, dt, z, b, c, a, d_skip),
-        "selective scan at 32 x 256 x 5120, state 16, compiles to a Mosaic kernel",
+        has_mosaic_kernel(enc._fwd_stream.__wrapped__, enc.params, *stream),
+        f"{name}: the stream forward compiles its scan to a Mosaic kernel",
     )
-    got = np.asarray(selective_scan(u, dt, z, b, c, a, d_skip), np.float32)
-    want = np.asarray(jax.jit(selective_scan_reference)(u, dt, z, b, c, a, d_skip), np.float32)
-    err = float(np.abs(got - want).max() / np.abs(want).max())
+    index = DeviceKnnIndex(enc.dim, metric="cos", reserved_space=64)
+    index.attach_encoder(enc)
+    index.add_batch_device(list(range(len(docs))), rows, None)
+    answers = index.search_texts_batch(docs[:16], 1)
+    norms = np.linalg.norm(np.asarray(rows), axis=1)
     check(
-        bool(np.isfinite(got).all()) and err <= SCAN_RTOL,
-        f"selective scan vs the lax.scan recurrence: max err {err:.2e} of the largest output (<= {SCAN_RTOL})",
+        bool(np.isfinite(norms).all()) and float(np.abs(norms - 1.0).max()) < 1e-3
+        and all(a and a[0][0] == i and a[0][1] > 0.99 for i, a in enumerate(answers)),
+        f"{name} embeds 64 documents of 10-252 tokens as packed streams to unit rows and each of 16 finds itself first through DeviceKnnIndex",
     )
 
 

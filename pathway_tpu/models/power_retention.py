@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from ..ops.power_retention import power_retention
 from .hybrid_ssm import _matmul, _rmsnorm  # float32 statistics; bfloat16 in, float32 out
+from .token_stream import TokenStream, stream_length
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -152,15 +153,13 @@ class PowerRetentionConfig:
         pairs = 4 * self.num_attention_heads * self.head_dim * seq / 2
         return float(self.num_hidden_layers * (2 * self.layer_matmul_params + pairs))
 
-    def stream_length(self, tokens: int) -> int:
-        """The stream a forward of ``tokens`` runs as: whole chunks, or
-        the least halving of a chunk that holds them."""
-        n = self.token_chunk
-        if tokens >= n:
-            return -(-tokens // n) * n
-        while n // 2 >= max(tokens, 16):
-            n //= 2
-        return n
+    def stream_counts(self, lens, computed: int) -> tuple[str, int, dict]:
+        """What a stream of documents of ``lens`` tokens, ``computed`` token
+        rows of it run, adds to the program's counters: the stage, its
+        calls (a retention a layer), a call's units — the stream's real
+        tokens and their causal pairs."""
+        pairs = int((lens.astype("int64") * (lens + 1) // 2).sum())
+        return "embed_retention", self.num_hidden_layers, {"tokens": int(lens.sum()), "rows": pairs, "computed_tokens": computed}
 
 
 def _rope(x, cos, sin):
@@ -238,7 +237,7 @@ class PowerRetentionEncoder:
         """``ids`` ``[n, s]`` right-padded, ``mask`` its real tokens: the
         stream of ``n * s`` tokens with a row a document."""
         n, s = ids.shape
-        t = self.cfg.stream_length(n * s)
+        t = stream_length(self.cfg.token_chunk, n * s)
         flat = jnp.pad(ids.reshape(n * s), (0, t - n * s))
         starts = jnp.arange(n, dtype=jnp.int32) * s
         return self.apply_stream(params, flat, starts, mask.sum(axis=1).astype(jnp.int32))
@@ -248,27 +247,14 @@ class PowerRetentionEncoder:
         document ``i`` at ``starts[i] ... starts[i] + lens[i] - 1``
         (``starts`` ascending; a document that is not there has length 0
         and starts at ``t``), anything between them padding. ``t`` is a
-        :meth:`PowerRetentionConfig.stream_length`. -> ``[docs, hidden]``
-        unit rows, zeros for a document that is not there."""
+        :func:`token_stream.stream_length` of ``token_chunk``. ->
+        ``[docs, hidden]`` unit rows, zeros for a document that is not
+        there."""
         c = self.cfg
         t, docs = ids.shape[0], starts.shape[0]
-        chunk = min(c.token_chunk, t)
         heads, kv, hd, eps = c.num_attention_heads, c.num_key_value_heads, c.head_dim, c.rms_norm_eps
-        at = jnp.arange(t, dtype=jnp.int32)
-        doc = jnp.clip(jnp.searchsorted(starts, at, side="right").astype(jnp.int32) - 1, 0, docs - 1)
-        pos = at - starts[doc]
-        real = (pos >= 0) & (pos < lens[doc])
-        seg, pos = jnp.where(real, doc, -1), jnp.where(real, pos, 0)
-        # the loops' trip count: the chunks that hold a real token
-        n_chunks = (jnp.max(jnp.where(lens > 0, starts + lens, 0)) + chunk - 1) // chunk
-
-        def over_chunks(body, carry):
-            if chunk == t:
-                return body(0, carry)
-            return jax.lax.fori_loop(0, n_chunks, lambda i, cr: body(i * chunk, cr), carry)
-
-        def rows(x, lo):
-            return jax.lax.dynamic_slice_in_dim(x, lo, chunk, axis=0)
+        st = TokenStream.of(c.token_chunk, t, starts, lens)
+        chunk, seg, pos, over_chunks, rows = st.chunk, st.seg, st.pos, st.over, st.rows
 
         inv = 1.0 / (c.rope_theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
         angle = pos.astype(F32)[:, None] * jnp.concatenate([inv, inv])[None, :]
@@ -299,7 +285,7 @@ class PowerRetentionEncoder:
             with jax.named_scope("pw.encode.retention"):
                 o = power_retention(
                     q, k, v, log_g, seg, pos,
-                    live=n_chunks * chunk, degree=c.degree, eps=c.retention_eps,
+                    live=st.live_chunks * chunk, degree=c.degree, eps=c.retention_eps,
                     block_q=c.blocks[0], block_k=c.blocks[1], interpret=c.retention_impl == "interpret",
                 )  # fmt: skip
 

@@ -11,6 +11,7 @@ embedding.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from functools import partial
 from typing import Sequence
@@ -105,11 +106,15 @@ class SentenceEncoder:
     ):
         if config is None:
             config = architecture_of(model)
-        self.cfg = config
         self.model_name = model
         if max_seq_len is None:
             # a module that takes documents whole says how long one may be
             max_seq_len = getattr(config, "max_seq_len", 256)
+        elif hasattr(config, "max_seq_len"):
+            # and is built for the length it is given: what its attention
+            # looks back over, what it refuses as too long for a stream
+            config = dataclasses.replace(config, max_seq_len=max_seq_len)
+        self.cfg = config
         self.max_seq_len = max_seq_len
         self.max_batch = max_batch
         checkpoint_dir = checkpoint_dir or os.environ.get("PATHWAY_TPU_CKPT")
@@ -374,12 +379,11 @@ class SentenceEncoder:
         live = int((starts + lens)[lens > 0].max()) if real else 0
         computed = -(-live // cfg.token_chunk) * cfg.token_chunk
         if _tracing_enabled():
-            # a retention layer call a layer, each over the stream's real
-            # tokens and their causal pairs: counted here, from the lengths
-            pairs = int((lens.astype(np.int64) * (lens + 1) // 2).sum())
-            units = {"tokens": real, "rows": pairs, "computed_tokens": computed}
-            for _ in range(cfg.num_hidden_layers):
-                TRACING_METRICS.observe("embed_retention", 0.0, "", units=units)
+            # the module's own stage, counted here from the lengths the
+            # host has: nothing is fetched from the device
+            stage, calls, units = cfg.stream_counts(lens, computed)
+            for _ in range(calls):
+                TRACING_METRICS.observe(stage, 0.0, "", units=units)
         ENCODER_KERNEL_STATS.record_dispatch(
             seq=cfg.max_group_tokens,  # the stream: what it leaves dead is what it skips
             batch=1,

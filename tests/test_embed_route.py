@@ -5,14 +5,16 @@ Every shape an embed call takes (whole groups, a ragged last group, a
 handful of rows, ``pad_to``, the halving of a large batch, a batch that
 is mostly padding, the whole-layer kernel, a module that is not a BERT
 block) must give the rows ``encode`` gives, and compile exactly the
-``(B, L)`` programs ``predict_compile_keys`` names.
+``(B, L)`` programs ``predict_compile_keys`` names. (A module that packs
+takes ``_stream_groups`` instead: ``test_hybrid_ssm.py``,
+``test_power_retention.py``.)
 """
 
 import numpy as np
 import pytest
 
 from pathway_tpu.models.encoder import EncoderConfig
-from pathway_tpu.models.hybrid_ssm import HybridSSMConfig
+from pathway_tpu.models.latent_moe import LatentMoEConfig
 from pathway_tpu.models.sentence_encoder import SentenceEncoder
 from pathway_tpu.models.tokenizer import default_tokenizer
 
@@ -60,8 +62,8 @@ CASES = {
     "sparse,n=512,interpret": (
         lambda: _bert(512, layer_impl="interpret"), 512, 128, 384, 512, _sparse,
     ),
-    "hybrid,n=2B+3": (
-        lambda: HybridSSMConfig.tiny_for_tests(scan_impl="interpret"), 2 * B + 3, B, 32, None, _mixed,
+    "latent-moe,n=2B+3": (
+        lambda: LatentMoEConfig.tiny_for_tests(expert_impl="interpret"), 2 * B + 3, B, 32, None, _mixed,
     ),
 }
 

@@ -98,20 +98,28 @@ def _paged_case(page_size: int):
     )
 
 
-def _scan_case(batch: int, seq: int):
+def _scan_case(tokens: int):
     # the hybrid embedder's mixer at its published width: 5,120 channels,
-    # a state of 16; the write batch and the query program's shape
+    # a state of 16, over a packed stream — a write batch's 8,192 tokens
+    # (as many documents as it can hold, its live length known on the
+    # device only) and the query program's 8 x 16
     d, n = 5120, 16
-    seqs = _spec((batch, seq, d), jnp.bfloat16)
-    cols = _spec((batch, seq, n), jnp.float32)
-    return selective_scan, (
+    seqs = _spec((tokens, d), jnp.bfloat16)
+    cols = _spec((tokens, n), jnp.float32)
+
+    def scan(u, dt, z, b, c, a, d_skip, starts, live):
+        return selective_scan(u, dt, z, b, c, a, d_skip, starts, live=live)
+
+    return scan, (
         seqs,
-        _spec((batch, seq, d), jnp.float32),
+        _spec((tokens, d), jnp.float32),
         seqs,
         cols,
         cols,
         _spec((d, n), jnp.float32),
         _spec((d,), jnp.float32),
+        _spec((tokens // 16,), jnp.int32),
+        _spec((), jnp.int32),
     )
 
 
@@ -151,8 +159,8 @@ SINGLE_DEVICE_CASES = {
         f"paged_decode_attention[page={p}]": functools.partial(_paged_case, p)
         for p in (8, 16, 32)
     },
-    "selective_scan[B=32,S=256]": functools.partial(_scan_case, 32, 256),
-    "selective_scan[B=8,S=16]": functools.partial(_scan_case, 8, 16),
+    "selective_scan[T=8192]": functools.partial(_scan_case, 8192),
+    "selective_scan[T=128]": functools.partial(_scan_case, 128),
     "expert_grouped_matmul[4096x7680x2048]": functools.partial(_experts_case, 7680, 2048),
     "expert_grouped_matmul[4096x2048x7680]": functools.partial(_experts_case, 2048, 7680),
     "power_retention[T=8192,40|8x128]": functools.partial(_retention_case, 8192),
