@@ -688,9 +688,13 @@ class MonitoringHttpServer:
         per-stage latency histograms whose buckets carry OpenMetrics
         trace-id exemplars (``# {trace_id="..."} value ts``), so a
         dashboard's slow bucket links straight to
-        ``pathway trace show <id>``. Rendered only once a span has been
+        ``pathway trace show <id>``. Beside it
+        ``pathway_stage_device_seconds{stage,state}``: the host seconds
+        each stage passed ``starved`` (nothing in flight on the device),
+        ``overlapped`` or ``waiting``. Rendered only once a span has been
         recorded — a tracing-off run scrapes byte-identical output."""
-        from ..tracing import TRACING_METRICS
+        from ..tracing import TRACE_STORE, TRACING_METRICS
+        from ..tracing.metrics import STATES
 
         if not TRACING_METRICS.active():
             return []
@@ -720,6 +724,19 @@ class MonitoringHttpServer:
                 )
             lines.append(series(f"{metric}_sum", f"{row['sum']:.9f}", labels))
             lines.append(series(f"{metric}_count", row["count"], labels))
+        # each stage's self time on the threads that dispatch device work,
+        # by whether the device had work of theirs to run
+        device_seconds = TRACING_METRICS.device_seconds()
+        if device_seconds:
+            metric = "pathway_stage_device_seconds"
+            lines.append(f"# TYPE {metric} counter")
+            for stage in sorted(device_seconds):
+                for state, seconds in zip(STATES, device_seconds[stage]):
+                    labels = (
+                        f'stage="{_escape_label(stage)}",state="{state}",'
+                        f'worker="{TRACE_STORE.worker}"'
+                    )
+                    lines.append(series(metric, f"{seconds:.9f}", labels))
         return lines
 
     @staticmethod

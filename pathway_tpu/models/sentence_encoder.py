@@ -20,7 +20,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..tracing import TRACING_METRICS, span as _span, tracing_enabled as _tracing_enabled
+from ..tracing import (
+    TRACING_METRICS,
+    dispatched as _dispatched,
+    span as _span,
+    tracing_enabled as _tracing_enabled,
+)
 from .batching import DEFAULT_SEQ_BUCKETS, chunks, pad_token_batch
 from .encoder import (
     CrossEncoderHead,
@@ -394,6 +399,7 @@ class SentenceEncoder:
         with _span("embed_dispatch", rows=int((lens > 0).sum()), tokens=computed):
             wire = self._ring().stage([ids, starts, lens])
             out = self._fwd_stream(self.live_params(), *wire)
+            _dispatched(out)
             self._wire_ring.retire(wire)
         return out
 
@@ -466,6 +472,7 @@ class SentenceEncoder:
                     jax.block_until_ready(out)
             else:
                 out = self._fwd_group(self.live_params(), ids_dev, lens_dev)
+            _dispatched(out[0] if isinstance(out, tuple) else out)
             self._wire_ring.retire([ids_dev, lens_dev])
         if isinstance(out, tuple):
             out, loads = out
@@ -559,23 +566,26 @@ class SentenceEncoder:
         ids_mat, lens = m
         n_out = pad_to or len(lens)
         pending = self._matrix_groups(ids_mat, lens)
-        if pad_to:
-            # keep full bucket-shaped group outputs; rows past each
-            # group's real count scatter out of bounds and drop
-            embs = jnp.concatenate([emb for _, _, emb in pending], axis=0)
-            order = np.full((int(embs.shape[0]),), n_out, np.int64)
-            off = 0
-            for group, ng, emb in pending:
-                order[off : off + ng] = group
-                off += int(emb.shape[0])
-        else:
-            # a full group is taken as it is: no slice op per group
-            embs = jnp.concatenate(
-                [emb if ng == emb.shape[0] else emb[:ng] for _, ng, emb in pending], axis=0
-            )
-            order = np.concatenate([group for group, _, _ in pending])
-        out = jnp.zeros((n_out, self.dim), jnp.float32)
-        return out.at[jnp.asarray(order)].set(embs.astype(jnp.float32), mode="drop")
+        with _span("embed_gather", rows=len(lens)):  # the eager tail: the groups' rows back in input order
+            if pad_to:
+                # keep full bucket-shaped group outputs; rows past each
+                # group's real count scatter out of bounds and drop
+                embs = jnp.concatenate([emb for _, _, emb in pending], axis=0)
+                order = np.full((int(embs.shape[0]),), n_out, np.int64)
+                off = 0
+                for group, ng, emb in pending:
+                    order[off : off + ng] = group
+                    off += int(emb.shape[0])
+            else:
+                # a full group is taken as it is: no slice op per group
+                embs = jnp.concatenate(
+                    [emb if ng == emb.shape[0] else emb[:ng] for _, ng, emb in pending], axis=0
+                )
+                order = np.concatenate([group for group, _, _ in pending])
+            out = jnp.zeros((n_out, self.dim), jnp.float32)
+            out = out.at[jnp.asarray(order)].set(embs.astype(jnp.float32), mode="drop")
+            _dispatched(out)
+        return out
 
     def __call__(self, texts: Sequence[str]) -> np.ndarray:
         return self.encode(texts)

@@ -37,7 +37,7 @@ from typing import Any, Callable
 import numpy as np
 
 from ..freshness.plane import FRESHNESS
-from ..tracing import span as _span
+from ..tracing import dispatched as _dispatched, span as _span, waited as _waited
 from .index_metrics import note_owing as _note_owing
 
 _NEG = -3.0e38
@@ -1031,6 +1031,7 @@ class DeviceKnnIndex:
                 l2=self.metric == "l2",
                 normalize=self.metric == "cos",
             )
+            _dispatched(self._dev_valid)
         real = slots[:n]
         self._valid_host[real] = True
         self._host_stale = True
@@ -1279,6 +1280,7 @@ class DeviceKnnIndex:
             return
         with _span("index_flush", rows=len(self._pending)):
             self._scatter_pending()
+            _dispatched(self._dev_valid)  # here the tombstones reach the device
 
     def _scatter_pending(self) -> None:
         n_rows = max(int(self._dev_matrix.shape[0]), self.capacity)
@@ -1634,7 +1636,8 @@ class DeviceKnnIndex:
             # encoder (a Mosaic kernel on TPU, which XLA cannot partition
             # into the sharded score program) embeds on its own first
             return self.search_batch(np.asarray(enc.encode(texts)), k, filter_fns)
-        self._sync()
+        with _span("query_sync", queries=n):  # owed publishes, tombstones ahead of the search
+            self._sync()
         # cache the fused program on the ENCODER (shared across index
         # instances): a warm-up index using the same embedder warms the
         # engine's index too — per-instance caches cold-compiled the
@@ -1652,8 +1655,8 @@ class DeviceKnnIndex:
             kk = min(fetch, self.capacity)
             route = _topk_route(int(self._dev_matrix.shape[0]), kk)
             with _span("query_device", queries=n, topk=route):
-                packed = np.asarray(
-                    self._fused_jit(
+                with _span("query_enqueue", queries=n):
+                    packed = self._fused_jit(
                         enc.live_params(),
                         ids,
                         lens_p,
@@ -1662,7 +1665,16 @@ class DeviceKnnIndex:
                         k=kk,
                         l2=self.metric == "l2",
                     )
-                )
+                    # the answer's copy to the host queued behind the program,
+                    # as np.asarray on the call's result did: waiting first and
+                    # copying then costs a second round trip of ~0.5 ms
+                    packed.copy_to_host_async()
+                    _dispatched(packed)
+                with _span("query_wait", queries=n):
+                    packed.block_until_ready()
+                    _waited()
+                with _span("query_fetch", queries=n):
+                    packed = np.asarray(packed)
             if route == "blocks":
                 # a stage with no time of its own: its queries over
                 # query_batch's are the share the two-stage route served

@@ -12,11 +12,13 @@ retention, a tail-attribution aggregator, OTLP export, and the
 a query batch is the journey. See README "Request tracing".
 
 On with ``pw.run(tracing=True)`` or ``PATHWAY_TRACING=1``, and for as
-long as a ``jax.profiler`` session runs: every span is then also a
-``TraceAnnotation("pw.<stage>")`` in the profile, on the device
-trace's clock. With tracing off every instrumentation site is a single
-check. :func:`stage_totals` reads each stage's calls, seconds and work
-units (``rows``, ``queries``, ``tokens``).
+long as a ``jax.profiler`` session runs: every span of a journey is
+then also a ``TraceAnnotation("pw.<stage>")`` in the profile, on the
+device trace's clock. With tracing off every instrumentation site is a
+single check. :func:`stage_totals` reads each stage's calls, seconds and
+work units (``rows``, ``queries``, ``tokens``) and, for a thread that
+dispatches device work (:func:`dispatched`, :func:`waited`), each
+stage's self time split by whether the device had work to run.
 """
 
 from __future__ import annotations
@@ -35,12 +37,14 @@ from .store import (
     TRACE_STORE,
     TraceStore,
     default_trace_dir,
+    dispatched,
     list_trace_dumps,
     load_trace_dump,
     record_span,
     set_tracing_enabled,
     span,
     tracing_enabled,
+    waited,
 )
 
 __all__ = [
@@ -56,6 +60,7 @@ __all__ = [
     "bind_trace",
     "current_trace",
     "default_trace_dir",
+    "dispatched",
     "emit_telemetry",
     "ensure_trace",
     "list_trace_dumps",
@@ -69,13 +74,20 @@ __all__ = [
     "span",
     "stage_totals",
     "tracing_enabled",
+    "waited",
 ]
 
 
 def stage_totals() -> dict[str, dict]:
     """``{stage: {"calls", "seconds", "rows", "queries", "tokens"}}``
     over every span finished since tracing came on (or the last
-    ``TRACING_METRICS.reset()``), summed over workers."""
+    ``TRACING_METRICS.reset()``), summed over workers. Stages of a
+    thread that dispatched device work also have ``self_seconds`` and
+    its parts ``starved_seconds`` (nothing of the thread's in flight on
+    the device: a lower bound of its idle time), ``overlapped_seconds``
+    and ``waiting_seconds``; the pseudo-stages ``caller`` and
+    ``timeline`` hold what no stage covers and the sums
+    (:meth:`TracingMetrics.totals`)."""
     return TRACING_METRICS.totals()
 
 

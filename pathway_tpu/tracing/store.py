@@ -5,10 +5,14 @@ Recording is gated on one process-wide switch: the flag
 ``jax.profiler`` session. With tracing off a :class:`span` block costs
 the check and records nothing, so the serving hot path stays within its
 <5% overhead budget and ``/metrics`` output is byte-identical to a build
-without the plane. While a profiler session runs every :class:`span` is
-also a ``jax.profiler.TraceAnnotation("pw.<stage>")``: the program's
-spans land in the same xplane as the device's ``XLA Ops`` line, on its
-clock.
+without the plane. While a profiler session runs every :class:`span`
+that belongs to a journey is also a
+``jax.profiler.TraceAnnotation("pw.<stage>")``: the program's spans land
+in the same xplane as the device's ``XLA Ops`` line, on its clock. A
+span's enter and exit, :func:`dispatched` and :func:`waited` are the
+boundaries of the thread's timeline (``metrics._Timeline``), which
+charges each stage its self time and whether the device had work of the
+thread's to run.
 
 The :class:`TraceStore` keeps completed spans in a bounded ring (like
 the flight recorder's event ring) plus **p99 exemplar retention**: when
@@ -42,6 +46,7 @@ from typing import Any, Optional
 
 from ..internals.flight_recorder import _env_flag, _env_int
 from .context import TraceContext, bind_trace, current_trace, gen_span_id, gen_trace_id
+from .metrics import TRACING_METRICS
 
 TRACE_DUMP_FORMAT_VERSION = 1
 
@@ -74,6 +79,8 @@ def set_tracing_enabled(on: bool) -> bool:
     global _ENABLED
     prev = _ENABLED
     _ENABLED = bool(on)
+    if _ENABLED != prev:
+        TRACING_METRICS.restart_timelines()  # the time it was off is no stage's
     return prev
 
 
@@ -257,8 +264,6 @@ class TraceStore:
             if sp.is_root or sp.boundary:
                 completed = self._by_trace.pop(sp.trace_id, [sp])
                 self._retain(sp.trace_id, completed, sp.duration_s)
-        from .metrics import TRACING_METRICS
-
         TRACING_METRICS.observe(
             sp.stage, sp.duration_s, sp.trace_id, worker=sp.worker, units=sp.attrs
         )
@@ -494,18 +499,22 @@ class span:
     With tracing off this is one check: the shared no-op comes back and
     nothing is built. On, the block's wall (``time.perf_counter``) and
     its work units (the attributes ``rows``, ``queries``, ``tokens``)
-    add to the stage's totals, and under a ``jax.profiler`` session the
-    block is a ``TraceAnnotation("pw.<stage>")`` in the profile.
+    add to the stage's totals, and its enter and exit are boundaries of
+    the thread's timeline (``metrics._Timeline``), on the same two clock
+    reads: a stage's self time is its wall less its children's.
 
     A :class:`Span` with ids is built (and yielded) only where there is
     a journey to hang it on: a trace context is bound, or
     ``new_trace=True`` — the admission path, where a request that
     arrived without a ``traceparent`` starts its journey, and the batch
     boundaries of the device plane, where with no request the batch is
-    the request. Elsewhere the block yields None and leaves its totals
-    and its annotation, nothing in the ring. While the block runs, the
-    child context is bound so nested spans parent correctly — the same
-    scoping ``bind_deadline`` gives the request deadline.
+    the request. Such a block is also, under a ``jax.profiler`` session,
+    a ``TraceAnnotation("pw.<stage>")`` in the profile. Elsewhere (a
+    bare ``remove``, a publish paid from another plane's read) the block
+    yields None and leaves its totals: nothing in the ring, no event in
+    the profile. While the block runs, the child context is bound so
+    nested spans parent correctly — the same scoping ``bind_deadline``
+    gives the request deadline.
 
     ``boundary=True`` marks the process-entry span of a journey (the
     HTTP request span): finishing it completes the trace for exemplar
@@ -513,67 +522,50 @@ class span:
     the *client's* span rather than a local root.
     """
 
-    __slots__ = (
-        "_stage",
-        "_ctx",
-        "_new_trace",
-        "_boundary",
-        "_links",
-        "_attrs",
-        "_sp",
-        "_token",
-        "_annotation",
-        "_t0",
-    )
+    __slots__ = ("_stage", "_options", "_profiled", "_sp", "_token", "_annotation", "_timeline", "_t0")
 
-    def __new__(cls, stage: str, **kwargs):
-        if not tracing_enabled():
+    def __new__(cls, stage: str, **options):
+        """``options``: ``ctx``, ``new_trace``, ``boundary``, ``links`` and
+        the span's attributes."""
+        profiled = _profiling()  # asked once a span
+        if not (_ENABLED or profiled):
             return _OFF
-        return super().__new__(cls)
-
-    def __init__(
-        self,
-        stage: str,
-        *,
-        ctx: TraceContext | None = None,
-        new_trace: bool = False,
-        boundary: bool = False,
-        links: tuple = (),
-        **attrs,
-    ):
+        self = object.__new__(cls)
         self._stage = stage
-        self._ctx = ctx
-        self._new_trace = new_trace
-        self._boundary = boundary
-        self._links = links
-        self._attrs = attrs
-        self._sp: Span | None = None
-        self._token = None
-        self._annotation = None
-        self._t0 = 0.0
+        self._options = options
+        self._profiled = profiled
+        self._sp = None
+        return self
 
     def __enter__(self) -> Span | None:
-        if _profiling():
-            self._annotation = _ANNOTATION("pw." + self._stage)
-            self._annotation.__enter__()
-        self._t0 = _time.perf_counter()
-        parent = self._ctx if self._ctx is not None else current_trace()
+        attrs = self._options  # what is left of them once the options are taken out
+        parent = attrs.pop("ctx", None) or current_trace()
+        journey = attrs.pop("new_trace", False) or parent is not None
+        annotation = None
+        if journey and self._profiled:
+            annotation = _ANNOTATION("pw." + self._stage)
+            annotation.__enter__()
+        timeline = self._timeline = TRACING_METRICS.timeline()
+        self._t0 = now = _time.perf_counter()
+        timeline.enter(self._stage, now)
+        if not journey:
+            return None
+        self._annotation = annotation
         if parent is None:
-            if not self._new_trace:
-                return None
             trace_id, parent_id = gen_trace_id(), ""
         else:
             trace_id, parent_id = parent.trace_id, parent.span_id
+        boundary, links = attrs.pop("boundary", False), attrs.pop("links", ())
         sp = Span(
             trace_id,
             gen_span_id(),
             parent_id,
             self._stage,
             worker=TRACE_STORE.worker,
-            attrs=self._attrs,
-            links=self._links,
+            attrs=attrs,
+            links=links,
         )
-        sp.boundary = self._boundary
+        sp.boundary = boundary
         self._sp = sp
         TRACE_STORE.begin(sp)
         self._token = bind_trace(TraceContext(trace_id, sp.span_id))
@@ -581,24 +573,40 @@ class span:
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        seconds = _time.perf_counter() - self._t0
-        sp, self._sp = self._sp, None
+        now = _time.perf_counter()
+        seconds = now - self._t0
+        sp = self._sp
         if sp is None:
-            from .metrics import TRACING_METRICS
-
-            TRACING_METRICS.observe(
-                self._stage, seconds, "", worker=TRACE_STORE.worker, units=self._attrs
-            )
-        else:
-            self._token.__exit__()
-            self._token = None
-            if exc is not None:
-                sp.attrs["error"] = type(exc).__name__
-            sp.duration_s = seconds
-            TRACE_STORE.finish(sp)
+            self._timeline.exit_bare(now, self._stage, seconds, TRACE_STORE.worker, self._options)
+            return
+        self._sp = None
+        self._timeline.exit(now)
+        self._token.__exit__()
+        self._token = None
+        if exc is not None:
+            sp.attrs["error"] = type(exc).__name__
+        sp.duration_s = seconds
+        TRACE_STORE.finish(sp)
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
             self._annotation = None
+
+
+def dispatched(handle) -> None:
+    """A site has enqueued device work. ``handle``: the smallest array
+    that program returns — the thread's timeline keeps it, and no copy,
+    until it is seen ready or the next dispatch replaces it. One check
+    with tracing off."""
+    if tracing_enabled():
+        TRACING_METRICS.timeline().dispatched(handle, _time.perf_counter())
+
+
+def waited() -> None:
+    """A site has blocked until its results were on the host: the
+    interval since the thread's last boundary was ``waiting``. One check
+    with tracing off."""
+    if tracing_enabled():
+        TRACING_METRICS.timeline().waited(_time.perf_counter())
 
 
 def record_span(

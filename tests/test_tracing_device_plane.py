@@ -5,8 +5,10 @@ With tracing off a site is one check and leaves nothing. On, a write
 batch, an embed batch and a query batch are journeys of their own when
 no request is bound; a bare ``remove(key)`` adds to its stage's totals
 and builds no ``Span``. A ``jax.profiler`` session turns tracing on by
-itself and every span is then a ``pw.<stage>`` event in the profile.
-The named scopes inside the device programs change no operation.
+itself and every span of a journey is then a ``pw.<stage>`` event in the
+profile. The thread that dispatches keeps a timeline: each stage's self
+time, starved, overlapped or waiting by whether the device had work to
+run. The named scopes inside the device programs change no operation.
 """
 
 from __future__ import annotations
@@ -16,6 +18,8 @@ import glob
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import jax
 import numpy as np
@@ -32,9 +36,15 @@ from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
 
 WRITE_STAGES = {
     "index_remove", "index_publish", "embed_batch", "embed_tokenize", "embed_pack",
-    "embed_dispatch", "index_add", "index_flush", "index_scatter",
+    "embed_dispatch", "embed_gather", "index_add", "index_flush", "index_scatter",
 }
-QUERY_STAGES = {"query_batch", "query_tokenize", "query_device", "query_resolve"}
+QUERY_STAGES = {
+    "query_batch", "query_tokenize", "query_sync", "query_device",
+    "query_enqueue", "query_wait", "query_fetch", "query_resolve",
+}
+#: what the timeline adds to the totals of a thread that dispatched
+PSEUDO_STAGES = {"caller", "timeline"}
+STATES = ("starved_seconds", "overlapped_seconds", "waiting_seconds")
 
 
 @pytest.fixture(autouse=True)
@@ -65,15 +75,36 @@ def embedder(enc):
 DOCS = [f"document {i} speaks of subject {i % 7} at length" for i in range(24)]
 
 
-@pytest.fixture()
-def index(enc, embedder):
-    """24 standing rows, put there with tracing off and the programs of
-    the write and query paths warm."""
+def _standing_index(enc, embedder):
     idx = knn.DeviceKnnIndex(dim=enc.dim, metric="cos", reserved_space=64)
     idx.attach_encoder(enc)
     idx.add_batch_device(list(range(24)), embedder.encode_device(DOCS), None)
     idx.search_texts_batch(DOCS[:3], 3)
     return idx
+
+
+@pytest.fixture()
+def index(enc, embedder):
+    """24 standing rows, put there with tracing off and the programs of
+    the write and query paths warm."""
+    return _standing_index(enc, embedder)
+
+
+class FakeHandle:
+    """What a dispatch site hands the timeline, with the test saying
+    when the device is done."""
+
+    def __init__(self, ready=False, deleted=False):
+        self.ready, self.deleted, self.probes = ready, deleted, 0
+
+    def is_deleted(self):
+        return self.deleted
+
+    def is_ready(self):
+        self.probes += 1
+        if self.deleted:  # of a real array this call ends the process
+            raise AssertionError("a deleted array was asked whether it is ready")
+        return self.ready
 
 
 def _write_batch(idx, embedder, keys):
@@ -118,11 +149,15 @@ def test_off_a_site_is_one_check(monkeypatch):
     monkeypatch.setattr(trace_store, "gen_span_id", refuse)
     monkeypatch.setattr(trace_store, "_ANNOTATION", None)
     monkeypatch.setattr(TRACING_METRICS, "_lock", None)
+    monkeypatch.setattr(TRACING_METRICS, "timeline", refuse)
     monkeypatch.setattr(TRACE_STORE, "_lock", None)
     a, b = span("index_remove", rows=1), span("query_batch", new_trace=True, queries=3)
     assert a is b and not isinstance(a, span)
     with a as sp:
         assert sp is None
+    handle = FakeHandle()
+    assert tracing.dispatched(handle) is None and tracing.waited() is None
+    assert handle.probes == 0
 
 
 def test_import_and_the_check_stay_jax_free():
@@ -168,20 +203,24 @@ def test_on_a_query_batch_counts_real_queries_not_padded(index):
     got = index.search_texts_batch([DOCS[4], DOCS[9], DOCS[11]], 3)
     assert [row[0][0] for row in got] == [4, 9, 11]
     totals = stage_totals()
-    assert set(totals) == QUERY_STAGES
+    assert set(totals) - PSEUDO_STAGES == QUERY_STAGES
     for stage in QUERY_STAGES:
         assert totals[stage]["calls"] == 1 and totals[stage]["queries"] == 3  # the program ran 8
         assert totals[stage]["rows"] == totals[stage]["tokens"] == 0
-    inner = sum(totals[s]["seconds"] for s in QUERY_STAGES - {"query_batch"})
+    inner = sum(totals[s]["seconds"] for s in ("query_tokenize", "query_sync", "query_device", "query_resolve"))
     assert 0 < inner <= totals["query_batch"]["seconds"]
+    # the "+1.6 ms" of a dispatch, in three parts under query_device
+    parts = sum(totals[s]["seconds"] for s in ("query_enqueue", "query_wait", "query_fetch"))
+    assert 0 < parts <= totals["query_device"]["seconds"]
+    assert totals["query_wait"]["waiting_seconds"] > 0
 
 
 @pytest.mark.parametrize(
     "boundary, children",
     [
-        ("embed_batch", {"embed_tokenize", "embed_pack", "embed_dispatch"}),
+        ("embed_batch", {"embed_tokenize", "embed_pack", "embed_dispatch", "embed_gather"}),
         ("index_add", {"index_flush", "index_scatter", "index_publish"}),
-        ("query_batch", {"query_tokenize", "query_device", "query_resolve"}),
+        ("query_batch", {"query_tokenize", "query_sync", "query_device", "query_resolve"}),
     ],
 )
 def test_with_no_request_the_batch_is_the_journey(index, embedder, boundary, children):
@@ -319,23 +358,273 @@ def test_totals_sum_workers_and_take_units_from_attributes():
     assert TRACING_METRICS.snapshot()["index_add[w1]"] == {"count": 1, "sum": 0.5, "rows": 5, "tokens": 11}
 
 
+# -- the host's timeline ---------------------------------------------------
+
+
+def _approx(x):
+    return pytest.approx(x, abs=1e-9)
+
+
+def test_the_headings_sum_to_self_time_and_self_time_is_seconds_less_the_children():
+    set_tracing_enabled(True)
+    handle = FakeHandle()
+    with span("index_add", new_trace=True, rows=3):
+        with span("index_replace", rows=2):
+            for _ in range(2):
+                with span("index_remove", rows=1):
+                    time.sleep(0.002)
+        with span("index_scatter", rows=3):
+            tracing.dispatched(handle)
+        time.sleep(0.002)
+    with span("query_batch", new_trace=True, queries=1):
+        with span("query_wait", queries=1):
+            time.sleep(0.002)
+            tracing.waited()
+    totals = stage_totals()
+    stages = set(totals) - {"timeline"}
+    assert stages == {"index_add", "index_replace", "index_remove", "index_scatter", "query_batch", "query_wait", "caller"}
+    for stage in stages:
+        assert totals[stage]["self_seconds"] == _approx(sum(totals[stage][s] for s in STATES))
+    whole = totals["timeline"]
+    assert whole["seconds"] == _approx(sum(totals[stage]["self_seconds"] for stage in stages))
+    for state in STATES:
+        assert whole[state] == _approx(sum(totals[stage][state] for stage in stages))
+    assert whole["calls"] == 0 and "self_seconds" not in whole
+    # inclusive seconds are what they were; self time takes the children out
+    add, replace, remove = (totals[s] for s in ("index_add", "index_replace", "index_remove"))
+    assert add["seconds"] > replace["seconds"] > remove["seconds"] > 0.004
+    assert remove["self_seconds"] == _approx(remove["seconds"])
+    assert replace["self_seconds"] == _approx(replace["seconds"] - remove["seconds"])
+    assert add["self_seconds"] == _approx(add["seconds"] - replace["seconds"] - totals["index_scatter"]["seconds"])
+    assert add["self_seconds"] > 0.002 > replace["self_seconds"]
+    assert totals["caller"]["seconds"] == totals["caller"]["self_seconds"] and totals["caller"]["calls"] == 0
+
+
+def test_an_interval_is_starved_overlapped_or_waiting():
+    set_tracing_enabled(True)
+    handle = FakeHandle()
+    with span("embed_batch", new_trace=True, rows=1):
+        time.sleep(0.003)  # nothing in flight
+        with span("embed_dispatch", rows=1):
+            tracing.dispatched(handle)
+        time.sleep(0.003)  # the device has the batch
+    with span("query_batch", new_trace=True, queries=1):
+        with span("query_wait", queries=1):
+            time.sleep(0.003)
+            tracing.waited()
+    totals = stage_totals()
+    embed, wait = totals["embed_batch"], totals["query_wait"]
+    assert embed["starved_seconds"] > 0.003 and embed["overlapped_seconds"] > 0.003 and embed["waiting_seconds"] == 0
+    assert embed["self_seconds"] < 0.009
+    assert wait["waiting_seconds"] > 0.003 and wait["starved_seconds"] == 0 and wait["overlapped_seconds"] < 0.001
+    # what led up to the dispatch was starved, what followed it overlapped
+    assert totals["embed_dispatch"]["starved_seconds"] > 0 and totals["embed_dispatch"]["overlapped_seconds"] > 0
+    assert totals["query_batch"]["starved_seconds"] == 0  # the handle never came ready
+    assert handle.probes > 0
+
+
+def test_a_handle_seen_ready_is_dropped_and_not_asked_again(index):
+    set_tracing_enabled(True)
+    handle = FakeHandle()
+    tracing.dispatched(handle)
+    with span("index_publish"):
+        pass
+    assert handle.probes == 2  # a boundary, a probe, while something is in flight
+    handle.ready = True
+    for key in range(100):  # 24 of them have a row
+        index.remove(key)
+    assert handle.probes == 3 and TRACING_METRICS.timeline().handle is None
+    removes = stage_totals()["index_remove"]
+    assert removes["calls"] == 100 and removes["starved_seconds"] == _approx(removes["seconds"])
+    assert removes["overlapped_seconds"] == 0  # the one overlapped interval was the caller's
+    again = FakeHandle()
+    tracing.dispatched(again)
+    index.remove(0)
+    assert again.probes == 2 and handle.probes == 3
+
+
+def test_a_deleted_array_counts_as_ready():
+    set_tracing_enabled(True)
+    gone = jax.numpy.zeros((3,))
+    gone.delete()
+    for handle in (gone, FakeHandle(deleted=True)):
+        tracing.dispatched(handle)
+        assert TRACING_METRICS.timeline().handle is handle
+        with span("index_flush", rows=1):
+            pass
+        assert TRACING_METRICS.timeline().handle is None
+    assert stage_totals()["index_flush"]["overlapped_seconds"] == 0
+
+
+def test_two_threads_keep_two_timelines():
+    """One holds the device busy, one has seen it empty, a third never
+    dispatches: no thread's handle colours another's seconds, and only
+    the threads that dispatch are on the timeline."""
+    set_tracing_enabled(True)
+    busy = FakeHandle()
+
+    def work(stage, handle):
+        if handle is not None:
+            tracing.dispatched(handle)
+        with span(stage, rows=1):
+            time.sleep(0.004)
+
+    threads = [
+        threading.Thread(target=work, args=args)
+        for args in (("index_scatter", busy), ("index_flush", FakeHandle(ready=True)), ("admission", None))
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    totals = stage_totals()
+    assert totals["index_scatter"]["overlapped_seconds"] > 0.004 and totals["index_scatter"]["starved_seconds"] == 0
+    assert totals["index_flush"]["starved_seconds"] > 0.004 and totals["index_flush"]["overlapped_seconds"] == 0
+    assert totals["admission"]["calls"] == 1 and "self_seconds" not in totals["admission"]
+    assert totals["timeline"]["seconds"] == _approx(
+        sum(totals[s]["self_seconds"] for s in ("index_scatter", "index_flush", "caller"))
+    )
+    assert 0.008 < totals["timeline"]["seconds"] < 0.1
+    # a thread that ended keeps its seconds when the next one registers
+    last = threading.Thread(target=work, args=("index_scatter", FakeHandle()))
+    last.start()
+    last.join(timeout=30)
+    assert not last.is_alive()
+    after = stage_totals()
+    assert after["index_scatter"]["overlapped_seconds"] > 0.008
+    assert after["index_flush"]["starved_seconds"] == totals["index_flush"]["starved_seconds"]
+    # and its bare spans, which it counted without the registry's lock
+    assert [after[s]["calls"] for s in ("index_scatter", "index_flush", "admission")] == [2, 1, 1]
+    assert after["index_scatter"]["rows"] == 2 and after["index_scatter"]["seconds"] > 0.008
+    assert {row["stage"]: row["count"] for row in TRACING_METRICS.series()} == {
+        "index_scatter": 2, "index_flush": 1, "admission": 1,
+    }
+
+
+def test_bare_spans_of_many_threads_lose_no_count_while_the_totals_are_read():
+    """Each thread counts its bare spans in histograms of its own, with
+    no lock; a reader sums them while they grow, and nothing is lost."""
+    set_tracing_enabled(True)
+    workers, each = 4 * (os.cpu_count() or 2), 500
+    go = threading.Event()
+
+    def work():
+        go.wait(30)
+        for _ in range(each):
+            with span("index_remove", rows=1):
+                pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(workers)]
+        for t in threads:
+            t.start()
+        go.set()
+        seen, deadline = 0, time.monotonic() + 120
+        while any(t.is_alive() for t in threads) and time.monotonic() < deadline:
+            now = stage_totals().get("index_remove", {}).get("calls", 0)
+            assert now >= seen  # a sum that only grows
+            seen = now
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    totals = stage_totals()["index_remove"]
+    assert totals["calls"] == totals["rows"] == workers * each
+    assert "self_seconds" not in totals  # no thread dispatched
+
+
+def test_a_reset_or_the_switch_charges_the_gap_to_no_stage():
+    set_tracing_enabled(True)
+    tracing.dispatched(FakeHandle())
+    with span("index_flush", rows=1):
+        pass
+    set_tracing_enabled(False)
+    time.sleep(0.01)  # off: nobody's
+    set_tracing_enabled(True)
+    with span("index_flush", rows=1):
+        pass
+    assert stage_totals()["timeline"]["seconds"] < 0.005
+    TRACING_METRICS.reset()
+    with span("index_flush", rows=1):
+        pass
+    assert set(stage_totals()) == {"index_flush"}  # the thread has not dispatched since
+
+
+def test_every_dispatch_site_hands_over_a_handle_and_tracing_changes_no_bit(enc, embedder, monkeypatch):
+    from pathway_tpu.internals.http_monitoring import MonitoringHttpServer
+    from pathway_tpu.models import sentence_encoder
+
+    keys, queries = [3, 4, 5, 6, 7], [DOCS[4], DOCS[9], DOCS[11]]
+    plain = _standing_index(enc, embedder)
+    _write_batch(plain, embedder, keys)
+    want = plain.search_texts_batch(queries, 3)
+    traced = _standing_index(enc, embedder)
+
+    handed = []
+
+    def record(handle):
+        handed.append((TRACING_METRICS.timeline().stack[-1], handle))
+        tracing.dispatched(handle)
+
+    monkeypatch.setattr(knn, "_dispatched", record)
+    monkeypatch.setattr(sentence_encoder, "_dispatched", record)
+    set_tracing_enabled(True)
+    _write_batch(traced, embedder, keys)
+    got = traced.search_texts_batch(queries, 3)
+    set_tracing_enabled(False)
+    assert got == want  # keys and scores, to the bit
+    np.testing.assert_array_equal(np.asarray(traced._dev_matrix), np.asarray(plain._dev_matrix))
+    np.testing.assert_array_equal(np.asarray(traced._dev_valid), np.asarray(plain._dev_valid))
+    assert [stage for stage, _ in handed] == [
+        "embed_dispatch", "embed_gather", "index_flush", "index_scatter", "query_enqueue",
+    ]
+    for stage, handle in handed:
+        assert isinstance(handle, jax.Array), stage
+    by_stage = dict(handed)
+    assert by_stage["index_scatter"].shape == (traced.capacity,)  # the validity column, not the slab
+    assert by_stage["query_enqueue"].shape == (8, 2 * 8)  # the packed answer
+    totals = stage_totals()
+    assert WRITE_STAGES | QUERY_STAGES | PSEUDO_STAGES == set(totals)
+    assert totals["embed_gather"]["rows"] == totals["embed_batch"]["rows"] == len(keys)
+    for stage in ("query_sync", "query_enqueue", "query_wait", "query_fetch"):
+        assert totals[stage]["calls"] == 1 and totals[stage]["queries"] == 3
+    for stage in set(totals) - {"timeline"}:
+        assert totals[stage]["self_seconds"] == _approx(sum(totals[stage][s] for s in STATES))
+    assert totals["timeline"]["starved_seconds"] > 0 and totals["query_wait"]["waiting_seconds"] > 0
+    lines = MonitoringHttpServer._tracing_lines()
+    assert "# TYPE pathway_stage_device_seconds counter" in lines
+    for state in ("starved", "overlapped", "waiting"):
+        assert any(
+            line.startswith(f'pathway_stage_device_seconds{{stage="query_wait",state="{state}",worker="0"}} ')
+            for line in lines
+        )
+    assert not any('stage="timeline"' in line for line in lines)
+
+
 # -- the profiler's switch ------------------------------------------------
 
 
 def test_a_profiler_session_turns_tracing_on_and_names_the_spans(index, embedder, tmp_path, monkeypatch):
+    """Every stage of a journey is a ``pw.<stage>`` event, as many as
+    its calls; a bare top-level ``remove`` has its totals and no event."""
     monkeypatch.delenv("PATHWAY_TRACING", raising=False)
     assert not tracing.tracing_enabled()
     jax.profiler.start_trace(str(tmp_path))
     try:
         assert tracing.tracing_enabled()
-        _write_batch(index, embedder, [3, 4])
+        _write_batch(index, embedder, [3, 4])  # two bare removes
+        index.add_batch_device([6], embedder.encode_device([DOCS[6]]), None)  # and one nested in the add's journey
         index.search_texts_batch([DOCS[3]], 2)
     finally:
         jax.profiler.stop_trace()
     assert not tracing.tracing_enabled()
     index.remove(5)  # off again: nothing more
     totals = stage_totals()
-    assert WRITE_STAGES | QUERY_STAGES <= set(totals) and totals["index_remove"]["calls"] == 2
+    assert WRITE_STAGES | QUERY_STAGES <= set(totals) and totals["index_remove"]["calls"] == 3
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
     seen: dict[str, int] = {}
     for plane in jax.profiler.ProfileData.from_file(path).planes:
@@ -344,8 +633,8 @@ def test_a_profiler_session_turns_tracing_on_and_names_the_spans(index, embedder
                 if event.name.startswith("pw."):
                     assert plane.name.startswith("/host:")
                     seen[event.name[3:]] = seen.get(event.name[3:], 0) + 1
-    assert set(seen) == set(totals)
-    assert seen == {stage: t["calls"] for stage, t in totals.items()}
+    calls = {stage: t["calls"] for stage, t in totals.items() if stage not in PSEUDO_STAGES}
+    assert seen == {**calls, "index_remove": 1}
 
 
 # -- the scopes are names only --------------------------------------------
