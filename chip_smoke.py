@@ -96,10 +96,12 @@ def check(ok: bool, what: str) -> None:
     say(f"ok: {what}")
 
 
-def has_mosaic_kernel(jitted, *args, **kwargs) -> bool:
+def has_mosaic_kernel(jitted, *args, name: str | None = None, **kwargs) -> bool:
     """Whether the program XLA compiled for these arguments calls a
-    Mosaic kernel — a path that quietly took the XLA route does not."""
-    return "tpu_custom_call" in jitted.lower(*args, **kwargs).compile().as_text()
+    Mosaic kernel (the one called ``name``, where given) — a path that
+    quietly took the XLA route does not."""
+    text = jitted.lower(*args, **kwargs).compile().as_text()
+    return any("tpu_custom_call" in line and (name is None or f"%{name}" in line) for line in text.splitlines())
 
 
 # ---- corpus ----------------------------------------------------------------
@@ -407,20 +409,23 @@ def check_selective_scan(rng) -> None:
 def check_latent_moe(rng) -> None:
     """The latent-attention / sparse-expert encoder at its test preset,
     through the embedder's table, ``encode_device``, ``add_batch_device``
-    and the fused text-query program."""
+    and the fused text-query program; its expert layer and, for texts of
+    128, its attention compile to Mosaic kernels."""
     import jax
 
     from pathway_tpu.models.sentence_encoder import SentenceEncoder
     from pathway_tpu.ops.knn import DeviceKnnIndex
 
     enc = SentenceEncoder("latent-moe-tiny-for-tests")
-    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(n))) for n in rng.integers(8, 60, size=64)]
+    # buckets of 64 and 128: the second runs the attention kernel
+    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(n))) for n in rng.integers(8, 120, size=64)]
     rows = enc.encode_device(docs)
-    ids = jax.ShapeDtypeStruct((8, 64), np.int16)
-    check(
-        has_mosaic_kernel(enc._fwd_group.__wrapped__, enc.params, ids, jax.ShapeDtypeStruct((8,), np.int32)),
-        "latent-moe-tiny-for-tests: the group forward compiles its expert layer to a Mosaic kernel",
-    )
+    lens = jax.ShapeDtypeStruct((8,), np.int32)
+    for seq, kernel, what in ((64, "expert_grouped_matmul", "expert layer"), (128, "mla_attention", "attention")):
+        check(
+            has_mosaic_kernel(enc._fwd_group.__wrapped__, enc.params, jax.ShapeDtypeStruct((8, seq), np.int16), lens, name=kernel),
+            f"latent-moe-tiny-for-tests: the group forward at 8 x {seq} compiles its {what} to a Mosaic kernel",
+        )
     index = DeviceKnnIndex(enc.dim, metric="cos", reserved_space=64)
     index.attach_encoder(enc)
     index.add_batch_device(list(range(len(docs))), rows, None)

@@ -33,6 +33,12 @@ experts, held or not: what the absent ones would have added is left out
 and the partial result goes on to the next layer — nothing stands in for
 the other ranks or their exchange (``ops/expert_dispatch.py``).
 
+The attention — the queries' rope, scores, causal and padding mask,
+softmax, values — is the Pallas kernel of ``ops/mla_attention.py`` where
+a text is whole tiles of it (the write batches' 256, set-up's 128 and
+256), an XLA chain elsewhere (the query program's 16 tokens); both
+compute the same thing.
+
 Precision: bfloat16 parameters and matmul inputs, float32 accumulation;
 the residual stream, every norm's statistics, rope, softmax, the pool,
 and the router — its weights, its logits (``highest``), the sigmoid and
@@ -60,6 +66,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..ops import mla_attention
 from ..ops.expert_dispatch import held_expert_sum, route
 from .hybrid_ssm import _matmul, _rmsnorm  # float32 statistics; bfloat16 in, float32 out
 
@@ -72,7 +79,7 @@ _SCORE_BYTES = 1 << 29
 @dataclasses.dataclass(frozen=True)
 class LatentMoEConfig:
     """The published ``config.json`` keys, letter for letter, then what
-    this program adds (``experts_held`` ... ``expert_impl``)."""
+    this program adds (``experts_held`` ... ``attention_impl``)."""
 
     attention_bias: bool = False
     first_k_dense_replace: int = 3
@@ -109,6 +116,8 @@ class LatentMoEConfig:
     normalize: bool = True
     # "kernel", or "interpret" for the Pallas interpreter (CPU tests)
     expert_impl: str = "kernel"
+    # "kernel", or "interpret" for the Pallas interpreter (CPU tests)
+    attention_impl: str = "kernel"
 
     #: the whole-layer kernel of ``ops/fused_layer.py`` is the BERT
     #: block's; ``use_fused_encoder`` reads this and stays out
@@ -163,6 +172,16 @@ class LatentMoEConfig:
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
 
+    def group_counts(self, seq: int, lens) -> tuple[str, int, dict] | None:
+        """What a forward of texts padded to ``seq`` with ``lens`` real
+        tokens adds to the program's counters where its attention takes
+        the kernel: the stage, its calls (one a layer), a call's units —
+        the real tokens and the token rows the kernel computes. ``None``
+        on the XLA route."""
+        if mla_attention.route(seq, self.attention_impl) != "kernel":
+            return None
+        return "embed_attention", self.num_hidden_layers, {"tokens": int(lens.sum()), "computed_tokens": len(lens) * seq}
+
     def flops_per_token(self, seq: int) -> float:
         """Forward FLOPs of one token in a text padded to ``seq``,
         multiply-add = 2: the projections, causal attention over half the
@@ -198,6 +217,13 @@ def _rope(x, cos, sin):
     return x * cos + turned * sin
 
 
+def _heads_product(x, w):
+    """``x`` ``[b, s, r]`` times ``w`` ``[r, heads, d]`` -> float32 ``[b, s,
+    heads * d]``, as one product of the tokens' rows."""
+    b, s, r = x.shape
+    return jnp.matmul(x.reshape(b * s, r), w.reshape(r, -1), preferred_element_type=F32).reshape(b, s, -1)
+
+
 def _rope_table(seq: int, dim: int, theta: float):
     inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=F32) / dim))
     angle = jnp.arange(seq, dtype=F32)[:, None] * inv[None, :]
@@ -210,6 +236,21 @@ def _texts_per_block(batch: int, seq: int, heads: int) -> int:
     scores ``[texts, heads, seq, seq]`` stay under ``_SCORE_BYTES``."""
     most = max(1, _SCORE_BYTES // (4 * heads * seq * seq))
     return max(b for b in range(1, batch + 1) if batch % b == 0 and b <= most)
+
+
+def _context_xla(q_nope, q_rope, k_nope, k_rope, v, mask):
+    """The attention as an XLA chain: ``[b, s, heads, nope | rope | v]``
+    queries, keys and values, ``k_rope`` ``[b, s, rope]``, ``mask`` ``[b,
+    s]`` -> the float32 context ``[b, s, heads * v]``. The scores are
+    written out, ``[b, heads, s, s]`` float32."""
+    b, s, heads, vd = v.shape
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope, preferred_element_type=F32)
+    scores = scores + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope, preferred_element_type=F32)
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    keep = causal[None, None] & mask[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(keep, scores / math.sqrt(q_nope.shape[-1] + k_rope.shape[-1]), -1e30), axis=-1)
+    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v, preferred_element_type=F32)
+    return ctx.reshape(b, s, heads * vd)
 
 
 class LatentMoEEncoder:
@@ -344,23 +385,27 @@ class LatentMoEEncoder:
         w_kvb = p["kv_b"]["kernel"].reshape(-1, heads, nope + vd)
         with jax.named_scope("pw.encode.mla_q"):
             cq = _rmsnorm(_matmul(h, p["q_a"]["kernel"]), p["q_a_norm"]["scale"], c.rms_norm_eps).astype(c.dtype)
-            q_nope = jnp.einsum("bsr,rhd->bshd", cq, w_qb[..., :nope], preferred_element_type=F32).astype(c.dtype)
-            q_rope = jnp.einsum("bsr,rhd->bshd", cq, w_qb[..., nope:], preferred_element_type=F32)
-            q_rope = _rope(q_rope, cos, sin).astype(c.dtype)
+            q_nope = _heads_product(cq, w_qb[..., :nope]).astype(c.dtype)
+            q_rope = _heads_product(cq, w_qb[..., nope:])  # float32, turned by the attention
         with jax.named_scope("pw.encode.mla_kv"):
             kva = _matmul(h, p["kv_a"]["kernel"])
             ckv = _rmsnorm(kva[..., : c.kv_lora_rank], p["kv_a_norm"]["scale"], c.rms_norm_eps).astype(c.dtype)
             k_rope = _rope(kva[..., c.kv_lora_rank :], cos, sin).astype(c.dtype)  # one vector for every head
-            k_nope = jnp.einsum("bsr,rhd->bshd", ckv, w_kvb[..., :nope], preferred_element_type=F32).astype(c.dtype)
-            v = jnp.einsum("bsr,rhd->bshd", ckv, w_kvb[..., nope:], preferred_element_type=F32).astype(c.dtype)
+            k_nope = _heads_product(ckv, w_kvb[..., :nope]).astype(c.dtype)
+            v = _heads_product(ckv, w_kvb[..., nope:]).astype(c.dtype)
         with jax.named_scope("pw.encode.attn"):
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope, preferred_element_type=F32)
-            scores = scores + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope, preferred_element_type=F32)
-            causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-            keep = causal[None, None] & mask[:, None, None, :]
-            probs = jax.nn.softmax(jnp.where(keep, scores / math.sqrt(nope + rot), -1e30), axis=-1)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(c.dtype), v, preferred_element_type=F32)
-            return _matmul(ctx.reshape(b, s, heads * vd), p["o"]["kernel"])
+            if mla_attention.route(s, c.attention_impl) == "kernel":
+                lens = mask.sum(axis=1, dtype=jnp.int32)  # right-padded texts
+                ctx = mla_attention.mla_attention(
+                    q_nope, q_rope, k_nope, k_rope, v, lens, cos, sin, interpret=c.attention_impl == "interpret"
+                )
+            else:
+                q_rope = _rope(q_rope.reshape(b, s, heads, rot), cos, sin).astype(c.dtype)
+                ctx = _context_xla(
+                    q_nope.reshape(b, s, heads, nope), q_rope, k_nope.reshape(b, s, heads, nope), k_rope,
+                    v.reshape(b, s, heads, vd), mask,
+                )  # fmt: skip
+            return _matmul(ctx, p["o"]["kernel"])
 
     def _moe(self, p, h, mask):
         c = self.cfg

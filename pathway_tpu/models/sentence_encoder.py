@@ -456,6 +456,13 @@ class SentenceEncoder:
         # instead of accumulating one upload per dispatch in HBM.
         wire = np.int16 if self.cfg.vocab_size < 32768 else np.int32
         computed = self._record_dispatch(ids.shape[0], ids.shape[1], lens)
+        group_counts = getattr(self.cfg, "group_counts", None)
+        if _tracing_enabled() and group_counts is not None and (counted := group_counts(ids.shape[1], lens)):
+            # the module's own stage, counted from the shape and lengths
+            # the host has: nothing is fetched from the device
+            stage, calls, units = counted
+            for _ in range(calls):
+                TRACING_METRICS.observe(stage, 0.0, "", units=units)
         with _span("embed_dispatch", rows=ids.shape[0], tokens=computed):
             ids_dev, lens_dev = self._ring().stage(
                 [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]

@@ -27,7 +27,7 @@ from pathway_tpu.models.hybrid_ssm import HybridSSMConfig
 from pathway_tpu.models.latent_moe import LatentMoEConfig, LatentMoEEncoder
 from pathway_tpu.models.sentence_encoder import SentenceEncoder, architecture_of
 from pathway_tpu.models.tokenizer import WordPieceTokenizer
-from pathway_tpu.ops import expert_dispatch, knn
+from pathway_tpu.ops import expert_dispatch, knn, mla_attention
 
 SCALES = {"word_std": 1.0, "matrix_gain": 1.0, "router_gain": 1.0, "post_norm_scale": 0.15}
 PRESET = "latent-moe-tiny-for-tests"
@@ -38,7 +38,10 @@ TEXTS = [
     "the quick brown fox jumps over the lazy dog " * 4,
     "w0404 " * 60,
 ]
-PROGRAM_ONLY = ("dtype", "expert_impl", "experts_held", "pooling", "normalize")
+PROGRAM_ONLY = ("dtype", "expert_impl", "attention_impl", "experts_held", "pooling", "normalize")
+#: the attention's two routes on the CPU: "kernel" takes the XLA chain off
+#: the chip, "interpret" the Pallas kernel in the interpreter
+ATTENTION = ("kernel", "interpret")
 
 
 def family_model(cfg: LatentMoEConfig) -> dict:
@@ -51,10 +54,12 @@ def family_model(cfg: LatentMoEConfig) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def tiny(dtype: str = "float32", held: tuple[int, int] = (0, 8)):
+def tiny(dtype: str = "float32", held: tuple[int, int] = (0, 8), attention: str = "kernel"):
     """(SentenceEncoder, family, model, weights) at the tiny preset, the
     seed's weights laid over the program's tree as the benchmark lays them."""
-    cfg = LatentMoEConfig.tiny_for_tests(dtype=jnp.dtype(dtype), expert_impl="interpret", experts_held=held)
+    cfg = LatentMoEConfig.tiny_for_tests(
+        dtype=jnp.dtype(dtype), expert_impl="interpret", attention_impl=attention, experts_held=held
+    )
     enc = SentenceEncoder(PRESET, config=cfg)
     family, model = spec.load_family("pangu_moe"), family_model(cfg)
     weights = make_weights(family, model, SCALES, seed=11)
@@ -221,10 +226,11 @@ def test_rope_by_hand_at_two_positions():
     np.testing.assert_allclose(np.asarray(latent_moe._rope(heads, cos, sin))[0, :, 1], 2 * got, atol=1e-5)
 
 
-def test_padding_invariance():
+@pytest.mark.parametrize("attention", ATTENTION)
+def test_padding_invariance(attention):
     """A text embeds to the same row alone at its own bucket and inside a
     batch padded to 256 (position = index in the text; pads not routed)."""
-    enc, *_ = tiny()
+    enc, *_ = tiny(attention=attention)
     short = "w0007 w0008 w0009 w0010"
     alone = np.asarray(enc.encode_device([short]))[0]
     long = " ".join(f"w{i:04d}" for i in range(250))
@@ -232,30 +238,99 @@ def test_padding_invariance():
     np.testing.assert_allclose(both[0], alone, atol=2e-6)
 
 
-def test_document_independence():
-    """Permuting the rows of a batch permutes the result."""
-    enc, *_ = tiny()
+@pytest.mark.parametrize("attention, texts", [("kernel", TEXTS), ("interpret", TEXTS[:4] + ["w0404 " * 100])])
+def test_document_independence(attention, texts):
+    """Permuting the rows of a batch permutes the result (through the
+    kernel at the bucket of 128)."""
+    enc, *_ = tiny(attention=attention)
     perm = [3, 0, 4, 2, 1]
-    straight = np.asarray(enc.encode_device(TEXTS))
-    shuffled = np.asarray(enc.encode_device([TEXTS[i] for i in perm]))
+    straight = np.asarray(enc.encode_device(texts))
+    shuffled = np.asarray(enc.encode_device([texts[i] for i in perm]))
     np.testing.assert_allclose(shuffled, straight[perm], atol=2e-6)
 
 
-def test_texts_go_through_attention_in_blocks():
+@pytest.mark.parametrize("attention, texts, seq", [("kernel", TEXTS, 64), ("interpret", TEXTS[:4] + ["w0404 " * 100], 128)])
+def test_texts_go_through_attention_in_blocks(attention, texts, seq):
     assert latent_moe._texts_per_block(32, 256, 128) == 16  # [16, 128, 256, 256] float32 = half a GiB
     assert latent_moe._texts_per_block(8, 16, 128) == 8
     assert latent_moe._texts_per_block(6, 256, 128) == 6
-    enc, *_ = tiny()
-    whole = np.asarray(enc.encode_device(TEXTS))
+    enc, *_ = tiny(attention=attention)
+    whole = np.asarray(enc.encode_device(texts))
     blocked = latent_moe._SCORE_BYTES
-    try:  # two texts a block of the bucket of 8 x 64
-        latent_moe._SCORE_BYTES = 2 * 4 * enc.cfg.num_attention_heads * 64 * 64
+    try:  # two texts a block of the bucket of 8 x seq
+        latent_moe._SCORE_BYTES = 2 * 4 * enc.cfg.num_attention_heads * seq * seq
         enc._fwd_group = None
-        got = np.asarray(enc.encode_device(TEXTS))
+        got = np.asarray(enc.encode_device(texts))
     finally:
         latent_moe._SCORE_BYTES = blocked
         enc._fwd_group = None
     np.testing.assert_allclose(got, whole, atol=2e-6)
+
+
+# ---- the attention kernel (ops/mla_attention.py) ------------------------------------
+
+
+@pytest.mark.parametrize("lens", ["ones", "full", "mixed"])
+@pytest.mark.parametrize("seq", [128, 256])
+def test_attention_kernel_equals_the_xla_chain(seq, lens):
+    """The kernel in the interpreter against the XLA chain it replaces —
+    rope of the queries, scores, masks, softmax, values — at the tiny
+    preset's heads, on one block of texts: every text one token (every
+    key after the first is padding), every text full, and a mix."""
+    cfg = LatentMoEConfig.tiny_for_tests()
+    heads, nope, rot, vd = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    lens = {"ones": [1, 1, 1, 1], "full": [seq] * 4, "mixed": [1, seq, 77, seq - 1]}[lens]
+    rng = np.random.default_rng(seq)
+
+    def draw(*shape, dtype=jnp.bfloat16):
+        return jnp.asarray(rng.normal(size=(4, seq) + shape), dtype)
+
+    q_nope, k_nope, v = draw(heads, nope), draw(heads, nope), draw(heads, vd)
+    q_rope, k_rope = draw(heads, rot, dtype=jnp.float32), draw(rot)  # the queries' rope lanes not yet turned
+    cos, sin = latent_moe._rope_table(seq, rot, cfg.rope_theta)
+    mask = jnp.arange(seq)[None, :] < jnp.asarray(lens)[:, None]
+    turned = latent_moe._rope(q_rope, cos, sin).astype(jnp.bfloat16)
+    want = np.asarray(latent_moe._context_xla(q_nope, turned, k_nope, k_rope, v, mask))
+    args = [x.reshape(4, seq, -1) for x in (q_nope, q_rope, k_nope)] + [k_rope, v.reshape(4, seq, -1), jnp.asarray(lens, jnp.int32), cos, sin]
+    got = mla_attention.mla_attention(*args, out_dtype=jnp.float32, interpret=True)
+    # float32 on both sides up to the bfloat16 rounding of a probability
+    # whose float32 value differs in its last bit
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    same = mla_attention.mla_attention(*args, interpret=True)
+    assert same.dtype == jnp.bfloat16  # what W_o reads, on the normal path
+    np.testing.assert_allclose(np.asarray(same, np.float32), want, rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("seq, route", [(16, "xla"), (160, "xla"), (128, "kernel"), (256, "kernel")])
+def test_attention_route_by_shape_and_its_counter(seq, route):
+    """Texts of whole 128-token tiles take the kernel — a call a layer,
+    counted on the host as ``embed_attention`` — and every other shape
+    (the query program's 16) the XLA chain, counted nowhere. Off the chip
+    the kernel runs only where it is asked for in the interpreter."""
+    assert mla_attention.route(seq, "interpret") == route
+    assert mla_attention.route(seq, "kernel") == "xla"  # the CPU
+    enc, *_ = tiny(attention="interpret")
+    layers = enc.cfg.num_hidden_layers
+    lens = np.array([seq, 1, seq // 2, 3], np.int32)
+    ids = np.where(np.arange(seq)[None, :] < lens[:, None], 7, 0).astype(np.int32)
+    program = str(jax.make_jaxpr(enc.module.apply)(enc.params, ids, jnp.asarray(ids > 0)))
+    assert program.count("mla_attention") == (layers if route == "kernel" else 0)
+    tracing.set_tracing_enabled(True)
+    tracing.TRACING_METRICS.reset()
+    try:
+        for _ in range(2):
+            enc._run_group(ids, lens)
+        totals = tracing.stage_totals()
+    finally:
+        tracing.set_tracing_enabled(False)
+        tracing.TRACING_METRICS.reset()
+    if route == "xla":
+        assert "embed_attention" not in totals
+    else:
+        stage = totals["embed_attention"]
+        assert stage["calls"] == 2 * layers
+        assert stage["tokens"] == 2 * layers * int(lens.sum())
+        assert stage["computed_tokens"] == 2 * layers * 4 * seq
 
 
 # ---- the published preset, without allocating it --------------------------------

@@ -29,6 +29,7 @@ from pathway_tpu.models.sentence_encoder import SentenceEncoder
 from pathway_tpu.ops.expert_dispatch import capacity_of, grouped_matmul
 from pathway_tpu.ops.fused_attention import attention
 from pathway_tpu.ops.fused_layer import _pack_rows, encoder_forward
+from pathway_tpu.ops.mla_attention import mla_attention
 from pathway_tpu.ops.paged_attention import paged_decode_attention
 from pathway_tpu.ops.pallas_knn import knn_topk, knn_topk_sharded
 from pathway_tpu.ops.power_retention import power_retention
@@ -144,6 +145,20 @@ def _retention_case(tokens: int):
     )
 
 
+def _mla_case(texts: int, seq: int, heads: int = 128, nope: int = 128, rot: int = 64, vd: int = 128):
+    # the latent-attention embedder's attention: a block of 16 texts of a
+    # write batch at the published heads (128 of 128 + 64 rope, values
+    # of 128), and the test preset's 4 heads of 16 + 8
+    def rows(width, dtype=jnp.bfloat16):
+        return _spec((texts, seq, width), dtype)
+
+    table = _spec((seq, rot), jnp.float32)
+    return mla_attention, (
+        rows(heads * nope), rows(heads * rot, jnp.float32), rows(heads * nope), rows(rot), rows(heads * vd),
+        _spec((texts,), jnp.int32), table, table,
+    )  # fmt: skip
+
+
 SINGLE_DEVICE_CASES = {
     **{
         f"encoder_forward[S={s}]": functools.partial(_encoder_case, s)
@@ -165,6 +180,9 @@ SINGLE_DEVICE_CASES = {
     "expert_grouped_matmul[4096x2048x7680]": functools.partial(_experts_case, 2048, 7680),
     "power_retention[T=8192,40|8x128]": functools.partial(_retention_case, 8192),
     "power_retention[T=128,40|8x128]": functools.partial(_retention_case, 128),
+    "mla_attention[16x256,128x(128+64|128)]": functools.partial(_mla_case, 16, 256),
+    "mla_attention[16x128,128x(128+64|128)]": functools.partial(_mla_case, 16, 128),
+    "mla_attention[8x128,4x(16+8|16)]": functools.partial(_mla_case, 8, 128, 4, 16, 8, 16),
 }
 
 
