@@ -67,6 +67,12 @@ SCAN_RTOL = 1e-2
 # they meet the values and its output to bf16 (2^-9 relative each), the
 # recurrence keeps float32 throughout. Relative to the largest output.
 RETENTION_RTOL = 2e-2
+# MLA attention at the published heads (128 of 128 + 64 rope, values of
+# 128) over a packed stream, against the XLA chain a document at a time:
+# the same bf16 inputs, both round the float32 probabilities to bf16 before
+# they meet the values, so a probability whose float32 sums differ in the
+# last bit lands a bf16 step apart (2^-9 relative), on outputs of ~1.
+MLA_ATOL = 2e-2
 # Decode: greedy tokens must equal the impl="xla" engine's, except that the
 # first token to differ may be one the plain XLA forward scores within this
 # of its best (logits have std ~0.3 and a typical top-2 gap of 0.05).
@@ -407,24 +413,65 @@ def check_selective_scan(rng) -> None:
 
 
 def check_latent_moe(rng) -> None:
-    """The latent-attention / sparse-expert encoder at its test preset,
-    through the embedder's table, ``encode_device``, ``add_batch_device``
-    and the fused text-query program; its expert layer and, for texts of
-    128, its attention compile to Mosaic kernels."""
+    """The MLA attention kernel at the published heads over a write
+    batch's packed stream, against the XLA chain a document at a time;
+    then the latent-attention / sparse-expert encoder at its test preset,
+    through the embedder's table, ``encode_device`` (packed streams whose
+    attention and expert layer compile to Mosaic kernels),
+    ``add_batch_device`` and the fused text-query program."""
     import jax
+    import jax.numpy as jnp
 
+    from pathway_tpu.models import latent_moe
     from pathway_tpu.models.sentence_encoder import SentenceEncoder
+    from pathway_tpu.models.token_stream import token_layout
+    from pathway_tpu.ops import mla_attention
     from pathway_tpu.ops.knn import DeviceKnnIndex
 
-    enc = SentenceEncoder("latent-moe-tiny-for-tests")
-    # buckets of 64 and 128: the second runs the attention kernel
-    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(n))) for n in rng.integers(8, 120, size=64)]
+    # 32 documents of 18-256 tokens, each aligned to 16, in a stream of 8,192
+    heads, nope, rot, vd, t = 128, 128, 64, 128, 8192
+    doc_lens = rng.integers(18, 257, size=32)
+    padded = -(-doc_lens // 16) * 16
+    begins = np.cumsum(padded) - padded
+    starts = np.full((t // 16,), t, np.int32)
+    starts[: len(begins)] = begins
+    lens = np.zeros((t // 16,), np.int32)
+    lens[: len(doc_lens)] = doc_lens
+    seg, pos, live = token_layout(jnp.asarray(starts), jnp.asarray(lens), t)
+
+    def draw(width, dtype=jnp.bfloat16):
+        return jnp.asarray(rng.normal(size=(t, width)), dtype)
+
+    q_nope, q_rope, k_nope, k_rope, v = draw(heads * nope), draw(heads * rot, jnp.float32), draw(heads * nope), draw(rot), draw(heads * vd)
+    cos, sin = latent_moe._rope_table(256, rot, 25600000.0)
+    args = (q_nope, q_rope, k_nope, k_rope, v, seg, pos, cos, sin)
+    kernel = jax.jit(functools.partial(mla_attention.mla_attention, span=mla_attention.span_of(256), out_dtype=jnp.float32))
+    shape = f"a stream of {t}, {len(doc_lens)} documents, {int(live)} tokens live, 128 heads of 128 + 64 | 128"
+    check(has_mosaic_kernel(kernel, *args, live=live, name="mla_attention"), f"MLA attention over {shape} compiles to a Mosaic kernel")
+    got = np.asarray(kernel(*args, live=live))
+    err = 0.0
+    for at, n in zip(begins, doc_lens):
+        rows = slice(int(at), int(at + n))
+        turned = latent_moe._rope(q_rope[rows].reshape(1, n, heads, rot), cos[:n], sin[:n]).astype(jnp.bfloat16)
+        want = latent_moe._context_xla(
+            q_nope[rows].reshape(1, n, heads, nope), turned, k_nope[rows].reshape(1, n, heads, nope), k_rope[rows][None],
+            v[rows].reshape(1, n, heads, vd), jnp.tril(jnp.ones((n, n), bool))[None],
+        )  # fmt: skip
+        err = max(err, float(np.abs(got[rows] - np.asarray(want)[0]).max()))
+    check(
+        bool(np.isfinite(got[np.asarray(seg) >= 0]).all()) and err <= MLA_ATOL,
+        f"MLA attention over {shape} vs the XLA chain a document at a time: max abs err {err:.2e} (<= {MLA_ATOL})",
+    )
+
+    name = "latent-moe-tiny-for-tests"
+    enc = SentenceEncoder(name)
+    docs = [" ".join(f"w{int(w):04d}" for w in rng.integers(0, 2000, size=int(n))) for n in rng.integers(8, 250, size=64)]
     rows = enc.encode_device(docs)
-    lens = jax.ShapeDtypeStruct((8,), np.int32)
-    for seq, kernel, what in ((64, "expert_grouped_matmul", "expert layer"), (128, "mla_attention", "attention")):
+    stream = jax.ShapeDtypeStruct((enc.cfg.max_group_tokens,), np.int32), *(jax.ShapeDtypeStruct((enc.cfg.max_group_tokens // 16,), np.int32),) * 2
+    for kernel_name, what in (("expert_grouped_matmul", "expert layer"), ("mla_attention", "attention")):
         check(
-            has_mosaic_kernel(enc._fwd_group.__wrapped__, enc.params, jax.ShapeDtypeStruct((8, seq), np.int16), lens, name=kernel),
-            f"latent-moe-tiny-for-tests: the group forward at 8 x {seq} compiles its {what} to a Mosaic kernel",
+            has_mosaic_kernel(enc._fwd_stream.__wrapped__, enc.params, *stream, name=kernel_name),
+            f"{name}: the stream forward compiles its {what} to a Mosaic kernel",
         )
     index = DeviceKnnIndex(enc.dim, metric="cos", reserved_space=64)
     index.attach_encoder(enc)
@@ -434,7 +481,7 @@ def check_latent_moe(rng) -> None:
     check(
         bool(np.isfinite(norms).all()) and float(np.abs(norms - 1.0).max()) < 1e-3
         and all(a and a[0][0] == i and a[0][1] > 0.99 for i, a in enumerate(answers)),
-        "latent-moe-tiny-for-tests embeds 64 documents to unit rows and each of 16 finds itself first through DeviceKnnIndex",
+        f"{name} embeds 64 documents of 10-252 tokens as packed streams to unit rows and each of 16 finds itself first through DeviceKnnIndex",
     )
 
 

@@ -212,10 +212,16 @@ class SentenceEncoder:
         return int(cache_size()) if cache_size is not None else -1
 
     def predict_compile_keys(self, lengths) -> set[tuple[int, int]]:
-        """The (B, S) jit keys ``encode_tokens`` would compile for a
-        workload of token ``lengths`` at this encoder's geometry."""
+        """The (B, S) jit keys the embed route compiles for a workload of
+        token ``lengths`` at this encoder's geometry: ``encode_tokens``'s
+        buckets, which the tokenized route (``encode``, ``encode_device``)
+        shares — except for a module that packs, whose tokenized route runs
+        every batch as streams of ``max_group_tokens``: one program, ``(1,
+        max_group_tokens)``."""
         from .batching import predict_compile_keys
 
+        if hasattr(self.module, "apply_stream"):
+            return {(1, self.cfg.max_group_tokens)} if len(lengths) else set()
         ndata = self.mesh.shape[self.data_axis] if self.mesh is not None else 1
         return predict_compile_keys(
             lengths, seq_buckets=self._seq_buckets, max_batch=self.max_batch, mesh_ndata=ndata
@@ -399,8 +405,15 @@ class SentenceEncoder:
         with _span("embed_dispatch", rows=int((lens > 0).sum()), tokens=computed):
             wire = self._ring().stage([ids, starts, lens])
             out = self._fwd_stream(self.live_params(), *wire)
-            _dispatched(out)
+            _dispatched(out[0] if isinstance(out, tuple) else out)
             self._wire_ring.retire(wire)
+        if isinstance(out, tuple):
+            # a module with sparse experts also says how many real tokens
+            # each held expert got: the device's own count, handed over
+            # un-fetched — whoever reads the stage totals pays the transfer
+            out, loads = out
+            if _tracing_enabled():
+                TRACING_METRICS.owe_expert_loads("embed_experts", loads)
         return out
 
     def _fused_layer_ok(self, seq_len: int) -> bool:
@@ -439,9 +452,7 @@ class SentenceEncoder:
                         lens=lens_.astype(jnp.int32),
                         interpret=fused_encoder_interpret(self.cfg),
                     )
-                # a module with sparse experts also says how many real
-                # tokens each held expert got: (rows, loads)
-                return getattr(self.module, "apply_with_loads", self.module.apply)(p, ids32, mask)
+                return self.module.apply(p, ids32, mask)
 
             from ..internals.profiler import wrap_jit
 
@@ -456,13 +467,6 @@ class SentenceEncoder:
         # instead of accumulating one upload per dispatch in HBM.
         wire = np.int16 if self.cfg.vocab_size < 32768 else np.int32
         computed = self._record_dispatch(ids.shape[0], ids.shape[1], lens)
-        group_counts = getattr(self.cfg, "group_counts", None)
-        if _tracing_enabled() and group_counts is not None and (counted := group_counts(ids.shape[1], lens)):
-            # the module's own stage, counted from the shape and lengths
-            # the host has: nothing is fetched from the device
-            stage, calls, units = counted
-            for _ in range(calls):
-                TRACING_METRICS.observe(stage, 0.0, "", units=units)
         with _span("embed_dispatch", rows=ids.shape[0], tokens=computed):
             ids_dev, lens_dev = self._ring().stage(
                 [ids.astype(wire, copy=False), lens.astype(np.int32, copy=False)]
@@ -479,14 +483,8 @@ class SentenceEncoder:
                     jax.block_until_ready(out)
             else:
                 out = self._fwd_group(self.live_params(), ids_dev, lens_dev)
-            _dispatched(out[0] if isinstance(out, tuple) else out)
+            _dispatched(out)
             self._wire_ring.retire([ids_dev, lens_dev])
-        if isinstance(out, tuple):
-            out, loads = out
-            if _tracing_enabled():
-                # the device's own count, handed over un-fetched: whoever
-                # reads the stage totals pays the transfer, not this path
-                TRACING_METRICS.owe_expert_loads("embed_experts", loads)
         return out
 
     def _record_dispatch(self, batch: int, seq: int, lens: np.ndarray) -> int:

@@ -38,7 +38,10 @@ TEXTS = [
     "the quick brown fox jumps over the lazy dog " * 4,
     "w0404 " * 60,
 ]
-PROGRAM_ONLY = ("dtype", "expert_impl", "attention_impl", "experts_held", "pooling", "normalize")
+PROGRAM_ONLY = (
+    "dtype", "expert_impl", "attention_impl", "experts_held", "pooling", "normalize", "max_group_tokens", "token_chunk",
+    "doc_align",
+)  # fmt: skip
 #: the attention's two routes on the CPU: "kernel" takes the XLA chain off
 #: the chip, "interpret" the Pallas kernel in the interpreter
 ATTENTION = ("kernel", "interpret")
@@ -47,7 +50,7 @@ ATTENTION = ("kernel", "interpret")
 def family_model(cfg: LatentMoEConfig) -> dict:
     """The benchmark's description of a program configuration."""
     keys = [f for f in cfg.__dataclass_fields__ if f not in PROGRAM_ONLY]
-    model = {"family": "pangu_moe", "max_seq_len": 256, **{k: getattr(cfg, k) for k in keys}}
+    model = {"family": "pangu_moe", **{k: getattr(cfg, k) for k in keys}}
     model["router_experts"] = cfg.n_routed_experts
     model["experts_first"], model["n_routed_experts"] = cfg.experts_held
     return model
@@ -251,86 +254,222 @@ def test_document_independence(attention, texts):
 
 @pytest.mark.parametrize("attention, texts, seq", [("kernel", TEXTS, 64), ("interpret", TEXTS[:4] + ["w0404 " * 100], 128)])
 def test_texts_go_through_attention_in_blocks(attention, texts, seq):
+    """The padded forward (the query program's) takes its texts in blocks
+    whose float32 scores fit; a write batch is one stream and has none."""
     assert latent_moe._texts_per_block(32, 256, 128) == 16  # [16, 128, 256, 256] float32 = half a GiB
     assert latent_moe._texts_per_block(8, 16, 128) == 8
     assert latent_moe._texts_per_block(6, 256, 128) == 6
     enc, *_ = tiny(attention=attention)
-    whole = np.asarray(enc.encode_device(texts))
+    ids, lens = enc.tokenizer.batch_encode_matrix(texts, 256)
+    ids = np.pad(ids, ((0, 8 - len(texts)), (0, 0)))[:, :seq]
+    mask = np.arange(seq)[None, :] < np.pad(lens, (0, 8 - len(texts)))[:, None]
+    assert lens.max() <= seq
+    whole = np.asarray(jax.jit(enc.module.apply)(enc.params, ids, mask))
     blocked = latent_moe._SCORE_BYTES
-    try:  # two texts a block of the bucket of 8 x seq
+    try:  # two texts a block of the 8 x seq
         latent_moe._SCORE_BYTES = 2 * 4 * enc.cfg.num_attention_heads * seq * seq
-        enc._fwd_group = None
-        got = np.asarray(enc.encode_device(texts))
+        assert latent_moe._texts_per_block(8, seq, enc.cfg.num_attention_heads) == 2
+        got = np.asarray(jax.jit(enc.module.apply)(enc.params, ids, mask))
     finally:
         latent_moe._SCORE_BYTES = blocked
-        enc._fwd_group = None
     np.testing.assert_allclose(got, whole, atol=2e-6)
+    np.testing.assert_allclose(got[: len(texts)], np.asarray(enc.encode_device(texts)), atol=2e-6)
 
 
 # ---- the attention kernel (ops/mla_attention.py) ------------------------------------
 
 
+def _heads_of(t: int, seed: int):
+    """Queries, keys and values of ``t`` tokens at the tiny preset's heads:
+    ``q_nope``, ``k_nope``, ``v`` bfloat16, ``q_rope`` float32 not yet
+    turned, ``k_rope`` bfloat16."""
+    cfg = LatentMoEConfig.tiny_for_tests()
+    heads, nope, rot, vd = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rng = np.random.default_rng(seed)
+
+    def draw(width, dtype=jnp.bfloat16):
+        return jnp.asarray(rng.normal(size=(t, width)), dtype)
+
+    return cfg, (draw(heads * nope), draw(heads * rot, jnp.float32), draw(heads * nope), draw(rot), draw(heads * vd))
+
+
+def _chain(cfg, q_nope, q_rope, k_nope, k_rope, v, seg, cos, sin):
+    """The XLA chain over ``[b, s]`` texts: the queries' rope turned by the
+    rows ``cos``/``sin`` ``[s, rope]``, masked by ``seg`` ``[b, s]`` and by
+    order -> float32 ``[b * s, heads * v]``."""
+    b, s = seg.shape
+    heads, nope, rot, vd = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    turned = latent_moe._rope(q_rope.reshape(b, s, heads, rot), cos, sin).astype(q_nope.dtype)
+    keep = (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]) & (seg[:, :, None] == seg[:, None, :])
+    ctx = latent_moe._context_xla(
+        q_nope.reshape(b, s, heads, nope), turned, k_nope.reshape(b, s, heads, nope), k_rope.reshape(b, s, rot),
+        v.reshape(b, s, heads, vd), keep,
+    )  # fmt: skip
+    return np.asarray(ctx).reshape(b * s, -1)
+
+
 @pytest.mark.parametrize("lens", ["ones", "full", "mixed"])
 @pytest.mark.parametrize("seq", [128, 256])
 def test_attention_kernel_equals_the_xla_chain(seq, lens):
-    """The kernel in the interpreter against the XLA chain it replaces —
-    rope of the queries, scores, masks, softmax, values — at the tiny
-    preset's heads, on one block of texts: every text one token (every
-    key after the first is padding), every text full, and a mix."""
-    cfg = LatentMoEConfig.tiny_for_tests()
-    heads, nope, rot, vd = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    lens = {"ones": [1, 1, 1, 1], "full": [seq] * 4, "mixed": [1, seq, 77, seq - 1]}[lens]
-    rng = np.random.default_rng(seq)
-
-    def draw(*shape, dtype=jnp.bfloat16):
-        return jnp.asarray(rng.normal(size=(4, seq) + shape), dtype)
-
-    q_nope, k_nope, v = draw(heads, nope), draw(heads, nope), draw(heads, vd)
-    q_rope, k_rope = draw(heads, rot, dtype=jnp.float32), draw(rot)  # the queries' rope lanes not yet turned
-    cos, sin = latent_moe._rope_table(seq, rot, cfg.rope_theta)
-    mask = jnp.arange(seq)[None, :] < jnp.asarray(lens)[:, None]
-    turned = latent_moe._rope(q_rope, cos, sin).astype(jnp.bfloat16)
-    want = np.asarray(latent_moe._context_xla(q_nope, turned, k_nope, k_rope, v, mask))
-    args = [x.reshape(4, seq, -1) for x in (q_nope, q_rope, k_nope)] + [k_rope, v.reshape(4, seq, -1), jnp.asarray(lens, jnp.int32), cos, sin]
-    got = mla_attention.mla_attention(*args, out_dtype=jnp.float32, interpret=True)
+    """The kernel in the interpreter against the XLA chain of the padded
+    forward — rope of the queries, scores, masks, softmax, values — at the
+    tiny preset's heads, on 4 texts padded to ``seq`` as the stream whose
+    text ``i`` starts at ``i * seq``: every text one token (every key after
+    the first is padding), every text full, and a mix."""
+    lens = jnp.asarray({"ones": [1, 1, 1, 1], "full": [seq] * 4, "mixed": [1, seq, 77, seq - 1]}[lens])
+    cfg, heads_in = _heads_of(4 * seq, seq)
+    mask = jnp.arange(seq)[None, :] < lens[:, None]
+    seg = jnp.where(mask, jnp.arange(4)[:, None], -1)
+    pos = jnp.broadcast_to(jnp.arange(seq), (4, seq))
+    cos, sin = latent_moe._rope_table(seq, cfg.qk_rope_head_dim, cfg.rope_theta)
+    want = _chain(cfg, *heads_in, seg, cos, sin)
+    args = (*heads_in, seg.reshape(-1), pos.reshape(-1), cos, sin)
+    span = mla_attention.span_of(seq)
+    got = mla_attention.mla_attention(*args, span=span, out_dtype=jnp.float32, interpret=True)
     # float32 on both sides up to the bfloat16 rounding of a probability
     # whose float32 value differs in its last bit
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
-    same = mla_attention.mla_attention(*args, interpret=True)
+    same = mla_attention.mla_attention(*args, span=span, interpret=True)
     assert same.dtype == jnp.bfloat16  # what W_o reads, on the normal path
     np.testing.assert_allclose(np.asarray(same, np.float32), want, rtol=1e-2, atol=1e-2)
 
 
+#: a stream of the tiny preset (1,024 tokens, chunks of 256, documents
+#: aligned to 16): one token; 37, no multiple of 16; a whole 256 across two
+#: tiles and a chunk's end; 130 across a tile's end; 16; 200 across a tile's
+#: and a chunk's end; then nothing — the rest of its chunk and a whole dead
+#: chunk, and two documents that are not there
+STREAM_LENS = [1, 37, 256, 130, 16, 200, 0, 0]
+
+
+def _stream_of(lens, t: int, align: int = 16):
+    starts = np.full((len(lens),), t, np.int32)
+    at = 0
+    for i, n in enumerate(lens):
+        if n:
+            starts[i] = at
+            at += -(-n // align) * align
+    return starts, np.asarray(lens, np.int32)
+
+
+def test_the_kernel_visits_the_live_tiles_and_equals_a_document_and_order_mask():
+    """The visit form over a packed stream: real rows against the XLA
+    chain over the stream as one text, masked by document and by order;
+    query tile ``i`` meets key tiles back to its first document's first,
+    and no tile past the live length is visited."""
+    from pathway_tpu.models.token_stream import token_layout
+
+    t = 1024
+    starts, lens = _stream_of(STREAM_LENS, t)
+    seg, pos, live = token_layout(jnp.asarray(starts), jnp.asarray(lens), t)
+    assert int(live) == 680
+    cfg, heads_in = _heads_of(t, 3)
+    cos, sin = latent_moe._rope_table(256, cfg.qk_rope_head_dim, cfg.rope_theta)
+    span = mla_attention.span_of(256)
+    assert span == 3  # a document of 256 starting a row past a tile's start
+    qi, kj, nth, count, steps = mla_attention.visits(pos, live, span)
+    steps = int(steps)
+    visits = sorted(zip(np.asarray(qi)[:steps].tolist(), np.asarray(kj)[:steps].tolist()))
+    # tile 0: itself; 1, 2: back to the 256's tile 0; 3: back to the 130's tile 2; 4, 5: back to the 200's tile 3
+    assert visits == [(0, 0), (1, 0), (1, 1), (2, 0), (2, 1), (2, 2), (3, 2), (3, 3), (4, 3), (4, 4), (5, 3), (5, 4), (5, 5)]
+    assert np.asarray(count)[:steps].tolist() == [1, 2, 2, 3, 3, 3, 2, 2, 2, 2, 3, 3, 3]
+    assert np.asarray(nth)[:steps].tolist() == [0, 0, 1, 0, 1, 2, 0, 1, 0, 1, 0, 1, 2]
+    got = np.asarray(mla_attention.mla_attention(*heads_in, seg, pos, cos, sin, span=span, live=live, out_dtype=jnp.float32, interpret=True))
+    want = _chain(cfg, *heads_in, seg[None], cos[pos], sin[pos])
+    real = np.asarray(seg) >= 0
+    np.testing.assert_allclose(got[real], want[real], rtol=1e-4, atol=1e-4)
+    assert np.isfinite(got[: 6 * mla_attention.BLOCK]).all()  # a padding row of a visited tile too
+
+
+def _stream_against_alone(enc):
+    """The rows of ``STREAM_LENS``' documents (seeded ids) as one packed
+    stream, and each alone through the padded forward at 256."""
+    t = enc.cfg.max_group_tokens
+    starts, lens = _stream_of(STREAM_LENS, t)
+    rng = np.random.default_rng(5)
+    ids = np.zeros((t,), np.int32)
+    alone = []
+    for start, n in zip(starts, lens):
+        if not n:
+            continue
+        doc = rng.integers(999, enc.cfg.vocab_size, size=n).astype(np.int32)
+        ids[start : start + n] = doc
+        row = np.zeros((1, 256), np.int32)
+        row[0, :n] = doc
+        alone.append(np.asarray(jax.jit(enc.module.apply)(enc.params, row, jnp.asarray(np.arange(256) < n)[None]))[0])
+    # traced anew: a kernel a test patches is what the program calls
+    rows, loads = jax.jit(lambda *args: enc.module.apply_stream(*args))(enc.params, ids, starts, lens)
+    return np.asarray(rows), np.asarray(alone), np.asarray(loads)
+
+
+@pytest.mark.parametrize("attention", ATTENTION)
+def test_the_stream_equals_its_documents_one_by_one(attention):
+    """In float32, the packed stream's rows are its documents' rows alone
+    through the padded forward (the kernel at 256 in the interpreter, the
+    XLA chain otherwise); a document that is not there gets zeros, and
+    the loads count every real token's choices of the held experts."""
+    enc, *_ = tiny(attention=attention)
+    rows, alone, loads = _stream_against_alone(enc)
+    present = np.asarray(STREAM_LENS) > 0
+    np.testing.assert_allclose(rows[present], alone, atol=2e-6)
+    assert not rows[~present].any()
+    sparse = enc.cfg.num_hidden_layers - enc.cfg.first_k_dense_replace
+    assert loads.shape == (sparse, 8) and loads.sum(axis=1).tolist() == [2 * sum(STREAM_LENS)] * sparse
+
+
+def test_a_cross_document_leak_is_caught(monkeypatch):
+    """The kernel told every real token is of one document: each attends
+    the documents before it, and their rows move far past the tolerance
+    the stream is held to."""
+    enc, *_ = tiny(attention="interpret")
+    kernel = mla_attention.mla_attention
+
+    def leaking(*args, **kw):
+        seg = args[5]
+        return kernel(*args[:5], jnp.where(seg >= 0, 0, seg), *args[6:], **kw)
+
+    monkeypatch.setattr(mla_attention, "mla_attention", leaking)
+    rows, alone, _ = _stream_against_alone(enc)
+    moved = np.abs(rows[np.asarray(STREAM_LENS) > 0] - alone).max(axis=1)
+    assert moved[0] < 2e-6  # the first document has nothing before it
+    assert moved[1:].min() > 1e-3
+
+
 @pytest.mark.parametrize("seq, route", [(16, "xla"), (160, "xla"), (128, "kernel"), (256, "kernel")])
 def test_attention_route_by_shape_and_its_counter(seq, route):
-    """Texts of whole 128-token tiles take the kernel — a call a layer,
-    counted on the host as ``embed_attention`` — and every other shape
-    (the query program's 16) the XLA chain, counted nowhere. Off the chip
-    the kernel runs only where it is asked for in the interpreter."""
+    """In the padded forward (the query program's) texts of whole
+    128-token tiles take the kernel and every other shape (the query
+    program's 16) the XLA chain; a write batch is a stream of whole tiles
+    and takes it in every layer, counted on the host as ``embed_latent``:
+    a call a stream, its real tokens and the token rows of its live
+    chunks. Off the chip the kernel runs only where it is asked for in the
+    interpreter."""
     assert mla_attention.route(seq, "interpret") == route
     assert mla_attention.route(seq, "kernel") == "xla"  # the CPU
     enc, *_ = tiny(attention="interpret")
-    layers = enc.cfg.num_hidden_layers
+    layers, t, chunk = enc.cfg.num_hidden_layers, enc.cfg.max_group_tokens, enc.cfg.token_chunk
     lens = np.array([seq, 1, seq // 2, 3], np.int32)
     ids = np.where(np.arange(seq)[None, :] < lens[:, None], 7, 0).astype(np.int32)
     program = str(jax.make_jaxpr(enc.module.apply)(enc.params, ids, jnp.asarray(ids > 0)))
     assert program.count("mla_attention") == (layers if route == "kernel" else 0)
+    stream = (jax.ShapeDtypeStruct((t,), jnp.int32),) + (jax.ShapeDtypeStruct((t // 16,), jnp.int32),) * 2
+    assert str(jax.make_jaxpr(enc.module.apply_stream)(enc.params, *stream)).count("mla_attention") == layers
     tracing.set_tracing_enabled(True)
     tracing.TRACING_METRICS.reset()
     try:
         for _ in range(2):
-            enc._run_group(ids, lens)
+            enc._matrix_groups(ids, lens)
         totals = tracing.stage_totals()
     finally:
         tracing.set_tracing_enabled(False)
         tracing.TRACING_METRICS.reset()
-    if route == "xla":
-        assert "embed_attention" not in totals
-    else:
-        stage = totals["embed_attention"]
-        assert stage["calls"] == 2 * layers
-        assert stage["tokens"] == 2 * layers * int(lens.sum())
-        assert stage["computed_tokens"] == 2 * layers * 4 * seq
+    assert "embed_attention" not in totals
+    starts, _ = _stream_of(lens, t)
+    live = int(starts[-1] + lens[-1])
+    stage = totals["embed_latent"]
+    assert stage["calls"] == 2
+    assert stage["tokens"] == 2 * int(lens.sum())
+    assert stage["computed_tokens"] == 2 * -(-live // chunk) * chunk
 
 
 # ---- the published preset, without allocating it --------------------------------
@@ -349,8 +488,9 @@ def test_published_tree_is_the_configuration_files():
     assert isinstance(cfg, LatentMoEConfig) and architecture_of("FreedomIntelligence/" + model["name"]) == cfg
     assert family_model(cfg) == {k: v for k, v in model.items() if k not in ("name", "float32", *PROGRAM_ONLY)}
     whole = LatentMoEConfig()
+    assert model["max_seq_len"] == cfg.max_seq_len == 256  # the harness's geometry, not a published key
     for key in whole.__dataclass_fields__:
-        if key in PROGRAM_ONLY:
+        if key in PROGRAM_ONLY or key == "max_seq_len":
             continue
         assert config[key] == model[key], key  # the top-level copy agrees
         if key in config["reduced"]:
@@ -512,5 +652,33 @@ def test_embed_experts_is_fed_from_the_device_without_a_fetch_on_the_dispatch_pa
     assert experts["mean_load"] == pytest.approx(experts["rows"] / 8)
     assert experts["max_load"] >= experts["mean_load"]
     padded = totals["embed_dispatch"]["tokens"]
-    assert padded == 2 * 8 * 64 and snap["dispatches"] == 3  # both pad to 8 texts of 64; one more ran untraced
-    assert snap["model_flops"] == pytest.approx(3 * 8 * 64 * enc.cfg.flops_per_token(64))
+    lens = enc.tokenizer.batch_encode_matrix(TEXTS, 256)[1]
+    chunk, t = enc.cfg.token_chunk, enc.cfg.max_group_tokens
+
+    def computed(ls):  # the live chunks of the one stream these documents are
+        starts, ls = _stream_of(ls, t)
+        return -(-int(starts[-1] + ls[-1]) // chunk) * chunk
+
+    def flops(ls):
+        return sum(int(n) * enc.cfg.flops_per_token(int(n)) for n in ls)
+
+    assert padded == computed(lens) + computed(lens[:2]) and snap["dispatches"] == 3  # one more ran untraced
+    assert snap["model_flops"] == pytest.approx(2 * flops(lens) + flops(lens[:2]))
+
+
+def test_a_stream_without_experts_owes_no_loads():
+    """A packing module whose stream forward returns its rows alone (the
+    hybrid) goes through ``_run_stream`` as it did: nothing is owed to
+    ``embed_experts``."""
+    enc = SentenceEncoder("hybrid-ssm-tiny-for-tests", config=HybridSSMConfig.tiny_for_tests(scan_impl="interpret"))
+    tracing.set_tracing_enabled(True)
+    tracing.TRACING_METRICS.reset()
+    try:
+        rows = np.asarray(enc.encode_device(TEXTS))
+        assert not tracing.TRACING_METRICS._owed
+        totals = tracing.stage_totals()
+    finally:
+        tracing.set_tracing_enabled(False)
+        tracing.TRACING_METRICS.reset()
+    assert "embed_experts" not in totals and totals["embed_ssm"]["calls"] == 1
+    np.testing.assert_allclose(np.linalg.norm(rows, axis=1), 1.0, atol=1e-3)

@@ -29,7 +29,7 @@ from pathway_tpu.models.sentence_encoder import SentenceEncoder
 from pathway_tpu.ops.expert_dispatch import capacity_of, grouped_matmul
 from pathway_tpu.ops.fused_attention import attention
 from pathway_tpu.ops.fused_layer import _pack_rows, encoder_forward
-from pathway_tpu.ops.mla_attention import mla_attention
+from pathway_tpu.ops.mla_attention import mla_attention, span_of
 from pathway_tpu.ops.paged_attention import paged_decode_attention
 from pathway_tpu.ops.pallas_knn import knn_topk, knn_topk_sharded
 from pathway_tpu.ops.power_retention import power_retention
@@ -146,16 +146,23 @@ def _retention_case(tokens: int):
 
 
 def _mla_case(texts: int, seq: int, heads: int = 128, nope: int = 128, rot: int = 64, vd: int = 128):
-    # the latent-attention embedder's attention: a block of 16 texts of a
-    # write batch at the published heads (128 of 128 + 64 rope, values
-    # of 128), and the test preset's 4 heads of 16 + 8
+    # the latent-attention embedder's attention over a packed stream of
+    # ``texts * seq`` tokens (documents of at most ``seq``): a write batch's
+    # 8,192 at the published heads (128 of 128 + 64 rope, values of 128), a
+    # padded block of texts, and the test preset's 4 heads of 16 + 8
+    tokens = texts * seq
+
     def rows(width, dtype=jnp.bfloat16):
-        return _spec((texts, seq, width), dtype)
+        return _spec((tokens, width), dtype)
 
     table = _spec((seq, rot), jnp.float32)
-    return mla_attention, (
+
+    def attend(q_nope, q_rope, k_nope, k_rope, v, seg, pos, cos, sin, live):
+        return mla_attention(q_nope, q_rope, k_nope, k_rope, v, seg, pos, cos, sin, span=span_of(seq), live=live)
+
+    return attend, (
         rows(heads * nope), rows(heads * rot, jnp.float32), rows(heads * nope), rows(rot), rows(heads * vd),
-        _spec((texts,), jnp.int32), table, table,
+        _spec((tokens,), jnp.int32), _spec((tokens,), jnp.int32), table, table, _spec((), jnp.int32),
     )  # fmt: skip
 
 
@@ -180,6 +187,7 @@ SINGLE_DEVICE_CASES = {
     "expert_grouped_matmul[4096x2048x7680]": functools.partial(_experts_case, 2048, 7680),
     "power_retention[T=8192,40|8x128]": functools.partial(_retention_case, 8192),
     "power_retention[T=128,40|8x128]": functools.partial(_retention_case, 128),
+    "mla_attention[T=8192,128x(128+64|128)]": functools.partial(_mla_case, 32, 256),
     "mla_attention[16x256,128x(128+64|128)]": functools.partial(_mla_case, 16, 256),
     "mla_attention[16x128,128x(128+64|128)]": functools.partial(_mla_case, 16, 128),
     "mla_attention[8x128,4x(16+8|16)]": functools.partial(_mla_case, 8, 128, 4, 16, 8, 16),

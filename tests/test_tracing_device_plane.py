@@ -702,6 +702,25 @@ def _latent_moe_forward(enc):
     return (lambda: jax.jit(module.apply)), shapes, {}
 
 
+def _latent_moe_stream_forward(enc):
+    """The latent-attention / sparse-expert encoder's packed forward (a
+    write batch's), at its test preset (the attention and the grouped
+    product in the Pallas interpreter: this lowers for the CPU): a stream
+    of four chunks, so the loops over the chunks are there."""
+    from pathway_tpu.models.latent_moe import LatentMoEConfig, LatentMoEEncoder
+
+    cfg = LatentMoEConfig.tiny_for_tests(expert_impl="interpret", attention_impl="interpret")
+    module = LatentMoEEncoder(cfg)
+    t = cfg.max_group_tokens
+    shapes = (
+        jax.eval_shape(module.init),
+        jax.ShapeDtypeStruct((t,), np.int32),
+        jax.ShapeDtypeStruct((t // cfg.doc_align,), np.int32),
+        jax.ShapeDtypeStruct((t // cfg.doc_align,), np.int32),
+    )
+    return (lambda: jax.jit(module.apply_stream)), shapes, {}
+
+
 def _power_retention_forward(enc):
     """The power-retention encoder's packed forward, at its test preset
     (the retention in the Pallas interpreter: this lowers for the CPU):
@@ -730,6 +749,7 @@ HYBRID_SCOPES = tuple("pw.encode." + s for s in ("ssm_in", "ssm_conv", "ssm_scan
     [
         (_hybrid_forward, HYBRID_SCOPES),
         (_latent_moe_forward, LATENT_MOE_SCOPES),
+        (_latent_moe_stream_forward, LATENT_MOE_SCOPES),
         (_power_retention_forward, RETENTION_SCOPES),
         (_fused, ("pw.query.encode", "pw.query.scan", "pw.query.topk")),
         (_scatter_dev, ("pw.index.scatter",)),
